@@ -1,13 +1,10 @@
-"""Pallas TPU compatibility shim — the ONLY module that may import
+"""Pallas TPU symbols — the ONLY module that may import
 ``jax.experimental.pallas.tpu``.
 
-JAX has renamed the TPU-side Pallas symbols across releases: the
-scratch-shape memory-space factory is ``pltpu.MemorySpace.VMEM`` on recent
-versions but ``pltpu.VMEM`` (an enum member of ``pltpu.TPUMemorySpace``) on
-the 0.4.x line, and grid specs with scalar prefetch have likewise moved.
-Writing kernels against one spelling makes them dead code on every other
-JAX — exactly what happened to the seed suite.  Kernels therefore never
-touch ``pallas.tpu`` directly; they import the resolved symbols from here.
+Kernels never touch ``pallas.tpu`` directly; they import the symbols
+from here, so a rename in a future JAX is one edit in one file.  The
+import is unconditional: a JAX without ``pallas.tpu`` fails here, at
+import, instead of quietly routing every kernel to its jnp oracle.
 
 Policy (the ``sole-tpu-importer`` rule in ``repro.analysis.lint`` — run
 in CI's ``policy`` job and delegated to by
@@ -19,90 +16,32 @@ Exports
 -------
 ``pl``                      ``jax.experimental.pallas`` (re-export, so kernel
                             modules have a single import site).
-``PLTPU_AVAILABLE``         True when ``pallas.tpu`` imported cleanly.
 ``vmem(shape, dtype)``      VMEM scratch-shape factory (MemoryRef).
 ``smem(shape, dtype)``      SMEM scratch-shape factory.
-``PrefetchScalarGridSpec``  grid spec with leading scalar-prefetch operands.
-``require_pltpu()``         raise a helpful ImportError when unavailable.
+``prefetch_scalar_grid_spec``  grid spec with leading scalar-prefetch
+                            operands.
 """
 from __future__ import annotations
 
 from jax.experimental import pallas as pl  # noqa: F401  (re-export)
-
-try:
-    from jax.experimental.pallas import tpu as _pltpu
-    PLTPU_AVAILABLE = True
-    PLTPU_IMPORT_ERROR = None
-except Exception as e:  # pragma: no cover - depends on installed jaxlib
-    _pltpu = None
-    PLTPU_AVAILABLE = False
-    PLTPU_IMPORT_ERROR = e
-
-
-def require_pltpu():
-    if not PLTPU_AVAILABLE:  # pragma: no cover
-        raise ImportError(
-            "jax.experimental.pallas.tpu is unavailable on this install "
-            f"(underlying error: {PLTPU_IMPORT_ERROR!r}); use the jnp "
-            "backend via repro.kernels.dispatch instead.")
-    return _pltpu
-
-
-def _resolve_memory_space(name):
-    """Find the named memory space across the known API spellings."""
-    pltpu = require_pltpu()
-    ms = getattr(pltpu, "MemorySpace", None)          # jax >= 0.5 spelling
-    if ms is not None and hasattr(ms, name):
-        return getattr(ms, name)
-    if hasattr(pltpu, name):                          # 0.4.x: pltpu.VMEM
-        return getattr(pltpu, name)
-    tms = getattr(pltpu, "TPUMemorySpace", None)      # 0.4.x enum class
-    if tms is not None and hasattr(tms, name):
-        return getattr(tms, name)
-    raise AttributeError(  # pragma: no cover
-        f"cannot resolve TPU memory space {name!r} on this JAX; "
-        f"available: {[n for n in dir(pltpu) if not n.startswith('_')]}")
+from jax.experimental.pallas import tpu as _pltpu
 
 
 def vmem(shape, dtype):
     """VMEM scratch-shape factory: ``scratch_shapes=[vmem((8, 128), f32)]``."""
-    return _resolve_memory_space("VMEM")(shape, dtype)
+    return _pltpu.MemorySpace.VMEM(shape, dtype)
 
 
 def smem(shape, dtype):
     """SMEM scratch-shape factory (scalars / control flow)."""
-    return _resolve_memory_space("SMEM")(shape, dtype)
-
-
-def _resolve_prefetch_grid_spec():
-    if not PLTPU_AVAILABLE:
-        return None
-    spec = getattr(_pltpu, "PrefetchScalarGridSpec", None)
-    if spec is not None:
-        return spec
-    # Newer JAX folded scalar prefetch into pl.GridSpec.
-    gs = getattr(pl, "GridSpec", None)  # pragma: no cover
-    if gs is not None:  # pragma: no cover
-        import inspect
-        try:
-            if "num_scalar_prefetch" in inspect.signature(gs).parameters:
-                return gs
-        except (TypeError, ValueError):
-            pass
-    return None  # pragma: no cover
-
-
-_PREFETCH_SPEC = _resolve_prefetch_grid_spec()
+    return _pltpu.MemorySpace.SMEM(shape, dtype)
 
 
 def prefetch_scalar_grid_spec(*, num_scalar_prefetch, grid, in_specs,
                               out_specs, scratch_shapes=()):
     """Grid spec whose first ``num_scalar_prefetch`` operands are scalars
     available to every ``index_map`` (the TPU scalar-prefetch mechanism)."""
-    if _PREFETCH_SPEC is None:  # pragma: no cover
-        require_pltpu()
-        raise NotImplementedError(
-            "no PrefetchScalarGridSpec equivalent found on this JAX")
-    return _PREFETCH_SPEC(num_scalar_prefetch=num_scalar_prefetch, grid=grid,
-                          in_specs=in_specs, out_specs=out_specs,
-                          scratch_shapes=scratch_shapes)
+    return _pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=num_scalar_prefetch, grid=grid,
+        in_specs=in_specs, out_specs=out_specs,
+        scratch_shapes=scratch_shapes)

@@ -21,6 +21,7 @@ in one arm.
 """
 from __future__ import annotations
 
+import collections
 import functools
 import os
 
@@ -30,7 +31,7 @@ import numpy as np
 from jax import custom_batching
 
 from repro.core import submodel as sm
-from repro.kernels import compat, ref
+from repro.kernels import ref
 from repro.kernels.masked_update import sgd_2d
 from repro.kernels.ops import (_from_2d, _to_2d, fillin_agg_tree,
                                masked_sgd_tree)
@@ -70,8 +71,28 @@ def resolve_backend(backend: str | None = None) -> str:
         raise ValueError(
             f"unknown kernel backend {backend!r}; expected one of {BACKENDS}")
     if backend == "auto":
-        return "pallas" if (on_tpu() and compat.PLTPU_AVAILABLE) else "jnp"
+        return "pallas" if on_tpu() else "jnp"
     return backend
+
+
+#: Rolling-matmul calls that resolved to the ``pallas`` arm but ran the jnp
+#: oracle, keyed by op name.  A shape the kernel grid cannot tile, or a
+#: traced offset without an alignment certificate, takes the oracle; on a
+#: TPU that is XLA code in place of the kernel the caller asked for.
+#: Counted at trace time (a jitted round counts once per trace), so a run
+#: that needs the kernels clears this before tracing and requires it to
+#: stay empty (``chip_smoke.py``).
+ORACLE_FALLBACKS: collections.Counter = collections.Counter()
+
+
+def _takes_pallas(backend, tileable: bool, op: str) -> bool:
+    """True when ``op`` runs its Pallas arm; a pallas-resolved call that
+    cannot is counted in :data:`ORACLE_FALLBACKS`."""
+    if resolve_backend(backend) != "pallas":
+        return False
+    if not tileable:
+        ORACLE_FALLBACKS[op] += 1
+    return tileable
 
 
 # ---------------------------------------------------------------------------
@@ -298,10 +319,10 @@ def _pallas_fwd(x, w, offset, win, bm, bn, bk):
 
 
 def _rolling_fwd_arm(x, w, offset, win, backend, bm, bn, bk, assume_aligned):
-    b = resolve_backend(backend)
     M, K = x.shape
-    if b == "pallas" and _rolling_tileable(M, K, win, offset, bm, bn, bk,
-                                           assume_aligned):
+    if _takes_pallas(backend,
+                     _rolling_tileable(M, K, win, offset, bm, bn, bk,
+                                       assume_aligned), "rolling_matmul"):
         return _pallas_fwd(x, w, offset, win, bm, bn, bk)
     return ref.rolling_matmul_ref(x, w, offset, win)
 
@@ -340,13 +361,12 @@ def _rolling_dx_arm(dy, w, offset, win, backend, bm, bn, bk, assume_aligned):
     """dx = dy @ w[:, offset:offset+win]^T — second offset-prefetch kernel
     (the contraction runs over the window, so the offset must land on a
     ``bk`` block boundary); jnp oracle otherwise."""
-    b = resolve_backend(backend)
     M = dy.shape[0]
     K = w.shape[0]
     bm_, bn_, bk_ = min(bm, M), min(bn, K), min(bk, win)
     tileable = (M % bm_ == 0 and K % bn_ == 0 and win % bk_ == 0
                 and _offset_aligned(offset, bk_, assume_aligned))
-    if b == "pallas" and tileable:
+    if _takes_pallas(backend, tileable, "rolling_matmul_dx"):
         return _pallas_dx(dy, w, offset, win, bm, bn, bk)
     wsub = jax.lax.dynamic_slice_in_dim(w, offset, win, axis=1)
     return jax.lax.dot_general(
@@ -400,7 +420,9 @@ def rolling_matmul(x, w, offset, win, backend=None, bm=None, bn=None,
     and — because the kernels floor-round the offset to a block boundary —
     for *traced* offsets unless ``assume_aligned=True`` (pass it when every
     offset the scheme can produce is a multiple of the block width, cf.
-    ``WindowScheme.grid_multiple`` / ``AxisWindow.aligned``).
+    ``WindowScheme.grid_multiple`` / ``AxisWindow.aligned``).  Each such
+    fallback under the ``pallas`` arm is counted in
+    :data:`ORACLE_FALLBACKS`.
 
     Registered with a custom VJP: ``dx = dy @ w[:, off:off+win]^T`` via the
     offset-prefetch backward kernel (``kernels.rolling_matmul_bwd``), ``dW``
@@ -435,12 +457,11 @@ def _batched_offsets_aligned(offsets, block, assume_aligned):
 
 def _rolling_b_fwd_arm(x, w, offsets, win, backend, bm, bn, bk,
                        assume_aligned):
-    b = resolve_backend(backend)
     _, M, K = x.shape
     bm_, bn_, bk_ = min(bm, M), min(bn, win), min(bk, K)
     tileable = (M % bm_ == 0 and win % bn_ == 0 and K % bk_ == 0
                 and _batched_offsets_aligned(offsets, bn_, assume_aligned))
-    if b == "pallas" and tileable:
+    if _takes_pallas(backend, tileable, "rolling_matmul_batched"):
         return _rolling_mm_batched_pallas(x, w, offsets, win, bm=bm, bn=bn,
                                           bk=bk,
                                           interpret=interpret_mode())
@@ -450,13 +471,12 @@ def _rolling_b_fwd_arm(x, w, offsets, win, backend, bm, bn, bk,
 
 def _rolling_b_dx_arm(dy, w, offsets, win, backend, bm, bn, bk,
                       assume_aligned):
-    b = resolve_backend(backend)
     _, M, _ = dy.shape
     K = w.shape[1]
     bm_, bn_, bk_ = min(bm, M), min(bn, K), min(bk, win)
     tileable = (M % bm_ == 0 and K % bn_ == 0 and win % bk_ == 0
                 and _batched_offsets_aligned(offsets, bk_, assume_aligned))
-    if b == "pallas" and tileable:
+    if _takes_pallas(backend, tileable, "rolling_matmul_batched_dx"):
         return _rolling_dx_batched_pallas(dy, w, offsets, win, bm=bm, bn=bn,
                                           bk=bk,
                                           interpret=interpret_mode())
@@ -568,12 +588,11 @@ def _pallas_multi_fwd(x, ws, offset, win, bm, bn, bk):
 
 
 def _multi_fwd_arm(x, ws, offset, win, backend, bm, bn, bk, assume_aligned):
-    b = resolve_backend(backend)
     M, K = x.shape
     uniform = len({w.shape for w in ws}) == 1
-    if (b == "pallas" and uniform
-            and _rolling_tileable(M, K, win, offset, bm, bn, bk,
-                                  assume_aligned)):
+    tileable = uniform and _rolling_tileable(M, K, win, offset, bm, bn, bk,
+                                             assume_aligned)
+    if _takes_pallas(backend, tileable, "rolling_matmul_multi"):
         ys = _pallas_multi_fwd(x, jnp.stack(ws), offset, win, bm, bn, bk)
         return tuple(ys[t] for t in range(len(ws)))
     # jnp arm: a literal loop of the single-weight oracle — bitwise
@@ -618,7 +637,6 @@ def _pallas_multi_dx(dys, ws, offset, win, bm, bn, bk):
 
 
 def _multi_dx_arm(dys, ws, offset, win, backend, bm, bn, bk, assume_aligned):
-    b = resolve_backend(backend)
     M = dys[0].shape[0]
     K = ws[0].shape[0]
     bm_, bn_, bk_ = min(bm, M), min(bn, K), min(bk, win)
@@ -626,7 +644,7 @@ def _multi_dx_arm(dys, ws, offset, win, backend, bm, bn, bk, assume_aligned):
     tileable = (uniform and M % bm_ == 0 and K % bn_ == 0
                 and win % bk_ == 0
                 and _offset_aligned(offset, bk_, assume_aligned))
-    if b == "pallas" and tileable:
+    if _takes_pallas(backend, tileable, "rolling_matmul_multi_dx"):
         return _pallas_multi_dx(jnp.stack(dys), jnp.stack(ws), offset, win,
                                 bm, bn, bk)
 

@@ -12,6 +12,13 @@ scan) stays outside in jnp — see ``ops.ssd_chunk_scan``.
 
 Head-blocked so the [nh_b, Q, Q] decay tensor stays VMEM-resident
 (nh_b·Q²·4B ≤ ~4 MB at Q=128, nh_b=64).
+
+Runs in interpret mode only: the TPU compiler refuses both forms.  The
+full-head form needs a ``cumsum``, which Mosaic cannot lower; the
+head-window form takes ``(…, nh_b, hd)`` blocks of a ``(…, nh, hd)``
+array (e.g. 12 of 24 heads), which breaks the rule that a block's last
+two dims be multiples of 8×128 or the whole dims.  No caller on the
+training path uses it; only the kernel tests do.
 """
 from __future__ import annotations
 

@@ -7,12 +7,22 @@ Multi-pod: 2 pods x 256 = 512 chips with a leading `pod` axis (DCN-ish).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _make_mesh(shape, axes):
+    """``jax.make_mesh`` with ``Auto`` axes: the round's ``shard_map`` and
+    ``with_sharding_constraint`` calls are written for the partitioner to
+    propagate shardings, which ``Explicit`` axes (``jax.make_mesh``'s
+    default) refuse."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -25,7 +35,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = max(1, min(model, n // data))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _make_mesh((data, model), ("data", "model"))
 
 
 def parse_mesh(spec: str):
@@ -59,4 +69,4 @@ def host_mesh(spec: str):
             "visible; on CPU, force host devices before JAX initializes "
             "(train.py --devices N, REPRO_HOST_DEVICES=N for pytest, or "
             "XLA_FLAGS=--xla_force_host_platform_device_count=N)")
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _make_mesh((data, model), ("data", "model"))

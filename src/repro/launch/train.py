@@ -6,14 +6,17 @@ algorithms) on real devices, through the ``repro.api`` facade.
         [--clients 4 --local-steps 2 --mb 2 --seq 128] \
         [--client-opt momentum --server-opt adam]
 
-On this CPU container use --reduced (smoke-scale config); on a TPU slice the
-same entry point drives the full config over the production mesh.
+On a CPU host use --reduced (smoke-scale config); on a TPU the same entry
+point drives the full config (``chip_smoke.py`` runs it at TinyLlama-1.1B
+published widths on one v5e chip).
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
+import pathlib
 import sys
 import time
 
@@ -50,11 +53,39 @@ from repro.data.synthetic import lm_batches
 from repro.launch.mesh import host_mesh
 from repro.models import build_model
 
+#: Root of the checkout (``src/repro/launch/train.py`` -> ``.``).
+CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
 
-def main():
+
+def enable_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache across runs; returns its
+    directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and is
+    left to JAX.  Otherwise the cache goes to ``<checkout>/.jax_cache``: a
+    fixed path, so the next run in this checkout finds what this one
+    compiled.  Call it from an entry point's ``main`` before the first
+    compile, never at import."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def main(argv=None):
+    """Parse ``argv`` (default ``sys.argv[1:]``), train, print the JSON
+    summary, and return the :class:`api.Trainer` (its ``losses``, ``fed``
+    and ``params`` are the run's result)."""
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="keep the first N layers: a depth cut that leaves "
+                         "every width as published, to fit one chip "
+                         "(default: all layers of the config)")
     ap.add_argument("--scheme", default="rolling",
                     choices=["rolling", "random", "static", "full",
                              "bernoulli", "importance"])
@@ -173,7 +204,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--log-every", type=int, default=10)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if args.kernel_block:
         from repro.kernels import dispatch
@@ -185,6 +216,11 @@ def main():
 
     cfg = get_reduced_config(args.arch) if args.reduced \
         else get_config(args.arch)
+    if args.layers is not None:
+        if not 1 <= args.layers <= cfg.n_layers:
+            raise SystemExit(f"--layers must be in [1, {cfg.n_layers}] "
+                             f"for {cfg.name}; got {args.layers}")
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
     unroll_kw = {}
     if args.layer_unroll:
         unroll_kw["layer_unroll"] = (True if args.layer_unroll == "full"
@@ -240,6 +276,10 @@ def main():
             log_fn=lambda s: print(
                 f"{s} ({(time.time() - t0) / (trainer.round_idx or 1):.2f}"
                 "s/round)", flush=True))
+    # The trainer now holds the only reference to the initial weights, so
+    # they are freed after the first round instead of pinning a second
+    # copy of the model in device memory for the whole run.
+    del params
     params, history = trainer.run(it, args.rounds)
     losses = trainer.losses  # history keeps device arrays; sync once here
     if args.ckpt:
@@ -256,6 +296,7 @@ def main():
                        sum(h["staleness"] for h in history) / len(history),
                        3))
     print(json.dumps(out))
+    return trainer
 
 
 if __name__ == "__main__":

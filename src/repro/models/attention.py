@@ -253,11 +253,12 @@ def gqa_train(p, x, cfg, positions, q_chunk=0, kv_chunk=0, window=None):
     if _USE_FLASH:
         # Pallas flash kernel (VMEM-resident online softmax) — the TPU
         # deployment path; interpret-mode on CPU hosts (see §Perf C3).
+        from repro.kernels.dispatch import interpret_mode
         from repro.kernels.flash_attention import flash_attention
         out = flash_attention(
             q, k, v, causal=True, window=cfg.sliding_window,
             bq=min(512, q.shape[1]), bkv=min(512, k.shape[1]),
-            interpret=jax.default_backend() != "tpu")
+            interpret=interpret_mode())
     else:
         out = blockwise_attention(q, k, v, causal=True,
                                   window=cfg.sliding_window,

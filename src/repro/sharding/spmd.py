@@ -1,10 +1,9 @@
-"""Version-portable ``shard_map`` + mesh-axis utilities.
+"""``shard_map`` + mesh-axis utilities.
 
 One home for the two helpers the sharding stack kept duplicating:
 
-* :func:`shard_map` — the manual-SPMD entry point across JAX versions
-  (``jax.shard_map`` with ``check_vma`` on >= 0.6, the experimental
-  module with ``check_rep`` before that).  Used by the mesh fed round
+* :func:`shard_map` — the manual-SPMD entry point (``jax.shard_map``
+  with ``check_vma`` off).  Used by the mesh fed round
   (``core/fedavg.py``) and the context-parallel attention path
   (``models/attention.py``).
 * :func:`axis_size` — size of a (possibly tuple) mesh axis; previously
@@ -17,18 +16,14 @@ here with a readable error instead of an opaque partitioner failure.
 """
 from __future__ import annotations
 
-try:  # jax >= 0.6
-    from jax import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-except ImportError:  # pragma: no cover - exercised on old JAX in CI matrix
-    from jax.experimental.shard_map import shard_map as _sm
 
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+def shard_map(f, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with the varying-manual-axes check
+    (``check_vma``) off."""
+    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                      check_vma=False)
 
 
 def axis_size(mesh, name) -> int:

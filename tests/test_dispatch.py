@@ -7,6 +7,7 @@
 * ``WindowFedAvg.round_with_server_opt`` honors the importance scheme.
 """
 import os
+from collections import Counter
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +26,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def test_compat_resolves_on_installed_jax():
-    assert compat.PLTPU_AVAILABLE, compat.PLTPU_IMPORT_ERROR
+    assert compat._pltpu.__name__ == "jax.experimental.pallas.tpu"
     scratch = compat.vmem((8, 128), jnp.float32)
     assert scratch is not None
     spec = compat.prefetch_scalar_grid_spec(
@@ -115,25 +116,31 @@ def test_fillin_agg_arms_match():
         dispatch.masked_sgd(wc, mc, g, 0.05, backend="jnp"))
 
 
-def test_rolling_matmul_arms_and_fallback():
+def test_rolling_matmul_arms_and_fallback(monkeypatch):
+    monkeypatch.setattr(dispatch, "ORACLE_FALLBACKS", Counter())
     x = jax.random.normal(jax.random.PRNGKey(0), (128, 256))
     w = jax.random.normal(jax.random.PRNGKey(1), (256, 512))
     y1 = dispatch.rolling_matmul(x, w, 128, 256, backend="pallas")
     y2 = dispatch.rolling_matmul(x, w, 128, 256, backend="jnp")
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
                                rtol=1e-4, atol=1e-3)
-    # non-MXU-tileable shapes degrade to the oracle instead of asserting
+    assert not dispatch.ORACLE_FALLBACKS
+    # non-MXU-tileable shapes degrade to the oracle instead of asserting,
+    # and the pallas arm counts the fallback (the jnp arm does not)
     y3 = dispatch.rolling_matmul(x[:100], w, 100, 156, backend="pallas")
     np.testing.assert_allclose(
         np.asarray(y3), np.asarray(ref.rolling_matmul_ref(x[:100], w, 100,
                                                           156)),
         rtol=1e-5, atol=1e-5)
+    dispatch.rolling_matmul(x[:100], w, 100, 156, backend="jnp")
+    assert dispatch.ORACLE_FALLBACKS == Counter(rolling_matmul=1)
 
 
-def test_rolling_matmul_traced_unaligned_offset_safe():
+def test_rolling_matmul_traced_unaligned_offset_safe(monkeypatch):
     """A traced offset of unknown alignment must take the oracle arm (the
     kernel floor-rounds offsets to block boundaries) unless the caller
     vouches with assume_aligned=True."""
+    monkeypatch.setattr(dispatch, "ORACLE_FALLBACKS", Counter())
     x = jax.random.normal(jax.random.PRNGKey(0), (128, 256))
     w = jax.random.normal(jax.random.PRNGKey(1), (256, 512))
     off = jnp.int32(100)  # NOT a multiple of bn=128
@@ -143,6 +150,8 @@ def test_rolling_matmul_traced_unaligned_offset_safe():
     np.testing.assert_allclose(
         np.asarray(y), np.asarray(ref.rolling_matmul_ref(x, w, 100, 128)),
         rtol=1e-4, atol=1e-3)
+    # the traced forward's fallback is visible, not silent
+    assert dispatch.ORACLE_FALLBACKS["rolling_matmul"] >= 1
 
 
 def test_dense_masks_reject_importance_scheme():
