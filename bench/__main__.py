@@ -1,0 +1,10 @@
+"""``python -m bench``: see ``bench/run.py``."""
+import time
+
+T_START = time.perf_counter()
+
+import sys  # noqa: E402
+
+from bench.run import main  # noqa: E402
+
+sys.exit(main(t_start=T_START))

@@ -1,0 +1,10 @@
+"""device_idle_pct: share of the traced window in which no operation ran on
+the device (1 - union of operation intervals / window), averaged over the
+chips.  From the profiler trace."""
+
+
+def read(ctx):
+    t = ctx.trace
+    if not t or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
