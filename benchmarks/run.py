@@ -756,17 +756,6 @@ def fed_round_mesh(rounds):
 C_OVERRIDE = None  # test hook: shrink the scale arm's client count
 
 
-def round_profile(rounds):
-    """Per-phase FLOP/byte/roofline numbers for the fused vs extract round
-    (see ``repro.analysis.round_profile``): compiles each phase, runs the
-    HLO cost analyzer, attributes the wall-clock gap to a phase and a
-    bottleneck term.  Compile-only — nothing executes on device."""
-    from repro.analysis.round_profile import profile
-
-    for k, v in sorted(profile().items()):
-        emit("round_profile", k, v)
-
-
 def roofline(rounds):
     files = sorted(glob.glob("experiments/dryrun/*.json"))
     if not files:
@@ -794,7 +783,6 @@ BENCHES = {
     "fed_round_fused": fed_round_fused,
     "fed_round_async": fed_round_async,
     "fed_round_mesh": fed_round_mesh,
-    "round_profile": round_profile,
     "roofline": roofline,
 }
 
@@ -894,7 +882,6 @@ BENCH_SCHEMA = {
                     "scale_round_maxdelta": str, "vmap_round_ms": _NUM},
         "gates": ["mesh_round_bitwise_equal"],
     },
-    "round_profile": {"metrics": {}},
     "roofline": {"metrics": {}},
     "curves": {"metrics": {}},
     "paper_protocol": {"metrics": {}},

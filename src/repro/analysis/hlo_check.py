@@ -22,9 +22,15 @@ this module without paying for a jax import.
 """
 from __future__ import annotations
 
-from typing import Callable, Sequence, Union
+import re
+from typing import Callable, List, Sequence, Set, Union
 
 Patterns = Union[str, Sequence[str]]
+
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+#: Separators of a name-stack path: ``/`` between scopes, and the
+#: ``jvp(...)`` / ``transpose(...)`` wrappers ``grad`` puts around them.
+_PATH_SEP = re.compile(r"[/()]")
 
 
 def compiled_text(fn: Callable, *args, static_argnums=None, **kwargs) -> str:
@@ -67,3 +73,25 @@ def stacked_shape(dtype: str, *dims: int) -> str:
     """HLO shape string ``f32[4,2,128,256]`` for an allocation witness —
     the spelling XLA uses in optimized-HLO buffer types."""
     return f"{dtype}[{','.join(str(int(d)) for d in dims)}]"
+
+
+def op_names(hlo: str) -> List[str]:
+    """The ``op_name`` metadata (the ``jax.named_scope`` path) of every
+    instruction of ``hlo`` that carries the whole path from its ``jit(``,
+    e.g. ``jit(step)/transpose(jvp(fed.client_phase))/dot_general``.
+    (Instructions of reducer bodies carry only the tail of a path.)"""
+    return [n for n in _OP_NAME.findall(hlo) if n.startswith("jit(")]
+
+
+def outermost_scope(op_name: str, prefix: str) -> str:
+    """The outermost path component of ``op_name`` that starts with
+    ``prefix`` (``jvp(`` / ``transpose(`` wrappers stripped), or ``""``."""
+    for part in _PATH_SEP.split(op_name):
+        if part.startswith(prefix):
+            return part
+    return ""
+
+
+def scopes(hlo: str, prefix: str = "fed.") -> Set[str]:
+    """The outermost ``prefix`` scopes that own an instruction in ``hlo``."""
+    return {outermost_scope(n, prefix) for n in op_names(hlo)} - {""}

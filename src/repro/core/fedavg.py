@@ -17,7 +17,10 @@ Two executable forms of one algorithm family, one code path each:
   masks are the window indicators).
 
 Both rounds share the same internal phases — client offsets/masks →
-``_client_phase`` (extract → K-step scan → delta) → aggregation — and both
+``_client_phase`` (extract → K-step scan → delta) → aggregation → server
+step — each under its ``jax.named_scope`` (``fed.offsets``,
+``fed.client_phase``, ``fed.aggregate``, ``fed.server_step``; see
+:mod:`repro.tracing`), so a device trace splits the round by phase.  Both
 take a pluggable :class:`repro.optim.client.ClientOpt` for the local steps
 and an optional stateful server optimizer (`round_with_server_opt`) that
 treats the mean delta as a pseudo-gradient.
@@ -38,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro import tracing
 from repro.configs.base import SubmodelConfig
 from repro.core import extract as ex
 from repro.core import submodel as sm
@@ -290,6 +294,7 @@ class WindowFedAvg:
 
     # -- composable round phases ---------------------------------------------
 
+    @tracing.scoped(tracing.OFFSETS)
     def _client_offsets(self, params, round_idx, rng):
         C = self.scfg.clients_per_round
         if self.hetero is not None:
@@ -329,20 +334,23 @@ class WindowFedAvg:
         homogeneous-round delta sums, accumulated in descending-beta
         bucket order (the composition pinned bitwise in
         ``tests/test_hetero.py``)."""
-        acc = jax.tree_util.tree_map(
-            lambda x: jnp.zeros(x.shape, jnp.float32), self.abstract)
+        with jax.named_scope(tracing.AGGREGATE):
+            acc = jax.tree_util.tree_map(
+                lambda x: jnp.zeros(x.shape, jnp.float32), self.abstract)
         parts, order = [], []
         for b in self.hetero:
-            lanes = jnp.asarray(b.idx, jnp.int32)
-            bb = jax.tree_util.tree_map(
-                lambda x: jnp.take(jnp.asarray(x), lanes, axis=1), batch)
+            with jax.named_scope(tracing.CLIENT_PHASE):
+                lanes = jnp.asarray(b.idx, jnp.int32)
+                bb = jax.tree_util.tree_map(
+                    lambda x: jnp.take(jnp.asarray(x), lanes, axis=1), batch)
             boff = b.fed._client_offsets(params, round_idx, rng)
             bfused = b.fed.use_fused and bool(boff)
             phase = (b.fed._client_phase_fused if bfused
                      else b.fed._client_phase)
             _, delta, bl = phase(params, bb, boff)
             part = b.fed._local_delta_sum(delta, boff, bfused)
-            acc = jax.tree_util.tree_map(lambda a, d: a + d, acc, part)
+            with jax.named_scope(tracing.AGGREGATE):
+                acc = jax.tree_util.tree_map(lambda a, d: a + d, acc, part)
             parts.append(bl)
             # b.idx is a static tuple of python ints, host-only
             # repro-lint: disable=host-sync
@@ -357,10 +365,12 @@ class WindowFedAvg:
         ``w + server_lr · (Σ_c scattered delta_c) / C``."""
         c = self.scfg
         acc, losses = self._hetero_delta_sum(params, batch, round_idx, rng)
-        new = jax.tree_util.tree_map(
-            lambda w, d: (w + c.server_lr * d / c.clients_per_round
-                          ).astype(w.dtype), params, acc)
-        new = sm.project_l2(new, c.proj_radius)
+        with jax.named_scope(tracing.AGGREGATE):
+            new = jax.tree_util.tree_map(
+                lambda w, d: (w + c.server_lr * d / c.clients_per_round
+                              ).astype(w.dtype), params, acc)
+        with jax.named_scope(tracing.SERVER_STEP):
+            new = sm.project_l2(new, c.proj_radius)
         return new, {"loss": losses.mean(), "client_loss": losses}
 
     def _hetero_phase_for(self, slots):
@@ -387,6 +397,7 @@ class WindowFedAvg:
             if cols.size:
                 plan.append((b, cols))
 
+        @tracing.scoped(tracing.CLIENT_PHASE)
         def phase(params, batch, offsets):
             dparts, lparts, order = [], [], []
             for b, cols in plan:
@@ -435,6 +446,7 @@ class WindowFedAvg:
                 lambda x: jnp.broadcast_to(x[None], (C,) + x.shape), params)
         return constrain_tree(sub0, self.axes_tree)
 
+    @tracing.scoped(tracing.CLIENT_PHASE)
     def _client_phase(self, params, batch, offsets):
         """extract → K local-optimizer steps (scan) → delta."""
         c = self.scfg
@@ -464,6 +476,7 @@ class WindowFedAvg:
             subK, sub0)
         return sub0, delta, losses
 
+    @tracing.scoped(tracing.CLIENT_PHASE)
     def _client_phase_fused(self, params, batch, offsets):
         """Fused multi-axis window client phase: K steps on the FULL tree.
 
@@ -529,6 +542,7 @@ class WindowFedAvg:
             fullK, full0)
         return full0, delta_full, losses
 
+    @tracing.scoped(tracing.AGGREGATE)
     def _apply_mean_delta(self, params, delta, offsets):
         """Plain averaging (the paper's fill-in update, delta form)."""
         c = self.scfg
@@ -573,6 +587,7 @@ class WindowFedAvg:
         return jax.tree_util.tree_map(
             lambda d: d.astype(jnp.bfloat16).astype(f32), tree)
 
+    @tracing.scoped(tracing.AGGREGATE)
     def _apply_mean_delta_fused(self, params, delta_full, offsets):
         """Aggregation for the fused client phase's FULL-shaped delta.
 
@@ -616,6 +631,7 @@ class WindowFedAvg:
             lambda w, d: (w + c.server_lr * d.astype(jnp.float32) / C
                           ).astype(w.dtype), params, acc)
 
+    @tracing.scoped(tracing.AGGREGATE)
     def _mean_delta_full_fused(self, delta_full):
         """Server pseudo-gradient from the fused phase: already full-shaped
         with exact zeros outside each client's window — the shared-window
@@ -640,6 +656,7 @@ class WindowFedAvg:
         full, _ = jax.lax.scan(acc_step, z, delta_full)
         return full
 
+    @tracing.scoped(tracing.AGGREGATE)
     def _mean_delta_full(self, params, delta, offsets):
         """Full-shaped f32 mean client delta (the server pseudo-gradient).
 
@@ -676,6 +693,7 @@ class WindowFedAvg:
 
     # -- mesh scale-out: the client axis under shard_map -----------------------
 
+    @tracing.scoped(tracing.AGGREGATE)
     def _local_delta_sum(self, delta, offsets, fused):
         """Shard-local f32 scatter-add of client deltas (no /C) — the
         summand of the client-axis ``psum``.  Mirrors the per-client scan
@@ -719,7 +737,9 @@ class WindowFedAvg:
           psum'd over the client axis (the scalable arm: O(model) comm,
           fp-reassociated).
 
-        Per-client losses are always gathered exactly ([K, C]).
+        Per-client losses are always gathered exactly ([K, C]).  The
+        exchange runs under the ``fed.aggregate`` scope, the rest of the
+        body under the phase's own.
         """
         axis = self.spmd_axis
         fused = self.use_fused and bool(offsets)
@@ -728,13 +748,15 @@ class WindowFedAvg:
         def body(p, b, off):
             phase = self._client_phase_fused if fused else self._client_phase
             _, delta, losses = phase(p, b, off)
-            losses = jax.lax.all_gather(losses, axis, axis=1, tiled=True)
             if psum:
-                part = self._local_delta_sum(delta, off, fused)
-                return jax.lax.psum(part, axis), losses
-            delta = jax.tree_util.tree_map(
-                lambda d: jax.lax.all_gather(d, axis, axis=0, tiled=True),
-                delta)
+                delta = self._local_delta_sum(delta, off, fused)
+            with jax.named_scope(tracing.AGGREGATE):
+                losses = jax.lax.all_gather(losses, axis, axis=1, tiled=True)
+                if psum:
+                    return jax.lax.psum(delta, axis), losses
+                delta = jax.tree_util.tree_map(
+                    lambda d: jax.lax.all_gather(d, axis, axis=0, tiled=True),
+                    delta)
             return delta, losses
 
         fn = spmd.shard_map(
@@ -750,22 +772,25 @@ class WindowFedAvg:
         if self.mesh_agg == "psum":
             # out = sum_c scattered delta_c (f32, full-shaped): the same
             # final update formula as the per-client scan arm
-            new = jax.tree_util.tree_map(
-                lambda w, d: (w + c.server_lr * d / c.clients_per_round
-                              ).astype(w.dtype), params, out)
+            with jax.named_scope(tracing.AGGREGATE):
+                new = jax.tree_util.tree_map(
+                    lambda w, d: (w + c.server_lr * d / c.clients_per_round
+                                  ).astype(w.dtype), params, out)
         elif self.use_fused and offsets:
             new = self._apply_mean_delta_fused(params, out, offsets)
         else:
             new = self._apply_mean_delta(params, out, offsets)
-        new = sm.project_l2(new, c.proj_radius)
+        with jax.named_scope(tracing.SERVER_STEP):
+            new = sm.project_l2(new, c.proj_radius)
         return new, {"loss": losses.mean(), "client_loss": losses}
 
     def _mean_delta_full_mesh(self, params, batch, offsets):
         """Sharded client phase + full-shaped mean delta (server-opt path)."""
         out, losses = self._client_phase_sharded(params, batch, offsets)
         if self.mesh_agg == "psum":
-            full_delta = jax.tree_util.tree_map(
-                lambda d: d / self.scfg.clients_per_round, out)
+            with jax.named_scope(tracing.AGGREGATE):
+                full_delta = jax.tree_util.tree_map(
+                    lambda d: d / self.scfg.clients_per_round, out)
         elif self.use_fused and offsets:
             full_delta = self._mean_delta_full_fused(out)
         else:
@@ -788,7 +813,8 @@ class WindowFedAvg:
         else:
             _, delta, losses = self._client_phase(params, batch, offsets)
             new = self._apply_mean_delta(params, delta, offsets)
-        new = sm.project_l2(new, self.scfg.proj_radius)
+        with jax.named_scope(tracing.SERVER_STEP):
+            new = sm.project_l2(new, self.scfg.proj_radius)
         return new, {"loss": losses.mean(), "client_loss": losses}
 
     def round_with_server_opt(self, params, opt_state, batch, round_idx,
@@ -809,26 +835,29 @@ class WindowFedAvg:
         if self.hetero is not None:
             acc, losses = self._hetero_delta_sum(params, batch, round_idx,
                                                  rng)
-            full_delta = jax.tree_util.tree_map(
-                lambda d: d / self.scfg.clients_per_round, acc)
+            with jax.named_scope(tracing.AGGREGATE):
+                full_delta = jax.tree_util.tree_map(
+                    lambda d: d / self.scfg.clients_per_round, acc)
+        else:
+            full_delta, losses = self._mean_delta(params, batch, round_idx,
+                                                  rng)
+        with jax.named_scope(tracing.SERVER_STEP):
             new, opt_state = server_opt.update(params, full_delta, opt_state)
             new = sm.project_l2(new, self.scfg.proj_radius)
-            return new, opt_state, {"loss": losses.mean(),
-                                    "client_loss": losses}
+        return new, opt_state, {"loss": losses.mean(), "client_loss": losses}
+
+    def _mean_delta(self, params, batch, round_idx, rng):
+        """Client phase and full-shaped mean delta of a homogeneous round
+        (the server optimizer's pseudo-gradient) with the client losses."""
         offsets = self._client_offsets(params, round_idx, rng)
         if self.mesh is not None:
-            full_delta, losses = self._mean_delta_full_mesh(params, batch,
-                                                            offsets)
-        elif self.use_fused and offsets:
+            return self._mean_delta_full_mesh(params, batch, offsets)
+        if self.use_fused and offsets:
             _, delta_full, losses = self._client_phase_fused(params, batch,
                                                              offsets)
-            full_delta = self._mean_delta_full_fused(delta_full)
-        else:
-            _, delta, losses = self._client_phase(params, batch, offsets)
-            full_delta = self._mean_delta_full(params, delta, offsets)
-        new, opt_state = server_opt.update(params, full_delta, opt_state)
-        new = sm.project_l2(new, self.scfg.proj_radius)
-        return new, opt_state, {"loss": losses.mean(), "client_loss": losses}
+            return self._mean_delta_full_fused(delta_full), losses
+        _, delta, losses = self._client_phase(params, batch, offsets)
+        return self._mean_delta_full(params, delta, offsets), losses
 
 
 def _scatter_update(params, dbar, abstract, axes_tree, off0, sizes,
@@ -951,11 +980,9 @@ class MaskFedAvg:
         """masks → m ⊙ w → K masked local-optimizer steps (scan)."""
         c = self.scfg
         capacities = self.capacities if capacities is None else capacities
-        masks = dense_client_masks(rng, self.abstract, self.axes_tree, c,
-                                   capacities, round_idx)
-        w_c = jax.tree_util.tree_map(
-            lambda w, m: w[None] * m.astype(w.dtype), params, masks)
-
+        with jax.named_scope(tracing.OFFSETS):
+            masks = dense_client_masks(rng, self.abstract, self.axes_tree, c,
+                                       capacities, round_idx)
         mvg = sm.masked_value_and_grad(self.loss_fn)
         opt = self.client_opt
 
@@ -968,7 +995,11 @@ class MaskFedAvg:
                                  backend=self.kernel_backend)
             return (wc, ost), loss
 
-        (w_cK, _), losses = jax.lax.scan(kstep, (w_c, opt.init(w_c)), batch)
+        with jax.named_scope(tracing.CLIENT_PHASE):
+            w_c = jax.tree_util.tree_map(
+                lambda w, m: w[None] * m.astype(w.dtype), params, masks)
+            (w_cK, _), losses = jax.lax.scan(kstep, (w_c, opt.init(w_c)),
+                                             batch)
         return w_cK, masks, losses
 
     # -- public rounds ---------------------------------------------------------
@@ -978,10 +1009,12 @@ class MaskFedAvg:
         (heterogeneous participation — the paper's 10%-of-100-clients)."""
         w_cK, masks, losses = self._client_phase(params, batch, round_idx,
                                                  rng, capacities)
-        new = dispatch.fillin_agg(params, w_cK, masks,
-                                  server_lr=self.scfg.server_lr,
-                                  backend=self.kernel_backend)
-        new = sm.project_l2(new, self.scfg.proj_radius)
+        with jax.named_scope(tracing.AGGREGATE):
+            new = dispatch.fillin_agg(params, w_cK, masks,
+                                      server_lr=self.scfg.server_lr,
+                                      backend=self.kernel_backend)
+        with jax.named_scope(tracing.SERVER_STEP):
+            new = sm.project_l2(new, self.scfg.proj_radius)
         return new, {"loss": losses.mean(), "client_loss": losses}
 
     def round_with_server_opt(self, params, opt_state, batch, round_idx,
@@ -995,12 +1028,15 @@ class MaskFedAvg:
                 "the round with api.fed_round(..., server_opt=...)")
         w_cK, masks, losses = self._client_phase(params, batch, round_idx,
                                                  rng, capacities)
-        dbar = jax.tree_util.tree_map(
-            lambda w, ws, ms: (ms * (ws.astype(jnp.float32)
-                                     - w[None].astype(jnp.float32))).mean(0),
-            params, w_cK, masks)
-        new, opt_state = server_opt.update(params, dbar, opt_state)
-        new = sm.project_l2(new, self.scfg.proj_radius)
+        with jax.named_scope(tracing.AGGREGATE):
+            dbar = jax.tree_util.tree_map(
+                lambda w, ws, ms: (ms * (ws.astype(jnp.float32)
+                                         - w[None].astype(jnp.float32))
+                                   ).mean(0),
+                params, w_cK, masks)
+        with jax.named_scope(tracing.SERVER_STEP):
+            new, opt_state = server_opt.update(params, dbar, opt_state)
+            new = sm.project_l2(new, self.scfg.proj_radius)
         return new, opt_state, {"loss": losses.mean(),
                                 "client_loss": losses}
 

@@ -10,6 +10,11 @@ eval / logging / checkpoint callbacks, and ``--rounds`` pacing with resume
 Batch iterators yield either a batch dict (leaves [K, C, ...]) or a
 ``(batch, round_kwargs)`` pair — the kwargs are forwarded to the round
 (e.g. mask mode's per-round ``capacities``).
+
+Each round's host work is a ``repro.round`` profiler span (children
+``repro.round.put`` and ``repro.round.dispatch``) and each host sync a
+``repro.sync`` span (:mod:`repro.tracing`); :attr:`Trainer.compiles`
+counts the round's jit cache misses.
 """
 from __future__ import annotations
 
@@ -18,6 +23,9 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import jax
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+from repro import tracing
 
 
 def _record(round_idx, metrics) -> Dict[str, Any]:
@@ -53,6 +61,10 @@ class Trainer:
     metrics merged in on eval rounds — see ``eval_fn`` / ``eval_every``).
     Checkpoint periodically via :func:`checkpoint_callback`; ``start_round``
     resumes a restored schedule mid-way.
+
+    :attr:`compiles` counts the jitted round's cache misses, each a
+    compile or a load from the persistent cache: 1 after the first round,
+    and one more for every new batch shape or round kwarg.
     """
 
     fed: Any                              # WindowFedAvg | MaskFedAvg
@@ -71,6 +83,7 @@ class Trainer:
     history: List[Dict] = field(default_factory=list, init=False)
     opt_state: Any = field(default=None, init=False)
     _step: Any = field(default=None, init=False)
+    _compiles: Any = field(default=None, init=False)
 
     def __post_init__(self):
         self.round_idx = self.start_round
@@ -92,19 +105,29 @@ class Trainer:
                     params, opt_state, batch, round_idx, self.server_opt,
                     rng=rng, **kw)
         self._step = jax.jit(step) if self.jit else step
+        self._compiles = tracing.CompileCounter()
+
+    @property
+    def compiles(self) -> int:
+        """Jit cache misses of the round so far (compiled or loaded)."""
+        return self._compiles.count
 
     def step(self, batch, round_kwargs=None):
         """Run exactly one round on ``batch``; returns the history record."""
         r, kw = self.round_idx, dict(round_kwargs or {})
-        self.rng, sub = jax.random.split(self.rng)
-        if isinstance(batch, dict):
-            batch = {k: jax.numpy.asarray(v) for k, v in batch.items()}
-        if self.server_opt is None:
-            self.params, metrics = self._step(self.params, batch, r, sub,
-                                              **kw)
-        else:
-            self.params, self.opt_state, metrics = self._step(
-                self.params, self.opt_state, batch, r, sub, **kw)
+        with StepTraceAnnotation(tracing.ROUND, step_num=r):
+            self.rng, sub = jax.random.split(self.rng)
+            with TraceAnnotation(tracing.ROUND_PUT):
+                if isinstance(batch, dict):
+                    batch = {k: jax.numpy.asarray(v)
+                             for k, v in batch.items()}
+            with TraceAnnotation(tracing.ROUND_DISPATCH), self._compiles:
+                if self.server_opt is None:
+                    self.params, metrics = self._step(self.params, batch, r,
+                                                      sub, **kw)
+                else:
+                    self.params, self.opt_state, metrics = self._step(
+                        self.params, self.opt_state, batch, r, sub, **kw)
         rec = _record(r, metrics)
         self.round_idx += 1
         return rec
@@ -121,29 +144,33 @@ class Trainer:
             if self.eval_fn and (r == last or (
                     self.eval_every and r % self.eval_every == 0)):
                 # eval boundary: the sanctioned place to sync metrics
-                # repro-lint: disable=host-sync
-                rec.update({k: float(v) for k, v in
-                            self.eval_fn(self.params).items()})
+                with TraceAnnotation(tracing.SYNC):
+                    # repro-lint: disable=host-sync
+                    rec.update({k: float(v) for k, v in
+                                self.eval_fn(self.params).items()})
             self.history.append(rec)
             for cb in self.callbacks:
                 cb(r, self.params, rec)
             if self.log_every and (r % self.log_every == 0 or r == last):
                 # the log boundary is where the host sync is allowed
-                # repro-lint: disable=host-sync
-                extras = " ".join(f"{k} {float(v):.4f}"
-                                  for k, v in rec.items()
-                                  if k not in ("round", "loss")
-                                  and np.ndim(v) == 0)
-                # repro-lint: disable=host-sync
-                self.log_fn(f"round {r:4d} loss {float(rec['loss']):.4f}"
+                with TraceAnnotation(tracing.SYNC):
+                    # repro-lint: disable=host-sync
+                    extras = " ".join(f"{k} {float(v):.4f}"
+                                      for k, v in rec.items()
+                                      if k not in ("round", "loss")
+                                      and np.ndim(v) == 0)
+                    # repro-lint: disable=host-sync
+                    loss = float(rec["loss"])
+                self.log_fn(f"round {r:4d} loss {loss:.4f}"
                             + (f"  {extras}" if extras else ""))
         return self.params, self.history
 
     @property
     def losses(self) -> List[float]:
         # reporting accessor, not the hot loop: sync is the point here
-        # repro-lint: disable=host-sync
-        return [float(h["loss"]) for h in self.history]
+        with TraceAnnotation(tracing.SYNC):
+            # repro-lint: disable=host-sync
+            return [float(h["loss"]) for h in self.history]
 
 
 def checkpoint_callback(path, every=0, meta=None):
@@ -156,10 +183,11 @@ def checkpoint_callback(path, every=0, meta=None):
 
     def cb(round_idx, params, record):
         from repro.checkpoint.checkpoint import save
-        losses.append(float(record["loss"]))
-        if every and round_idx % every != 0:
-            return
-        save(path, params, {**(meta or {}), "round": round_idx + 1,
-                            "history": losses})
+        with TraceAnnotation(tracing.SYNC):
+            losses.append(float(record["loss"]))
+            if every and round_idx % every != 0:
+                return
+            save(path, params, {**(meta or {}), "round": round_idx + 1,
+                                "history": losses})
 
     return cb

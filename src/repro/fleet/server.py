@@ -34,7 +34,9 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from repro import tracing
 from repro.core import submodel as sm
 from repro.core.trainer import _record
 from repro.fleet.buffer import ClientReport, DeltaBuffer
@@ -269,9 +271,11 @@ class AsyncTrainer:
         cohort_off = {k: jnp.take(v, lanes, axis=0)
                       for k, v in offsets.items()}
         host_off = self._offsets_host[tag]
-        batch = self._next_batch(source, ids, slots)
-        delta, losses = self.fleet.run_cohort(
-            self._phase_fn(slots), self.params, batch, cohort_off)
+        with TraceAnnotation(tracing.ROUND_PUT):
+            batch = self._next_batch(source, ids, slots)
+        with TraceAnnotation(tracing.ROUND_DISPATCH):
+            delta, losses = self.fleet.run_cohort(
+                self._phase_fn(slots), self.params, batch, cohort_off)
         for j, (slot, cid) in enumerate(zip(slots, ids)):
             delay, ok = self.fleet.completion(int(cid), self._seq)
             rep = ClientReport(
@@ -327,18 +331,24 @@ class AsyncTrainer:
 
         if with_opt:
             def f(params, opt_state, delta, offsets, g):
-                delta = scaled(delta, g)
+                with jax.named_scope(tracing.AGGREGATE):
+                    delta = scaled(delta, g)
                 full = (arm._mean_delta_full_fused(delta) if fused
                         else arm._mean_delta_full(params, delta, offsets))
-                new, opt_state = server_opt.update(params, full, opt_state)
-                return sm.project_l2(new, fed.scfg.proj_radius), opt_state
+                with jax.named_scope(tracing.SERVER_STEP):
+                    new, opt_state = server_opt.update(params, full,
+                                                       opt_state)
+                    return (sm.project_l2(new, fed.scfg.proj_radius),
+                            opt_state)
         else:
             def f(params, delta, offsets, g):
-                delta = scaled(delta, g)
+                with jax.named_scope(tracing.AGGREGATE):
+                    delta = scaled(delta, g)
                 new = (arm._apply_mean_delta_fused(params, delta, offsets)
                        if fused else
                        arm._apply_mean_delta(params, delta, offsets))
-                return sm.project_l2(new, fed.scfg.proj_radius)
+                with jax.named_scope(tracing.SERVER_STEP):
+                    return sm.project_l2(new, fed.scfg.proj_radius)
 
         self._agg_cache[key] = jax.jit(f) if self.jit else f
         return self._agg_cache[key]
@@ -391,7 +401,8 @@ class AsyncTrainer:
         ticks = 0
         while self.round_idx <= last:
             if self._idle:
-                self._dispatch(source)
+                with TraceAnnotation(tracing.ROUND):
+                    self._dispatch(source)
             if not self._events:
                 raise RuntimeError("fleet deadlock: no in-flight clients "
                                    "and nothing left to dispatch")

@@ -106,6 +106,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, bq=512, bkv=512,
     out = pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, causal=causal,
                           window=window, bq=bq, bkv=bkv, nkv=nkv),
+        name="flash_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, G, hd), lambda b, i, j: (b, i, 0, 0)),

@@ -37,6 +37,7 @@ def masked_sgd_2d(p, m, g, lr, block_rows=256, interpret=True):
     spec = pl.BlockSpec((br, C), lambda i: (i, 0))
     return pl.pallas_call(
         functools.partial(_masked_sgd_kernel, lr=float(lr)),
+        name="masked_sgd",
         grid=(pl.cdiv(R, br),),
         in_specs=[spec, spec, spec],
         out_specs=spec,
@@ -59,6 +60,7 @@ def sgd_2d(p, g, lr, block_rows=256, interpret=True):
     spec = pl.BlockSpec((br, C), lambda i: (i, 0))
     return pl.pallas_call(
         functools.partial(_sgd_kernel, lr=float(lr)),
+        name="sgd_step",
         grid=(pl.cdiv(R, br),),
         in_specs=[spec, spec],
         out_specs=spec,
@@ -86,6 +88,7 @@ def fillin_agg_2d(w, w_clients, m_clients, scale, block_rows=256,
     cspec = pl.BlockSpec((Cl, br, Cols), lambda i: (0, i, 0))
     return pl.pallas_call(
         functools.partial(_fillin_kernel, scale=float(scale), n_clients=Cl),
+        name="fillin_agg",
         grid=(pl.cdiv(R, br),),
         in_specs=[wspec, cspec, cspec],
         out_specs=wspec,

@@ -66,6 +66,7 @@ def rolling_matmul(x, w, offset, win, *, bm=128, bn=128, bk=128,
     )
     return pl.pallas_call(
         functools.partial(_rolling_mm_kernel, nk=nk),
+        name="rolling_matmul_fwd",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, win), x.dtype),
         interpret=interpret,
@@ -127,6 +128,7 @@ def rolling_matmul_multi(x, ws, offset, win, *, bm=128, bn=128, bk=128,
     )
     return pl.pallas_call(
         functools.partial(_rolling_mm_multi_kernel, nk=nk),
+        name="rolling_matmul_multi",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, M, win), x.dtype),
         interpret=interpret,

@@ -84,6 +84,7 @@ def rolling_matmul_batched(x, w, offsets, win, *, bm=128, bn=128, bk=128,
     )
     return pl.pallas_call(
         functools.partial(_batched_mm_kernel, nk=nk),
+        name="rolling_matmul_batched_fwd",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, M, win), x.dtype),
         interpret=interpret,
@@ -135,6 +136,7 @@ def rolling_matmul_batched_dx(dy, w, offsets, win, *, bm=128, bn=128,
     )
     return pl.pallas_call(
         functools.partial(_batched_dx_kernel, nj=nj),
+        name="rolling_matmul_batched_dx",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, M, K), dy.dtype),
         interpret=interpret,
@@ -192,6 +194,7 @@ def rolling_matmul_batched_multi(x, ws, offsets, win, *, bm=128, bn=128,
     )
     return pl.pallas_call(
         functools.partial(_batched_mm_multi_kernel, nk=nk),
+        name="rolling_matmul_batched_multi",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, T, M, win), x.dtype),
         interpret=interpret,
@@ -247,6 +250,7 @@ def rolling_matmul_batched_dx_multi(dys, ws, offsets, win, *, bm=128,
     )
     return pl.pallas_call(
         functools.partial(_batched_dx_multi_kernel, nt=T, nj=nj),
+        name="rolling_matmul_batched_dx_multi",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, M, K), dys.dtype),
         interpret=interpret,

@@ -72,6 +72,7 @@ def rolling_matmul_dx(dy, w, offset, win, *, bm=128, bn=128, bk=128,
     )
     return pl.pallas_call(
         functools.partial(_rolling_dx_kernel, nj=nj),
+        name="rolling_matmul_dx",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, K), dy.dtype),
         interpret=interpret,
@@ -133,6 +134,7 @@ def rolling_matmul_dx_multi(dys, ws, offset, win, *, bm=128, bn=128, bk=128,
     )
     return pl.pallas_call(
         functools.partial(_rolling_dx_multi_kernel, nt=T, nj=nj),
+        name="rolling_matmul_dx_multi",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, K), dys.dtype),
         interpret=interpret,

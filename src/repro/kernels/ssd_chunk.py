@@ -90,6 +90,7 @@ def ssd_chunk_intra(x, dt, A, B, C, *, nh_block=0, interpret=True,
         assert nh % nhb == 0
         return pl.pallas_call(
             _ssd_chunk_kernel,
+            name="ssd_chunk",
             grid=(Bt, nc, nh // nhb),
             in_specs=[
                 pl.BlockSpec((1, 1, Q, nhb, hd),
@@ -131,6 +132,7 @@ def ssd_chunk_intra(x, dt, A, B, C, *, nh_block=0, interpret=True,
     )
     return pl.pallas_call(
         _ssd_chunk_kernel_offset,
+        name="ssd_chunk_offset",
         grid_spec=grid_spec,
         out_shape=out_shapes,
         interpret=interpret,

@@ -295,6 +295,8 @@ def main(argv=None):
                    mean_staleness=round(
                        sum(h["staleness"] for h in history) / len(history),
                        3))
+    else:
+        out["compiles"] = trainer.compiles
     print(json.dumps(out))
     return trainer
 

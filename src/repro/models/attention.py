@@ -8,6 +8,9 @@ attention only visits the kv chunks inside the window (sub-quadratic).
 Decode is one-token attention against a KV cache.  For `long_500k` the cache
 is sharded along the sequence dim over the mesh `data` axis and combined with
 an exact log-sum-exp psum (`cp_decode_attention`) — context-parallel decode.
+
+The attention core (scores, softmax, weighted values; not the q/k/v/o
+projections) runs under the ``model.attention`` named scope in every path.
 """
 from __future__ import annotations
 
@@ -19,6 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro import tracing
 from repro.models.layers import ParamBuilder, apply_rope, head_proj, rms_norm
 from repro.sharding.spmd import shard_map
 
@@ -86,6 +90,7 @@ _Q_CHUNK = int(os.environ.get("REPRO_ATTN_Q_CHUNK", "512"))
 _KV_CHUNK = int(os.environ.get("REPRO_ATTN_KV_CHUNK", "512"))
 
 
+@tracing.scoped(tracing.ATTENTION)
 def blockwise_attention(q, k, v, *, causal=True, window=0, q_chunk=0,
                         kv_chunk=0, softmax_scale=None):
     """q [B,Sq,H,hd]; k,v [B,Sk,KV,hd]; H % KV == 0.  Returns [B,Sq,H,hd].
@@ -159,6 +164,7 @@ def blockwise_attention(q, k, v, *, causal=True, window=0, q_chunk=0,
 # ---------------------------------------------------------------------------
 
 
+@tracing.scoped(tracing.ATTENTION)
 def decode_attention(q, k, v, valid, softmax_scale=None):
     """q [B,H,hd]; k,v [B,Sc,KV,hd]; valid [B,Sc] bool.  -> [B,H,vdim]."""
     B, H, hd = q.shape
@@ -175,6 +181,7 @@ def decode_attention(q, k, v, valid, softmax_scale=None):
     return out.reshape(B, H, -1)
 
 
+@tracing.scoped(tracing.ATTENTION)
 def cp_decode_attention(mesh, q, k, v, valid, axis="data", softmax_scale=None):
     """Context-parallel exact decode attention.
 
@@ -255,10 +262,11 @@ def gqa_train(p, x, cfg, positions, q_chunk=0, kv_chunk=0, window=None):
         # deployment path; interpret-mode on CPU hosts (see §Perf C3).
         from repro.kernels.dispatch import interpret_mode
         from repro.kernels.flash_attention import flash_attention
-        out = flash_attention(
-            q, k, v, causal=True, window=cfg.sliding_window,
-            bq=min(512, q.shape[1]), bkv=min(512, k.shape[1]),
-            interpret=interpret_mode())
+        with jax.named_scope(tracing.ATTENTION):
+            out = flash_attention(
+                q, k, v, causal=True, window=cfg.sliding_window,
+                bq=min(512, q.shape[1]), bkv=min(512, k.shape[1]),
+                interpret=interpret_mode())
     else:
         out = blockwise_attention(q, k, v, causal=True,
                                   window=cfg.sliding_window,

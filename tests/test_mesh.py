@@ -182,6 +182,31 @@ def test_mesh_round_hlo_contains_all_gather():
     assert hlo_check.has_collective(hlo, "all-gather")
 
 
+@pytest.mark.parametrize("n", MESHES)
+@pytest.mark.parametrize("agg", ["gather", "psum"])
+def test_mesh_round_phase_scopes(n, agg):
+    """The sharded round keeps the phase scopes, and the cross-chip
+    exchange (the deltas' all-gather or psum, the losses' all-gather) is
+    aggregation work."""
+    mesh = _mesh(n)
+    m, params, scfg, batch = _lm_setup()
+    sharded = api.fed_round(m, scfg, fused_forward="on", mesh=mesh,
+                            mesh_agg=agg)
+    hlo = hlo_check.compiled_text(sharded.round, params, batch, 0,
+                                  jax.random.PRNGKey(1))
+    assert {"fed.offsets", "fed.client_phase",
+            "fed.aggregate"} <= hlo_check.scopes(hlo)
+    exchange = [line for line in hlo.splitlines()
+                if " all-gather(" in line or " all-reduce(" in line
+                or "all-gather-start(" in line or "all-reduce-start(" in line]
+    assert len(exchange) > 0 or n == 1
+    for line in exchange:
+        names = hlo_check.op_names(line)
+        assert names and all(
+            hlo_check.outermost_scope(name, "fed.") == "fed.aggregate"
+            for name in names), line
+
+
 # -- validation (no extra devices needed) -------------------------------------
 
 
