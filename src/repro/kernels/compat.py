@@ -20,6 +20,8 @@ Exports
 ``smem(shape, dtype)``      SMEM scratch-shape factory.
 ``prefetch_scalar_grid_spec``  grid spec with leading scalar-prefetch
                             operands.
+``compiler_params(vmem_limit_bytes=)``  Mosaic compiler parameters: the
+                            scoped VMEM a kernel may use.
 """
 from __future__ import annotations
 
@@ -45,3 +47,9 @@ def prefetch_scalar_grid_spec(*, num_scalar_prefetch, grid, in_specs,
         num_scalar_prefetch=num_scalar_prefetch, grid=grid,
         in_specs=in_specs, out_specs=out_specs,
         scratch_shapes=scratch_shapes)
+
+
+def compiler_params(*, vmem_limit_bytes):
+    """Mosaic compiler parameters for one ``pallas_call``: the scoped VMEM
+    its blocks, their double buffers and its scratch may use."""
+    return _pltpu.CompilerParams(vmem_limit_bytes=int(vmem_limit_bytes))

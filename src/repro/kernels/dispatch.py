@@ -35,6 +35,7 @@ from repro.kernels import ref
 from repro.kernels.masked_update import sgd_2d
 from repro.kernels.ops import (_from_2d, _to_2d, fillin_agg_tree,
                                masked_sgd_tree)
+from repro.kernels.rolling_matmul import LAUNCHES, tile_vmem_bytes
 from repro.kernels.rolling_matmul import rolling_matmul as _rolling_mm_pallas
 from repro.kernels.rolling_matmul import \
     rolling_matmul_multi as _rolling_mm_multi_pallas
@@ -100,9 +101,9 @@ def _takes_pallas(backend, tileable: bool, op: str) -> bool:
 # ---------------------------------------------------------------------------
 
 #: Cache of autotuned (bm, bn, bk) triples, keyed per
-#: ((M, K, win), dtype-name, resolved-backend).  Deterministic — the tuner
-#: never times anything — so the cache is a memo, not a measurement store,
-#: and two processes always agree on the choice for a key.
+#: ((M, K, win), dtype-name, resolved-backend, role).  Deterministic — the
+#: tuner never times anything — so the cache is a memo, not a measurement
+#: store, and two processes always agree on the choice for a key.
 _AUTOTUNE_CACHE: dict = {}
 
 #: Process-wide override installed by :func:`set_block_override`
@@ -111,21 +112,33 @@ _AUTOTUNE_CACHE: dict = {}
 #: args still take precedence.  Never written into ``_AUTOTUNE_CACHE``.
 _BLOCK_OVERRIDE: tuple | None = None
 
-#: Largest candidate block edge — one 128x128 MXU tile per dimension.
+#: Largest window-side block edge — one 128-lane MXU tile.  The kernels
+#: count the window offset in blocks of this edge (``off_blocks = offset //
+#: block``), and the models' alignment certificate
+#: (``AxisWindow.aligned(min(128, win))``) vouches for exactly that grid,
+#: so this edge never grows: a coarser one would floor-round certified
+#: offsets to the wrong block.
 _BLOCK_CAP = 128
 
-#: VMEM working-set budget per kernel instance.  The grid double-buffers
-#: every operand block (that is what overlaps the next W-column fetch with
-#: the current dot), so the tuner charges 2x per input/output block plus the
-#: f32 accumulator scratch, and shrinks bk until the set fits.
-_VMEM_BUDGET_BYTES = 8 * 1024 * 1024
+#: Working-set budget of one rolling-matmul call: its operand and output
+#: blocks, double-buffered, plus the f32 accumulator
+#: (``rolling_matmul.tile_vmem_bytes``).  A v5e core has 128 MiB of VMEM;
+#: the budget plus the headroom each call adds for Mosaic's own scratch
+#: (``rolling_matmul.vmem_limit_bytes``) stays under half of it, so a
+#: kernel's limit never crowds the scoped buffers XLA's fusions take.
+_VMEM_BUDGET_BYTES = 40 * 2**20
+
+#: Which edge of each role carries the window offset: the forward's output
+#: columns ``bn``; dx's contraction ``bk`` (its output ``bn`` runs over K).
+ROLES = ("fwd", "dx")
 
 
 def _choose_block(dim: int, cap: int = _BLOCK_CAP) -> int:
     """Largest divisor of ``dim`` that is ≤ ``cap``, preferring multiples of
     8 (f32 sublane width) over raw size.  Divisors-only keeps every Pallas
-    grid exact — the kernels assert ``dim % block == 0`` — so the choice can
-    never change numerics, only tiling."""
+    grid exact — the kernels assert ``dim % block == 0`` — so no block
+    drops or repeats a row or column.  The choice is not free of numerics:
+    a contraction edge sets how the f32 partial sums over K are grouped."""
     dim = int(dim)
     if dim <= 0:
         return 1
@@ -134,31 +147,77 @@ def _choose_block(dim: int, cap: int = _BLOCK_CAP) -> int:
     return max(sublane) if sublane else max(divisors)
 
 
-def _vmem_block_bytes(bm: int, bn: int, bk: int, itemsize: int) -> int:
-    return 2 * (bm * bk + bk * bn + bm * bn) * itemsize + bm * bn * 4
+def _edge_candidates(dim: int, align: int) -> set:
+    """Block edges the tuner may give an offset-free edge of ``dim``: the
+    ≤ 128 choice of :func:`_choose_block`, and every divisor above 128 that
+    Mosaic's tiling rule admits — the whole dim or a multiple of ``align``
+    (8·4/itemsize rows, 128 lanes)."""
+    return {_choose_block(dim)} | {
+        d for d in range(_BLOCK_CAP + 1, dim + 1)
+        if dim % d == 0 and (d == dim or d % align == 0)}
 
 
-def autotune_blocks(M, K, win, dtype=jnp.float32, backend=None):
-    """Pick (bm, bn, bk) for a rolling matmul of ``x[M, K] @ W[K, off:off+
-    win]`` — deterministically, from the divisors of the operand dims.
+def _tune(M, K, win, itemsize, role):
+    """(bm, bn, bk) for one role: the window edge at :func:`_choose_block`
+    (≤ 128), the two offset-free edges the pair whose call streams the
+    fewest bytes from HBM inside the VMEM budget (fewer grid steps, then
+    the larger ``bm``, break ties).  Forward, ``x[M, K] @ W[K, win]``: W's
+    window is read once per row block and x once per window block, unless
+    ``bk == K`` keeps x's block index fixed across the window sweep.  dx,
+    ``dy[M, win] @ W[K, win]^T``: W's window is read once per row block, dy
+    once per output column block.  The ≤ 128 pair always fits, so some pair
+    does."""
+    wb = _choose_block(win)
+    rows = _edge_candidates(M, 32 // itemsize)
+    cols = _edge_candidates(K, 128)
+    if role == "fwd":
+        def cost(bm, bk):
+            x_reads = 1 if bk == K else win // wb
+            return ((M // bm) * K * win + x_reads * M * K,
+                    (M // bm) * (win // wb) * (K // bk))
 
-    Cached per ``((M, K, win), dtype, resolved backend)``; the backend is in
-    the key because the jnp arm ignores blocks while future TPU generations
-    may want different caps, and crossing keys would let one shape's choice
-    leak into another's.  Call :func:`clear_block_cache` to drop the memo
-    (tests), :func:`set_block_override` to bypass the tuner entirely.
+        def triple(bm, e):
+            return bm, wb, e
+    else:
+        def cost(bm, bn):
+            return ((M // bm) * K * win + (K // bn) * M * win,
+                    (M // bm) * (K // bn) * (win // wb))
+
+        def triple(bm, e):
+            return bm, e, wb
+    fits = [(bm, e) for bm in rows for e in cols
+            if tile_vmem_bytes(*triple(bm, e), itemsize)
+            <= _VMEM_BUDGET_BYTES]
+    return triple(*min(fits, key=lambda p: (*cost(*p), -p[0])))
+
+
+def autotune_blocks(M, K, win, dtype=jnp.float32, backend=None, role="fwd"):
+    """Pick (bm, bn, bk) for one role of a rolling matmul of ``x[M, K] @
+    W[K, off:off+win]`` — deterministically, from the divisors of the
+    operand dims and the VMEM budget.
+
+    ``role="fwd"``: ``bn`` is the window edge (the offset's block), ``bm``
+    and ``bk`` grow; ``role="dx"``: ``bk`` is the window edge, ``bm`` and
+    ``bn`` (over K) grow.  The window edge stays at :func:`_choose_block`
+    (≤ 128: the block the alignment certificate vouches for); see
+    :func:`_tune` for the offset-free edges.  The budget counts elements
+    by ``dtype``, so bfloat16 gets twice as many.
+
+    Cached per ``((M, K, win), dtype, resolved backend, role)``; the
+    backend is in the key because the jnp arm ignores blocks while future
+    TPU generations may want different budgets, and crossing keys would
+    let one shape's choice leak into another's.  Call
+    :func:`clear_block_cache` to drop the memo (tests),
+    :func:`set_block_override` to bypass the tuner entirely.
     """
+    if role not in ROLES:
+        raise ValueError(f"unknown block role {role!r}; expected {ROLES}")
     key = ((int(M), int(K), int(win)), np.dtype(dtype).name,
-           resolve_backend(backend))
+           resolve_backend(backend), role)
     hit = _AUTOTUNE_CACHE.get(key)
     if hit is not None:
         return hit
-    bm, bn, bk = _choose_block(M), _choose_block(win), _choose_block(K)
-    itemsize = np.dtype(dtype).itemsize
-    while bk > 8 and _vmem_block_bytes(bm, bn, bk,
-                                       itemsize) > _VMEM_BUDGET_BYTES:
-        bk = _choose_block(K, cap=bk // 2)
-    choice = (bm, bn, bk)
+    choice = _tune(int(M), int(K), int(win), np.dtype(dtype).itemsize, role)
     _AUTOTUNE_CACHE[key] = choice
     return choice
 
@@ -168,8 +227,10 @@ def set_block_override(blocks):
 
     The override wins over the autotuner for every dispatched rolling-matmul
     whose block args default to ``None``; explicit per-call ``bm/bn/bk``
-    still take precedence.  It is never written into the autotune cache, so
-    clearing it restores tuned behaviour without a cache flush."""
+    still take precedence.  Like explicit args it is one triple in the
+    forward's roles, which dx reads with ``bn`` over K and ``bk`` over the
+    window.  It is never written into the autotune cache, so clearing it
+    restores tuned behaviour without a cache flush."""
     global _BLOCK_OVERRIDE
     if blocks is not None:
         bm, bn, bk = (int(b) for b in blocks)
@@ -181,22 +242,44 @@ def set_block_override(blocks):
 
 
 def clear_block_cache():
-    """Drop all memoized autotune choices (test isolation)."""
+    """Drop all memoized autotune choices and the record of traced
+    launches that :func:`block_choices` reads (test isolation)."""
     _AUTOTUNE_CACHE.clear()
+    LAUNCHES.clear()
 
 
-def _resolve_blocks(M, K, win, dtype, backend, bm, bn, bk):
-    """Fill ``None`` block args: explicit call args > ``set_block_override``
-    > cached :func:`autotune_blocks` choice."""
+def block_choices():
+    """The blocks every rolling-matmul kernel was traced with in this
+    process: one entry per (kernel, operand shapes) with its ``blocks``
+    ``(bm, bn, bk)``, ``grid_steps`` a call and ``vmem_limit_bytes``,
+    sorted by kernel name.  Written at trace time, so reading it costs a
+    round nothing.  A kernel under a custom batching rule is also traced
+    unbatched (for its output type), so that form is listed beside the
+    batched one that runs."""
+    return [dict(op=name, x=list(a), w=list(w), out=list(out),
+                 blocks=list(v["blocks"]), grid_steps=v["grid_steps"],
+                 vmem_limit_bytes=v["vmem_limit_bytes"])
+            for (name, a, w, out), v in sorted(LAUNCHES.items())]
+
+
+def _resolve_blocks(M, K, win, dtype, backend, bm, bn, bk, role="fwd"):
+    """Fill ``None`` block args for ``role``: explicit call args >
+    ``set_block_override`` > cached :func:`autotune_blocks` choice."""
     if bm is not None and bn is not None and bk is not None:
         return bm, bn, bk
     if _BLOCK_OVERRIDE is not None:
         abm, abn, abk = _BLOCK_OVERRIDE
     else:
-        abm, abn, abk = autotune_blocks(M, K, win, dtype, backend)
+        abm, abn, abk = autotune_blocks(M, K, win, dtype, backend, role)
     return (abm if bm is None else bm,
             abn if bn is None else bn,
             abk if bk is None else bk)
+
+
+def _role_blocks(M, K, win, dtype, backend, bm, bn, bk):
+    """``(forward, dx)`` block triples of one dispatched call."""
+    return tuple(_resolve_blocks(M, K, win, dtype, backend, bm, bn, bk, r)
+                 for r in ROLES)
 
 
 # ---------------------------------------------------------------------------
@@ -374,25 +457,25 @@ def _rolling_dx_arm(dy, w, offset, win, backend, bm, bn, bk, assume_aligned):
         preferred_element_type=jnp.float32).astype(dy.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _rolling_mm(x, w, offset, win, backend, bm, bn, bk, assume_aligned):
-    return _rolling_fwd_arm(x, w, offset, win, backend, bm, bn, bk,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _rolling_mm(x, w, offset, win, backend, blocks, assume_aligned):
+    return _rolling_fwd_arm(x, w, offset, win, backend, *blocks[0],
                             assume_aligned)
 
 
-def _rolling_mm_fwd(x, w, offset, win, backend, bm, bn, bk, assume_aligned):
-    y = _rolling_fwd_arm(x, w, offset, win, backend, bm, bn, bk,
+def _rolling_mm_fwd(x, w, offset, win, backend, blocks, assume_aligned):
+    y = _rolling_fwd_arm(x, w, offset, win, backend, *blocks[0],
                          assume_aligned)
     return y, (x, w, offset)
 
 
-def _rolling_mm_bwd(win, backend, bm, bn, bk, assume_aligned, res, dy):
+def _rolling_mm_bwd(win, backend, blocks, assume_aligned, res, dy):
     """Custom VJP: dx through the offset-prefetch backward kernel (oracle
     fallback), dW as a window scatter-add — exactly the transpose autodiff
     derives for the slice-then-matmul oracle, so grads through the fused
     arm match grads through extract-then-matmul."""
     x, w, offset = res
-    dx = _rolling_dx_arm(dy, w, offset, win, backend, bm, bn, bk,
+    dx = _rolling_dx_arm(dy, w, offset, win, backend, *blocks[1],
                          assume_aligned)
     dw_win = jax.lax.dot_general(
         x, dy, (((0,), (0,)), ((), ())),
@@ -412,7 +495,8 @@ def rolling_matmul(x, w, offset, win, backend=None, bm=None, bn=None,
 
     Block sizes default to ``None`` = resolved at trace time via
     :func:`autotune_blocks` (explicit args > :func:`set_block_override` >
-    cached autotune choice).
+    cached autotune choice), one triple per role: the forward and dx
+    kernels each get blocks sized for the edge that carries their offset.
 
     Pallas arm fuses the window into the matmul's index_map so inactive
     columns of ``w`` are never read from HBM; jnp arm is the dynamic-slice
@@ -436,10 +520,9 @@ def rolling_matmul(x, w, offset, win, backend=None, bm=None, bn=None,
     synthesized per-element loop; the jnp oracle batches through the
     ordinary gather rules.  :func:`rolling_matmul_batched` is the same arm
     with the batch explicit in the call."""
-    bm, bn, bk = _resolve_blocks(x.shape[-2], x.shape[-1], win, x.dtype,
-                                 backend, bm, bn, bk)
-    return _rolling_mm(x, w, offset, win, backend, bm, bn, bk,
-                       assume_aligned)
+    blocks = _role_blocks(x.shape[-2], x.shape[-1], win, x.dtype, backend,
+                          bm, bn, bk)
+    return _rolling_mm(x, w, offset, win, backend, blocks, assume_aligned)
 
 
 # -- explicit batched-offset form (per-client windows, staggered schemes) ----
@@ -490,25 +573,24 @@ def _rolling_b_dx_arm(dy, w, offsets, win, backend, bm, bn, bk,
     return jax.vmap(one)(dy, w, offsets)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _rolling_mm_b(x, w, offsets, win, backend, bm, bn, bk, assume_aligned):
-    return _rolling_b_fwd_arm(x, w, offsets, win, backend, bm, bn, bk,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _rolling_mm_b(x, w, offsets, win, backend, blocks, assume_aligned):
+    return _rolling_b_fwd_arm(x, w, offsets, win, backend, *blocks[0],
                               assume_aligned)
 
 
-def _rolling_mm_b_fwd(x, w, offsets, win, backend, bm, bn, bk,
-                      assume_aligned):
-    y = _rolling_b_fwd_arm(x, w, offsets, win, backend, bm, bn, bk,
+def _rolling_mm_b_fwd(x, w, offsets, win, backend, blocks, assume_aligned):
+    y = _rolling_b_fwd_arm(x, w, offsets, win, backend, *blocks[0],
                            assume_aligned)
     return y, (x, w, offsets)
 
 
-def _rolling_mm_b_bwd(win, backend, bm, bn, bk, assume_aligned, res, dy):
+def _rolling_mm_b_bwd(win, backend, blocks, assume_aligned, res, dy):
     """Mirror of the shared-offset VJP, per batch row: dx through the
     batched offset-prefetch backward kernel (vmapped oracle fallback), dW
     as a per-row window scatter-add of ``x[b]^T @ dy[b]``."""
     x, w, offsets = res
-    dx = _rolling_b_dx_arm(dy, w, offsets, win, backend, bm, bn, bk,
+    dx = _rolling_b_dx_arm(dy, w, offsets, win, backend, *blocks[1],
                            assume_aligned)
 
     def dw_one(x_b, dy_b, off_b, w_b):
@@ -540,10 +622,11 @@ def rolling_matmul_batched(x, w, offsets, win, backend=None, bm=None,
     for concrete offsets off the block grid, and for *traced* offsets
     unless ``assume_aligned=True`` (the scheme's ``grid_multiple``
     certificate).  Custom VJP mirrors :func:`rolling_matmul` per row.
-    ``None`` block args resolve through :func:`autotune_blocks`."""
-    bm, bn, bk = _resolve_blocks(x.shape[-2], x.shape[-1], win, x.dtype,
-                                 backend, bm, bn, bk)
-    return _rolling_mm_b(x, w, offsets, win, backend, bm, bn, bk,
+    ``None`` block args resolve through :func:`autotune_blocks`, per
+    role."""
+    blocks = _role_blocks(x.shape[-2], x.shape[-1], win, x.dtype, backend,
+                          bm, bn, bk)
+    return _rolling_mm_b(x, w, offsets, win, backend, blocks,
                          assume_aligned)
 
 
@@ -663,28 +746,26 @@ def _multi_dx_arm(dys, ws, offset, win, backend, bm, bn, bk, assume_aligned):
     return out
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _rolling_mm_multi(x, ws, offset, win, backend, bm, bn, bk,
-                      assume_aligned):
-    return _multi_fwd_arm(x, ws, offset, win, backend, bm, bn, bk,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _rolling_mm_multi(x, ws, offset, win, backend, blocks, assume_aligned):
+    return _multi_fwd_arm(x, ws, offset, win, backend, *blocks[0],
                           assume_aligned)
 
 
-def _rolling_mm_multi_fwd(x, ws, offset, win, backend, bm, bn, bk,
+def _rolling_mm_multi_fwd(x, ws, offset, win, backend, blocks,
                           assume_aligned):
-    ys = _multi_fwd_arm(x, ws, offset, win, backend, bm, bn, bk,
+    ys = _multi_fwd_arm(x, ws, offset, win, backend, *blocks[0],
                         assume_aligned)
     return ys, (x, ws, offset)
 
 
-def _rolling_mm_multi_bwd(win, backend, bm, bn, bk, assume_aligned, res,
-                          dys):
+def _rolling_mm_multi_bwd(win, backend, blocks, assume_aligned, res, dys):
     """dx accumulates across the T steps inside one kernel call (oracle:
     pairwise sum of per-step dots); each dW is the same window scatter-add
     as the single-weight VJP."""
     x, ws, offset = res
     dys = tuple(dys)
-    dx = _multi_dx_arm(dys, ws, offset, win, backend, bm, bn, bk,
+    dx = _multi_dx_arm(dys, ws, offset, win, backend, *blocks[1],
                        assume_aligned)
     dws = []
     for w, dy in zip(ws, dys):
@@ -717,11 +798,11 @@ def rolling_matmul_multi(x, ws, offset, win, backend=None, bm=None, bn=None,
     op cannot move fused-vs-extract numerics on CPU.  Under ``jax.vmap``
     both Pallas halves lower to the batched-offset multi kernels (or fold
     rows when weights and offset are shared).  ``None`` block args resolve
-    through :func:`autotune_blocks`; falls back to the oracle loop for
-    untileable shapes, non-uniform weight shapes, and unaligned/traced
-    offsets without ``assume_aligned``."""
+    through :func:`autotune_blocks`, per role; falls back to the oracle
+    loop for untileable shapes, non-uniform weight shapes, and
+    unaligned/traced offsets without ``assume_aligned``."""
     ws = tuple(ws)
-    bm, bn, bk = _resolve_blocks(x.shape[-2], x.shape[-1], win, x.dtype,
-                                 backend, bm, bn, bk)
-    return _rolling_mm_multi(x, ws, offset, win, backend, bm, bn, bk,
+    blocks = _role_blocks(x.shape[-2], x.shape[-1], win, x.dtype, backend,
+                          bm, bn, bk)
+    return _rolling_mm_multi(x, ws, offset, win, backend, blocks,
                              assume_aligned)

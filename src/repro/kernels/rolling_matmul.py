@@ -15,15 +15,78 @@ Grid: (M/bm, win/bn, K/bk), K innermost for accumulator reuse; the offset
 arrives via ``pltpu.PrefetchScalarGridSpec`` and shifts the W column-block
 index.  f32 accumulation in VMEM scratch-free form (out block revisited over
 k with @pl.when init).
+
+Blocks: ``bn`` counts the offset, so it stays at the 128-lane tile the
+window's alignment is certified for; ``bm`` and ``bk`` are free, and
+``dispatch.autotune_blocks`` sizes them from VMEM.  With ``bk == K`` the x
+block's index is constant across the window sweep and the pipeline fetches
+it once per row block.  :func:`rolling_spec` builds every kernel's
+``pallas_call`` arguments: it passes a VMEM limit computed from the call's
+blocks and records them in :data:`LAUNCHES`.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.compat import pl, prefetch_scalar_grid_spec, vmem
+from repro.kernels.compat import (compiler_params, pl,
+                                  prefetch_scalar_grid_spec, vmem)
+
+#: Scoped VMEM a v5e core grants a Mosaic kernel that asks for none (16 MiB
+#: of its 128 MiB).  No rolling-matmul call asks for less than this.
+_DEFAULT_SCOPED_VMEM = 16 * 2**20
+
+#: Room above a call's own blocks for Mosaic's internal scratch (the dot's
+#: partial products, operands it re-tiles).
+_VMEM_HEADROOM = 8 * 2**20
+
+
+def tile_vmem_bytes(bm, bn, bk, itemsize):
+    """VMEM one grid step of a rolling-matmul kernel holds: the two operand
+    blocks (``[bm, bk]`` and ``[bk, bn]``; ``[bn, bk]`` for W in the dx
+    kernels) and the output block ``[bm, bn]``, each double-buffered by
+    the pipeline, plus the float32 accumulator ``[bm, bn]``."""
+    return 2 * (bm * bk + bk * bn + bm * bn) * itemsize + bm * bn * 4
+
+
+def vmem_limit_bytes(bm, bn, bk, itemsize):
+    """The ``vmem_limit_bytes`` a call with these blocks passes to Mosaic."""
+    return max(_DEFAULT_SCOPED_VMEM,
+               tile_vmem_bytes(bm, bn, bk, itemsize) + _VMEM_HEADROOM)
+
+
+#: Every rolling-matmul launch traced in this process, keyed by (kernel
+#: name, operand shapes, output shape): its ``(bm, bn, bk)``, grid steps a
+#: call and ``vmem_limit_bytes``.  Written at trace time (a jitted round
+#: records once per trace); ``dispatch.block_choices()`` reports it.
+LAUNCHES: dict = {}
+
+
+def rolling_spec(name, *, grid, in_specs, out_specs, out_shape, blocks,
+                 operands):
+    """``pallas_call`` arguments of one rolling-matmul kernel: a grid spec
+    with the scalar-prefetched offset blocks and a float32 ``[bm, bn]``
+    accumulator, the output shape, and compiler params whose VMEM limit is
+    computed from the call's own blocks.  Records the launch in
+    :data:`LAUNCHES` under ``name`` and the shapes of ``operands`` (the
+    activation or cotangent, and W)."""
+    bm, bn, bk = blocks
+    a, w = operands
+    limit = vmem_limit_bytes(bm, bn, bk, jnp.dtype(a.dtype).itemsize)
+    LAUNCHES[(name, tuple(a.shape), tuple(w.shape),
+              tuple(out_shape.shape))] = dict(
+        blocks=(bm, bn, bk), grid_steps=math.prod(grid),
+        vmem_limit_bytes=limit)
+    return dict(
+        grid_spec=prefetch_scalar_grid_spec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=[vmem((bm, bn), jnp.float32)]),
+        out_shape=out_shape,
+        compiler_params=compiler_params(vmem_limit_bytes=limit))
 
 
 def _rolling_mm_kernel(off_ref, x_ref, w_ref, o_ref, acc_ref, *, nk):
@@ -54,21 +117,19 @@ def rolling_matmul(x, w, offset, win, *, bm=128, bn=128, bk=128,
     nk = K // bk
     off_blocks = jnp.asarray(offset, jnp.int32)[None] // bn
 
-    grid_spec = prefetch_scalar_grid_spec(
-        num_scalar_prefetch=1,
-        grid=(M // bm, win // bn, nk),
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k, off: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k, off: (k, off[0] + j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, off: (i, j)),
-        scratch_shapes=[vmem((bm, bn), jnp.float32)],
-    )
     return pl.pallas_call(
         functools.partial(_rolling_mm_kernel, nk=nk),
         name="rolling_matmul_fwd",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((M, win), x.dtype),
+        **rolling_spec(
+            "rolling_matmul_fwd",
+            grid=(M // bm, win // bn, nk),
+            in_specs=[
+                pl.BlockSpec((bm, bk), lambda i, j, k, off: (i, k)),
+                pl.BlockSpec((bk, bn), lambda i, j, k, off: (k, off[0] + j)),
+            ],
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, off: (i, j)),
+            out_shape=jax.ShapeDtypeStruct((M, win), x.dtype),
+            blocks=(bm, bn, bk), operands=(x, w)),
         interpret=interpret,
     )(off_blocks, x, w)
 
@@ -114,22 +175,20 @@ def rolling_matmul_multi(x, ws, offset, win, *, bm=128, bn=128, bk=128,
     nk = K // bk
     off_blocks = jnp.asarray(offset, jnp.int32)[None] // bn
 
-    grid_spec = prefetch_scalar_grid_spec(
-        num_scalar_prefetch=1,
-        grid=(T, M // bm, win // bn, nk),
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda t, i, j, k, off: (i, k)),
-            pl.BlockSpec((1, bk, bn),
-                         lambda t, i, j, k, off: (t, k, off[0] + j)),
-        ],
-        out_specs=pl.BlockSpec((1, bm, bn),
-                               lambda t, i, j, k, off: (t, i, j)),
-        scratch_shapes=[vmem((bm, bn), jnp.float32)],
-    )
     return pl.pallas_call(
         functools.partial(_rolling_mm_multi_kernel, nk=nk),
         name="rolling_matmul_multi",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, M, win), x.dtype),
+        **rolling_spec(
+            "rolling_matmul_multi",
+            grid=(T, M // bm, win // bn, nk),
+            in_specs=[
+                pl.BlockSpec((bm, bk), lambda t, i, j, k, off: (i, k)),
+                pl.BlockSpec((1, bk, bn),
+                             lambda t, i, j, k, off: (t, k, off[0] + j)),
+            ],
+            out_specs=pl.BlockSpec((1, bm, bn),
+                                   lambda t, i, j, k, off: (t, i, j)),
+            out_shape=jax.ShapeDtypeStruct((T, M, win), x.dtype),
+            blocks=(bm, bn, bk), operands=(x, ws)),
         interpret=interpret,
     )(off_blocks, x, ws)

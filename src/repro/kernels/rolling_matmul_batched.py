@@ -39,7 +39,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.compat import pl, prefetch_scalar_grid_spec, vmem
+from repro.kernels.compat import pl
+from repro.kernels.rolling_matmul import rolling_spec
 
 
 def _batched_mm_kernel(off_ref, x_ref, w_ref, o_ref, acc_ref, *, nk):
@@ -70,23 +71,21 @@ def rolling_matmul_batched(x, w, offsets, win, *, bm=128, bn=128, bk=128,
     nk = K // bk
     off_blocks = jnp.asarray(offsets, jnp.int32) // bn
 
-    grid_spec = prefetch_scalar_grid_spec(
-        num_scalar_prefetch=1,
-        grid=(B, M // bm, win // bn, nk),
-        in_specs=[
-            pl.BlockSpec((1, bm, bk), lambda b, i, j, k, off: (b, i, k)),
-            pl.BlockSpec((1, bk, bn),
-                         lambda b, i, j, k, off: (b, k, off[b] + j)),
-        ],
-        out_specs=pl.BlockSpec((1, bm, bn),
-                               lambda b, i, j, k, off: (b, i, j)),
-        scratch_shapes=[vmem((bm, bn), jnp.float32)],
-    )
     return pl.pallas_call(
         functools.partial(_batched_mm_kernel, nk=nk),
         name="rolling_matmul_batched_fwd",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, M, win), x.dtype),
+        **rolling_spec(
+            "rolling_matmul_batched_fwd",
+            grid=(B, M // bm, win // bn, nk),
+            in_specs=[
+                pl.BlockSpec((1, bm, bk), lambda b, i, j, k, off: (b, i, k)),
+                pl.BlockSpec((1, bk, bn),
+                             lambda b, i, j, k, off: (b, k, off[b] + j)),
+            ],
+            out_specs=pl.BlockSpec((1, bm, bn),
+                                   lambda b, i, j, k, off: (b, i, j)),
+            out_shape=jax.ShapeDtypeStruct((B, M, win), x.dtype),
+            blocks=(bm, bn, bk), operands=(x, w)),
         interpret=interpret,
     )(off_blocks, x, w)
 
@@ -122,23 +121,21 @@ def rolling_matmul_batched_dx(dy, w, offsets, win, *, bm=128, bn=128,
     nj = win // bk
     off_blocks = jnp.asarray(offsets, jnp.int32) // bk
 
-    grid_spec = prefetch_scalar_grid_spec(
-        num_scalar_prefetch=1,
-        grid=(B, M // bm, K // bn, nj),
-        in_specs=[
-            pl.BlockSpec((1, bm, bk), lambda b, i, k, j, off: (b, i, j)),
-            pl.BlockSpec((1, bn, bk),
-                         lambda b, i, k, j, off: (b, k, off[b] + j)),
-        ],
-        out_specs=pl.BlockSpec((1, bm, bn),
-                               lambda b, i, k, j, off: (b, i, k)),
-        scratch_shapes=[vmem((bm, bn), jnp.float32)],
-    )
     return pl.pallas_call(
         functools.partial(_batched_dx_kernel, nj=nj),
         name="rolling_matmul_batched_dx",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, M, K), dy.dtype),
+        **rolling_spec(
+            "rolling_matmul_batched_dx",
+            grid=(B, M // bm, K // bn, nj),
+            in_specs=[
+                pl.BlockSpec((1, bm, bk), lambda b, i, k, j, off: (b, i, j)),
+                pl.BlockSpec((1, bn, bk),
+                             lambda b, i, k, j, off: (b, k, off[b] + j)),
+            ],
+            out_specs=pl.BlockSpec((1, bm, bn),
+                                   lambda b, i, k, j, off: (b, i, k)),
+            out_shape=jax.ShapeDtypeStruct((B, M, K), dy.dtype),
+            blocks=(bm, bn, bk), operands=(dy, w)),
         interpret=interpret,
     )(off_blocks, dy, w)
 
@@ -180,23 +177,22 @@ def rolling_matmul_batched_multi(x, ws, offsets, win, *, bm=128, bn=128,
     nk = K // bk
     off_blocks = jnp.asarray(offsets, jnp.int32) // bn
 
-    grid_spec = prefetch_scalar_grid_spec(
-        num_scalar_prefetch=1,
-        grid=(B, T, M // bm, win // bn, nk),
-        in_specs=[
-            pl.BlockSpec((1, bm, bk), lambda b, t, i, j, k, off: (b, i, k)),
-            pl.BlockSpec((1, 1, bk, bn),
-                         lambda b, t, i, j, k, off: (t, b, k, off[b] + j)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bm, bn),
-                               lambda b, t, i, j, k, off: (b, t, i, j)),
-        scratch_shapes=[vmem((bm, bn), jnp.float32)],
-    )
     return pl.pallas_call(
         functools.partial(_batched_mm_multi_kernel, nk=nk),
         name="rolling_matmul_batched_multi",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, T, M, win), x.dtype),
+        **rolling_spec(
+            "rolling_matmul_batched_multi",
+            grid=(B, T, M // bm, win // bn, nk),
+            in_specs=[
+                pl.BlockSpec((1, bm, bk),
+                             lambda b, t, i, j, k, off: (b, i, k)),
+                pl.BlockSpec((1, 1, bk, bn),
+                             lambda b, t, i, j, k, off: (t, b, k, off[b] + j)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, bm, bn),
+                                   lambda b, t, i, j, k, off: (b, t, i, j)),
+            out_shape=jax.ShapeDtypeStruct((B, T, M, win), x.dtype),
+            blocks=(bm, bn, bk), operands=(x, ws)),
         interpret=interpret,
     )(off_blocks, x, ws)
 
@@ -235,23 +231,21 @@ def rolling_matmul_batched_dx_multi(dys, ws, offsets, win, *, bm=128,
     nj = win // bk
     off_blocks = jnp.asarray(offsets, jnp.int32) // bk
 
-    grid_spec = prefetch_scalar_grid_spec(
-        num_scalar_prefetch=1,
-        grid=(B, M // bm, K // bn, T, nj),
-        in_specs=[
-            pl.BlockSpec((1, 1, bm, bk),
-                         lambda b, i, k, t, j, off: (b, t, i, j)),
-            pl.BlockSpec((1, 1, bn, bk),
-                         lambda b, i, k, t, j, off: (t, b, k, off[b] + j)),
-        ],
-        out_specs=pl.BlockSpec((1, bm, bn),
-                               lambda b, i, k, t, j, off: (b, i, k)),
-        scratch_shapes=[vmem((bm, bn), jnp.float32)],
-    )
     return pl.pallas_call(
         functools.partial(_batched_dx_multi_kernel, nt=T, nj=nj),
         name="rolling_matmul_batched_dx_multi",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, M, K), dys.dtype),
+        **rolling_spec(
+            "rolling_matmul_batched_dx_multi",
+            grid=(B, M // bm, K // bn, T, nj),
+            in_specs=[
+                pl.BlockSpec((1, 1, bm, bk),
+                             lambda b, i, k, t, j, off: (b, t, i, j)),
+                pl.BlockSpec((1, 1, bn, bk),
+                             lambda b, i, k, t, j, off: (t, b, k, off[b] + j)),
+            ],
+            out_specs=pl.BlockSpec((1, bm, bn),
+                                   lambda b, i, k, t, j, off: (b, i, k)),
+            out_shape=jax.ShapeDtypeStruct((B, M, K), dys.dtype),
+            blocks=(bm, bn, bk), operands=(dys, ws)),
         interpret=interpret,
     )(off_blocks, dys, ws)

@@ -27,7 +27,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.compat import pl, prefetch_scalar_grid_spec, vmem
+from repro.kernels.compat import pl
+from repro.kernels.rolling_matmul import rolling_spec
 
 
 def _rolling_dx_kernel(off_ref, dy_ref, w_ref, o_ref, acc_ref, *, nj):
@@ -60,21 +61,19 @@ def rolling_matmul_dx(dy, w, offset, win, *, bm=128, bn=128, bk=128,
     nj = win // bk
     off_blocks = jnp.asarray(offset, jnp.int32)[None] // bk
 
-    grid_spec = prefetch_scalar_grid_spec(
-        num_scalar_prefetch=1,
-        grid=(M // bm, K // bn, nj),
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, k, j, off: (i, j)),
-            pl.BlockSpec((bn, bk), lambda i, k, j, off: (k, off[0] + j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, k, j, off: (i, k)),
-        scratch_shapes=[vmem((bm, bn), jnp.float32)],
-    )
     return pl.pallas_call(
         functools.partial(_rolling_dx_kernel, nj=nj),
         name="rolling_matmul_dx",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((M, K), dy.dtype),
+        **rolling_spec(
+            "rolling_matmul_dx",
+            grid=(M // bm, K // bn, nj),
+            in_specs=[
+                pl.BlockSpec((bm, bk), lambda i, k, j, off: (i, j)),
+                pl.BlockSpec((bn, bk), lambda i, k, j, off: (k, off[0] + j)),
+            ],
+            out_specs=pl.BlockSpec((bm, bn), lambda i, k, j, off: (i, k)),
+            out_shape=jax.ShapeDtypeStruct((M, K), dy.dtype),
+            blocks=(bm, bn, bk), operands=(dy, w)),
         interpret=interpret,
     )(off_blocks, dy, w)
 
@@ -121,21 +120,19 @@ def rolling_matmul_dx_multi(dys, ws, offset, win, *, bm=128, bn=128, bk=128,
     nj = win // bk
     off_blocks = jnp.asarray(offset, jnp.int32)[None] // bk
 
-    grid_spec = prefetch_scalar_grid_spec(
-        num_scalar_prefetch=1,
-        grid=(M // bm, K // bn, T, nj),
-        in_specs=[
-            pl.BlockSpec((1, bm, bk), lambda i, k, t, j, off: (t, i, j)),
-            pl.BlockSpec((1, bn, bk),
-                         lambda i, k, t, j, off: (t, k, off[0] + j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, k, t, j, off: (i, k)),
-        scratch_shapes=[vmem((bm, bn), jnp.float32)],
-    )
     return pl.pallas_call(
         functools.partial(_rolling_dx_multi_kernel, nt=T, nj=nj),
         name="rolling_matmul_dx_multi",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((M, K), dys.dtype),
+        **rolling_spec(
+            "rolling_matmul_dx_multi",
+            grid=(M // bm, K // bn, T, nj),
+            in_specs=[
+                pl.BlockSpec((1, bm, bk), lambda i, k, t, j, off: (t, i, j)),
+                pl.BlockSpec((1, bn, bk),
+                             lambda i, k, t, j, off: (t, k, off[0] + j)),
+            ],
+            out_specs=pl.BlockSpec((bm, bn), lambda i, k, t, j, off: (i, k)),
+            out_shape=jax.ShapeDtypeStruct((M, K), dys.dtype),
+            blocks=(bm, bn, bk), operands=(dys, ws)),
         interpret=interpret,
     )(off_blocks, dys, ws)

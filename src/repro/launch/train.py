@@ -50,6 +50,7 @@ from repro import api
 from repro.checkpoint.checkpoint import save as ckpt_save
 from repro.configs.base import SubmodelConfig, get_config, get_reduced_config
 from repro.data.synthetic import lm_batches
+from repro.kernels import dispatch
 from repro.launch.mesh import host_mesh
 from repro.models import build_model
 
@@ -113,9 +114,11 @@ def main(argv=None):
     ap.add_argument("--kernel-block", default=None, metavar="BMxBNxBK",
                     help="override the rolling-matmul block autotuner with "
                          "a fixed (bm, bn, bk) triple, e.g. 128x128x64 "
-                         "(also accepts comma-separated); default: "
-                         "deterministic autotune from the operand-dim "
-                         "divisors, cached per (shape, dtype, backend)")
+                         "(also accepts comma-separated), read by dx with "
+                         "bn over K and bk over the window; default: "
+                         "deterministic autotune per role (forward, dx) "
+                         "from the operand-dim divisors and the VMEM "
+                         "budget, cached per (shape, dtype, backend)")
     ap.add_argument("--layer-unroll", default=None, metavar="N|full",
                     help="unroll the model's layer scan (N layers per "
                          "iteration, or 'full' to inline it).  Inlining "
@@ -207,7 +210,6 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     if args.kernel_block:
-        from repro.kernels import dispatch
         blocks = args.kernel_block.replace("x", ",").split(",")
         if len(blocks) != 3:
             raise SystemExit("--kernel-block expects BMxBNxBK, e.g. "
@@ -297,6 +299,7 @@ def main(argv=None):
                        3))
     else:
         out["compiles"] = trainer.compiles
+    out["block_choices"] = dispatch.block_choices()
     print(json.dumps(out))
     return trainer
 

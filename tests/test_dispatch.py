@@ -321,19 +321,23 @@ def fresh_tuner():
 @given(M=_dims, K=_dims, win=_dims)
 @settings(max_examples=100, deadline=None)
 def test_autotune_blocks_divide_and_cover(M, K, win):
-    """Every tuned (bm, bn, bk) exactly tiles its dim (the kernels assert
-    dim % block == 0), stays within the MXU-tile cap, prefers the f32
-    sublane multiple when the dim allows one, and fits the VMEM budget."""
+    """Every tuned (bm, bn, bk), in both roles, exactly tiles its dims (the
+    kernels assert dim % block == 0); the window edge — the forward's bn,
+    dx's bk — stays within the MXU-tile cap and prefers the f32 sublane
+    multiple when the dim allows one; bm keeps that preference too; and
+    the working set fits the VMEM budget."""
     dispatch.clear_block_cache()
-    bm, bn, bk = dispatch.autotune_blocks(M, K, win)
-    assert M % bm == 0 and win % bn == 0 and K % bk == 0
-    assert 1 <= bm <= 128 and 1 <= bn <= 128 and 1 <= bk <= 128
-    if M % 8 == 0:
-        assert bm % 8 == 0
-    if win % 8 == 0:
-        assert bn % 8 == 0
-    assert dispatch._vmem_block_bytes(bm, bn, bk, 4) \
-        <= dispatch._VMEM_BUDGET_BYTES or bk <= 8
+    for role in dispatch.ROLES:
+        bm, bn, bk = dispatch.autotune_blocks(M, K, win, role=role)
+        wb, kb = (bn, bk) if role == "fwd" else (bk, bn)
+        assert M % bm == 0 and win % wb == 0 and K % kb == 0
+        assert 1 <= wb <= 128
+        if M % 8 == 0:
+            assert bm % 8 == 0
+        if win % 8 == 0:
+            assert wb % 8 == 0
+        assert dispatch.tile_vmem_bytes(bm, bn, bk, 4) \
+            <= dispatch._VMEM_BUDGET_BYTES
 
 
 @given(M=_dims, K=_dims, win=_dims,
@@ -359,16 +363,20 @@ def test_autotune_cache_never_crosses_keys(M, K, win):
     dispatch.clear_block_cache()
     poisoned = (-1, -1, -1)
     backend = dispatch.resolve_backend(None)
-    dispatch._AUTOTUNE_CACHE[((M, K, win), "float32", backend)] = poisoned
+    dispatch._AUTOTUNE_CACHE[((M, K, win), "float32", backend, "fwd")] = \
+        poisoned
     # the poisoned key itself is returned verbatim (proves exact keying) ...
     assert dispatch.autotune_blocks(M, K, win, "float32") == poisoned
-    # ... while neighbouring shape keys and the other dtype are untouched
+    # ... while neighbouring shape keys, the other dtype and the other
+    # role are untouched
     for other in ((M + 1, K, win), (M, K + 1, win), (M, K, win + 1)):
         got = dispatch.autotune_blocks(*other, "float32")
         assert got != poisoned
         assert other[0] % got[0] == 0 and other[2] % got[1] == 0 \
             and other[1] % got[2] == 0
     assert dispatch.autotune_blocks(M, K, win, "bfloat16") != poisoned
+    assert dispatch.autotune_blocks(M, K, win, "float32",
+                                    role="dx") != poisoned
     dispatch.clear_block_cache()
 
 
@@ -401,6 +409,73 @@ def test_block_override_wins_over_tuner(M, K, win, ov):
     finally:
         dispatch.set_block_override(None)
         dispatch.clear_block_cache()
+
+
+# -- block autotuner: the contract at the benchmark cells' shapes -----------
+
+# (M, K, win): rows a client step feeds one call, the projection's input
+# width, the window.  ds7b-silo (2x2048 tokens, d_model 4096: q/k/v window
+# 2048, gate/up window 5504), phi3-partition (1x1024 tokens, d_model 3072:
+# 1536 and 4096), TinyLlama-1.1B at 1x512 tokens (d_ff window 2816), and
+# CPU-test shapes.
+TUNER_SHAPES = [
+    pytest.param(4096, 4096, 2048, id="ds7b-qkv"),
+    pytest.param(4096, 4096, 5504, id="ds7b-gate_up"),
+    pytest.param(1024, 3072, 1536, id="phi3-qkv"),
+    pytest.param(1024, 3072, 4096, id="phi3-gate_up"),
+    pytest.param(512, 2048, 2816, id="tinyllama-gate_up"),
+    pytest.param(128, 256, 256, id="cpu-aligned"),
+    pytest.param(96, 160, 96, id="cpu-unaligned"),
+    pytest.param(64, 96, 64, id="cpu-small"),
+]
+
+
+@pytest.mark.parametrize("M,K,win", TUNER_SHAPES)
+def test_tuner_role_contract(fresh_tuner, M, K, win):
+    """Forward and dx get their own blocks.  The edge that carries the
+    window offset (forward bn, dx bk) is min(128, win), the block the
+    models' alignment certificate vouches for; every edge divides its dim;
+    the offset-free edges (bm, forward bk, dx bn over K) exceed 128 wherever
+    the dim allows, the forward contracting all of K in one block."""
+    fwd = dispatch.autotune_blocks(M, K, win, role="fwd")
+    dx = dispatch.autotune_blocks(M, K, win, role="dx")
+    assert fwd[1] == dx[2] == min(128, win)
+    for bm, wb, kb in (fwd, (dx[0], dx[2], dx[1])):
+        assert M % bm == 0 and win % wb == 0 and K % kb == 0
+        assert bm == M or bm > 128
+        assert kb == K or kb > 128
+    assert fwd[2] == K
+
+
+@pytest.mark.parametrize("M,K,win", TUNER_SHAPES)
+def test_tuned_call_passes_a_vmem_limit_that_holds_its_blocks(
+        fresh_tuner, M, K, win):
+    """Trace (no run) one batched call and its VJP on the pallas arm: each
+    kernel records the tuner's blocks for its role, its grid steps a call,
+    and a vmem_limit_bytes that holds its double-buffered working set."""
+    B, N = 2, win + 128
+
+    def loss(x, w, offs):
+        return dispatch.rolling_matmul_batched(
+            x, w, offs, win, backend="pallas", assume_aligned=True).sum()
+
+    jax.eval_shape(jax.grad(loss, argnums=(0, 1)),
+                   jax.ShapeDtypeStruct((B, M, K), jnp.float32),
+                   jax.ShapeDtypeStruct((B, K, N), jnp.float32),
+                   jax.ShapeDtypeStruct((B,), jnp.int32))
+    got = {c["op"]: c for c in dispatch.block_choices()}
+    assert set(got) == {"rolling_matmul_batched_fwd",
+                        "rolling_matmul_batched_dx"}
+    for op, role in (("rolling_matmul_batched_fwd", "fwd"),
+                     ("rolling_matmul_batched_dx", "dx")):
+        c = got[op]
+        bm, bn, bk = dispatch.autotune_blocks(M, K, win, role=role)
+        assert tuple(c["blocks"]) == (bm, bn, bk)
+        steps = (B * (M // bm) * (win // bn) * (K // bk) if role == "fwd"
+                 else B * (M // bm) * (K // bn) * (win // bk))
+        assert c["grid_steps"] == steps
+        assert dispatch.tile_vmem_bytes(bm, bn, bk, 4) \
+            <= c["vmem_limit_bytes"] < 128 * 2**20
 
 
 def test_block_override_validates(fresh_tuner):

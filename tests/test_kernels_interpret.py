@@ -180,6 +180,106 @@ def test_rolling_matmul_batched_dx_multi_interpret(M, K, N, off, win,
     _assert_close(dx, want)
 
 
+# -- rectangular blocks, as the VMEM-sized tuner picks them ----------------
+
+# (M, K, N, win, forward (bm, bn, bk), dx (bm, bn, bk)): the window edge
+# (forward bn, dx bk) stays at 128 while the offset-free edges grow — bm
+# to 256 or more, the forward's bk to 512 or all of K, dx's bn over K.
+RECT_SHAPES = [
+    pytest.param(256, 512, 768, 256, (256, 128, 512), (256, 512, 128),
+                 id="bk=K"),
+    pytest.param(512, 1024, 1280, 384, (256, 128, 512), (512, 256, 128),
+                 id="bm256-bk512"),
+]
+
+
+@pytest.mark.parametrize("M,K,N,win,fwd,dxb", RECT_SHAPES)
+def test_batched_fwd_rect_blocks_interpret(M, K, N, win, fwd, dxb):
+    B = 3
+    bm, bn, bk = fwd
+    x, w = _xw(M, K, N, lead=(B,))
+    offs = _offsets(B, N, win, 128)
+    y = rolling_matmul_batched(x, w, offs, win, bm=bm, bn=bn, bk=bk,
+                               interpret=True)
+    want = jnp.stack([ref.rolling_matmul_ref(x[b], w[b], offs[b], win)
+                      for b in range(B)])
+    _assert_close(y, want)
+
+
+@pytest.mark.parametrize("M,K,N,win,fwd,dxb", RECT_SHAPES)
+def test_batched_dx_rect_blocks_interpret(M, K, N, win, fwd, dxb):
+    B = 3
+    bm, bn, bk = dxb
+    _, w = _xw(M, K, N, lead=(B,))
+    dy = jax.random.normal(jax.random.PRNGKey(2), (B, M, win))
+    offs = _offsets(B, N, win, 128)
+    dx = rolling_matmul_batched_dx(dy, w, offs, win, bm=bm, bn=bn, bk=bk,
+                                   interpret=True)
+    want = jnp.stack([dy[b] @ jax.lax.dynamic_slice_in_dim(
+        w[b], offs[b], win, axis=1).T for b in range(B)])
+    _assert_close(dx, want)
+
+
+@pytest.mark.parametrize("M,K,N,win,fwd,dxb", RECT_SHAPES)
+def test_batched_multi_rect_blocks_interpret(M, K, N, win, fwd, dxb):
+    B, T = 3, 2
+    bm, bn, bk = fwd
+    x, _ = _xw(M, K, N, lead=(B,))
+    ws = jax.random.normal(jax.random.PRNGKey(3), (T, B, K, N))
+    offs = _offsets(B, N, win, 128)
+    ys = rolling_matmul_batched_multi(x, ws, offs, win, bm=bm, bn=bn, bk=bk,
+                                      interpret=True)
+    want = jnp.stack([
+        jnp.stack([ref.rolling_matmul_ref(x[b], ws[t, b], offs[b], win)
+                   for t in range(T)]) for b in range(B)])
+    _assert_close(ys, want)
+
+
+@pytest.mark.parametrize("M,K,N,win,fwd,dxb", RECT_SHAPES)
+def test_batched_dx_multi_rect_blocks_interpret(M, K, N, win, fwd, dxb):
+    B, T = 3, 2
+    bm, bn, bk = dxb
+    ws = jax.random.normal(jax.random.PRNGKey(3), (T, B, K, N))
+    dys = jax.random.normal(jax.random.PRNGKey(4), (B, T, M, win))
+    offs = _offsets(B, N, win, 128)
+    dx = rolling_matmul_batched_dx_multi(dys, ws, offs, win, bm=bm, bn=bn,
+                                         bk=bk, interpret=True)
+    want = jnp.stack([
+        sum(dys[b, t] @ jax.lax.dynamic_slice_in_dim(
+            ws[t, b], offs[b], win, axis=1).T for t in range(T))
+        for b in range(B)])
+    _assert_close(dx, want)
+
+
+def test_dispatch_dx_keeps_its_window_block(monkeypatch):
+    """Role coupling: the tuner grows the forward's bk to all of K (512),
+    past the window; dx must still count the traced, certified offsets in
+    128-blocks.  Were dx to inherit the forward's triple, its window block
+    would be min(512, win) = 256 and the offset 128 would floor to 0."""
+    from repro.kernels import dispatch
+
+    dispatch.clear_block_cache()
+    monkeypatch.setattr(dispatch, "_BLOCK_OVERRIDE", None)
+    B, M, K, N, win = 2, 256, 512, 640, 256
+    assert dispatch.autotune_blocks(M, K, win, role="fwd")[2] == K
+    assert dispatch.autotune_blocks(M, K, win, role="dx")[2] == 128
+    x, w = _xw(M, K, N, lead=(B,))
+    offs = jnp.asarray([128, 384], jnp.int32)
+    ct = jax.random.normal(jax.random.PRNGKey(5), (B, M, win))
+
+    def loss(x, offs, backend, aligned):
+        y = dispatch.rolling_matmul_batched(x, w, offs, win, backend=backend,
+                                            assume_aligned=aligned)
+        return (y * ct).sum()
+
+    before = dict(dispatch.ORACLE_FALLBACKS)
+    got = jax.jit(jax.grad(lambda x, o: loss(x, o, "pallas", True)))(x, offs)
+    assert dict(dispatch.ORACLE_FALLBACKS) == before
+    want = jax.grad(lambda x, o: loss(x, o, "jnp", False))(x, offs)
+    _assert_close(got, want)
+    dispatch.clear_block_cache()
+
+
 # -- intra-chunk SSD kernel -------------------------------------------------
 
 

@@ -67,7 +67,8 @@ def _kernel_case(name, dtype, sh):
     from repro.kernels.rolling_matmul_batched import rolling_matmul_batched
     from repro.kernels.rolling_matmul_bwd import rolling_matmul_dx
 
-    bm, bn, bk = dispatch.autotune_blocks(M, K, WIN, dtype)
+    role = "dx" if name == "rolling_matmul_dx" else "fwd"
+    bm, bn, bk = dispatch.autotune_blocks(M, K, WIN, dtype, role=role)
     blocks = dict(bm=bm, bn=bn, bk=bk, interpret=False)
     off = _spec(sh, (), jnp.int32)
     w = _spec(sh, (K, N), dtype)
@@ -96,6 +97,56 @@ def test_rolling_matmul_compiles_for_v5e(one_chip, name, dtype):
     fn, args = _kernel_case(name, dtype, one_chip)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# The benchmark cells' batched calls: (clients B, rows M, d_model K; q/k/v
+# output width N and window, gate/up output width and window).
+CELLS = {
+    "ds7b-silo": (1, 4096, 4096, (4096, 2048), (11008, 5504)),
+    "phi3-partition": (2, 1024, 3072, (3072, 1536), (8192, 4096)),
+}
+
+
+def _batched_case(name, cell, sh):
+    """(fn, abstract args, vmem_limit_bytes) of one batched kernel at a
+    cell's shapes, with the blocks the tuner gives its role and the limit
+    the call passes to Mosaic."""
+    from repro.kernels import dispatch
+    from repro.kernels import rolling_matmul_batched as rmb
+    from repro.kernels.rolling_matmul import vmem_limit_bytes
+
+    B, M, K, qkv, gate_up = CELLS[cell]
+    multi = name.endswith("_multi")
+    N, win = gate_up if multi else qkv
+    role = "dx" if "_dx" in name else "fwd"
+    bm, bn, bk = dispatch.autotune_blocks(M, K, win, jnp.float32, role=role)
+    kernel = getattr(rmb, name)
+    f32 = jnp.float32
+    w = _spec(sh, (2, B, K, N) if multi else (B, K, N), f32)
+    if role == "fwd":
+        a = _spec(sh, (B, M, K), f32)
+    else:
+        a = _spec(sh, (B, 2, M, win) if multi else (B, M, win), f32)
+    return ((lambda a, w, o: kernel(a, w, o, win, bm=bm, bn=bn, bk=bk,
+                                    interpret=False)),
+            (a, w, _spec(sh, (B,), jnp.int32)),
+            vmem_limit_bytes(bm, bn, bk, 4))
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+@pytest.mark.parametrize("name", ["rolling_matmul_batched",
+                                  "rolling_matmul_batched_dx",
+                                  "rolling_matmul_batched_multi",
+                                  "rolling_matmul_batched_dx_multi"])
+def test_tuned_batched_kernels_compile_for_v5e(one_chip, name, cell):
+    """Mosaic takes the tuned blocks within the VMEM limit the call
+    passes (it refuses a kernel whose buffers exceed it)."""
+    fn, args, limit = _batched_case(name, cell, one_chip)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # above the 16 MiB a v5e kernel gets unasked: the compile checks the
+    # limit the call passes, not the default
+    assert limit > 16 * 2**20
 
 
 def test_masked_sgd_compiles_for_v5e(one_chip):
