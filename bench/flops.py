@@ -1,9 +1,9 @@
 """Operations and bytes of a round, counted from a cell's shapes.
 
-``model_flops``: what the sub-model needs per round, forward and backward
-(2 FLOPs per multiply-add; backward twice the forward): every windowed
-projection, the LM head, and causal attention over the window's heads.
-Rematerialised recompute is not counted.
+``model_flops``: what the sub-model needs per round, forward and backward,
+as the configuration's own plain reference counts it
+(``model_flops(config, mix)`` of ``bench/reference/<config["reference"]>.py``),
+so a configuration with another block brings its count with its reference.
 
 ``rolling_matmul_calls``: the calls routed through ``kernels.dispatch``'s
 rolling-matmul family per round, as they run: q/k/v projections (one
@@ -17,41 +17,16 @@ the window once; per-client windows read one window per client.
 """
 from __future__ import annotations
 
-from bench.reference.round import plan
+from bench import spec
+from bench.reference.round import sizes
 
 F32 = 4
 
 
-def _sizes(config: dict, mix: dict) -> dict:
-    p = plan(config, mix["capacity"])
-    hd = config["head_dim"]
-    return dict(D=config["hidden_size"], V=config["vocab_size"],
-                L=config["num_hidden_layers"], hd=hd,
-                nq=p.kv_win * p.group * hd, nkv=p.kv_win * hd,
-                hq=p.kv_win * p.group, F=p.ff_win,
-                S=mix["seq_len"], B=mix["seqs_per_step"],
-                C=mix["clients"], K=mix["local_steps"],
-                window=config.get("sliding_window") or 0)
-
-
-def causal_pairs(S: int, window: int = 0) -> int:
-    """(query, key) pairs a causal (optionally sliding) mask keeps."""
-    w = window or S
-    return sum(min(i + 1, w) for i in range(S))
-
-
 def model_flops(config: dict, mix: dict) -> float:
     """FLOPs the sub-model needs per round, summed over clients and local
-    steps."""
-    z = _sizes(config, mix)
-    per_layer = (z["D"] * z["nq"] * 2 + z["D"] * z["nkv"] * 2
-                 + 3 * z["D"] * z["F"])
-    params = z["L"] * per_layer + z["D"] * z["V"]
-    tokens = z["B"] * z["S"]
-    # causal attention: QK^T and PV, 2 FLOPs per pair and head dim each
-    attn_fwd = 4 * z["hd"] * z["hq"] * causal_pairs(z["S"], z["window"])
-    step = 6 * params * tokens + 3 * z["L"] * z["B"] * attn_fwd
-    return float(z["C"] * z["K"] * step)
+    steps, by the configuration's reference."""
+    return spec.reference_module(config).model_flops(config, mix)
 
 
 def _mm(M, K, N, weights=1, readers=1):
@@ -63,7 +38,7 @@ def _mm(M, K, N, weights=1, readers=1):
 
 def rolling_matmul_calls(config: dict, mix: dict):
     """``[(name, flops, bytes, calls per round)]``."""
-    z = _sizes(config, mix)
+    z = sizes(config, mix)
     shared = not mix.get("stagger", False)
     T = z["B"] * z["S"]
     M = z["C"] * T

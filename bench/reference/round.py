@@ -16,6 +16,11 @@ Runs in float32 under the highest matmul precision; ``dtype`` runs the
 whole round in another type instead (the control), and ``precision``
 takes another matmul precision (``"bfloat16"``: one bfloat16 pass of the
 operands, float32 accumulation, on a TPU).
+
+``model_flops`` counts what the round needs, for ``bench.flops`` and the
+``round_mfu_pct`` reader: 2 FLOPs per multiply-add, backward twice the
+forward, every windowed projection, the LM head and causal attention over
+the window's heads; rematerialised recompute is not counted.
 """
 from __future__ import annotations
 
@@ -48,6 +53,40 @@ def plan(config: dict, capacity: float) -> Plan:
     R = max(math.ceil(F / ff_win), math.ceil(KV / kv_win))
     return Plan(F, ff_win, KV, kv_win,
                 config["num_attention_heads"] // KV, R)
+
+
+def sizes(config: dict, mix: dict) -> dict:
+    """The sizes of one round that the counts take: model widths, the
+    window's query/key-value columns and MLP width, and the mix's job."""
+    p = plan(config, mix["capacity"])
+    hd = config["head_dim"]
+    return dict(D=config["hidden_size"], V=config["vocab_size"],
+                L=config["num_hidden_layers"], hd=hd,
+                nq=p.kv_win * p.group * hd, nkv=p.kv_win * hd,
+                hq=p.kv_win * p.group, F=p.ff_win,
+                S=mix["seq_len"], B=mix["seqs_per_step"],
+                C=mix["clients"], K=mix["local_steps"],
+                window=config.get("sliding_window") or 0)
+
+
+def causal_pairs(S: int, window: int = 0) -> int:
+    """(query, key) pairs a causal (optionally sliding) mask keeps."""
+    w = window or S
+    return sum(min(i + 1, w) for i in range(S))
+
+
+def model_flops(config: dict, mix: dict) -> float:
+    """FLOPs the sub-model needs per round, summed over clients and local
+    steps."""
+    z = sizes(config, mix)
+    per_layer = (z["D"] * z["nq"] * 2 + z["D"] * z["nkv"] * 2
+                 + 3 * z["D"] * z["F"])
+    params = z["L"] * per_layer + z["D"] * z["V"]
+    tokens = z["B"] * z["S"]
+    # causal attention: QK^T and PV, 2 FLOPs per pair and head dim each
+    attn_fwd = 4 * z["hd"] * z["hq"] * causal_pairs(z["S"], z["window"])
+    step = 6 * params * tokens + 3 * z["L"] * z["B"] * attn_fwd
+    return float(z["C"] * z["K"] * step)
 
 
 def _grid(n, w, R):

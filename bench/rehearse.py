@@ -43,7 +43,7 @@ def main(argv=None):
     # The dispatch layer asks the (CPU) backend which platform it is on.
     dispatch.on_tpu = lambda: True
     cfg = cell.model_config()
-    model = build_model(cfg, moe_path="dropping", remat=True)
+    model = build_model(cfg, remat=True)
     mesh = None
     if cell.mix.get("mesh_agg"):
         mesh = Mesh(np.array(topo.devices[:cell.chips]).reshape(cell.chips, 1),

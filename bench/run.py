@@ -17,8 +17,10 @@ and the plain reference recomputes the first three rounds
 (``bench.check``).
 
 ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` runs
-the window under the profiler and reports the per-layer metrics
-(``bench/metrics/<name>.py``).  The last line of stdout is the result;
+the window under the profiler, reduces its trace with ``bench.scopes``
+(device time by phase, ``model.*`` scope and Pallas kernel, the program's
+host spans) and reports the per-layer metrics (``bench/metrics/<name>.py``,
+each reading the :class:`Context`).  The last line of stdout is the result;
 the numbers compared, with their limits, are the last lines of stderr.
 Without a TPU, or with fewer chips than the cell asks for, the run exits
 with code 2 and prints no result.
@@ -54,7 +56,8 @@ class Context:
     window_s: float = 0.0
     feed_s: float = 0.0
     fallbacks: int = 0
-    trace: Optional[dict] = None
+    compiles: int = 0             # compiled or loaded inside the window
+    trace: Optional[dict] = None  # bench.scopes.reduce of the window
 
 
 def log(msg):
@@ -102,7 +105,7 @@ class Harness:
         self.cell = cell
         self.devices = jax.devices()[:cell.chips]
         self.cfg = cell.model_config()
-        model = build_model(self.cfg, moe_path="dropping", remat=True)
+        model = build_model(self.cfg, remat=True)
         self.mesh, replicated = None, None
         if cell.mix.get("mesh_agg"):
             from jax.sharding import NamedSharding, PartitionSpec
@@ -151,11 +154,8 @@ class Harness:
         computes it in another type (the control), ``precision`` at another
         matmul precision, ``fault`` plants a fault
         (``check.reference_readings``)."""
-        import importlib
-
         import jax.numpy as jnp
-        Reference = importlib.import_module(
-            "bench.reference." + self.cell.config["reference"]).Reference
+        Reference = spec.reference_module(self.cell.config).Reference
         if self._ref_init is None:
             self._ref_init = weights.make_init(self.abstract,
                                                self.cfg.n_layers)
@@ -231,10 +231,12 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t_start: float,
     memory_peak = _memory_peak(h.devices)
 
     ctx = Context(cell=cell, peaks=peaks, rounds=rounds, window_s=window_s,
-                  feed_s=feed_s, fallbacks=fallbacks)
+                  feed_s=feed_s, fallbacks=fallbacks,
+                  compiles=compiles_window)
     if trace:
-        from bench import trace as tr
-        ctx.trace = tr.reduce(tr.find_xplane(tracedir))
+        from bench import scopes
+        from bench.trace import find_xplane
+        ctx.trace = scopes.reduce(find_xplane(tracedir))
         shutil.rmtree(tracedir, ignore_errors=True)
 
     # Free the program's state, then the reference.

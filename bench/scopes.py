@@ -5,10 +5,12 @@
 prints the reduction of one trace as JSON.  It extends ``bench.trace``,
 whose readings it keeps: the program names the round's work by
 ``jax.named_scope`` (``fed.offsets``, ``fed.client_phase``,
-``fed.aggregate``, ``fed.server_step``, ``model.attention``; a program
-without them reads as unscoped) and its host work by
+``fed.aggregate``, ``fed.server_step``, and ``model.*`` scopes such as
+``model.attention``; a program without them reads as unscoped), its
+Pallas kernels by ``pallas_call(name=)``, and its host work by
 ``jax.profiler.TraceAnnotation`` spans (``repro.round``,
 ``repro.round.put``, ``repro.round.dispatch``, ``repro.sync``).
+``bench/run.py`` reduces every traced window with :func:`reduce`.
 
 Where a scope lands.  On a TPU device plane the name-stack path of an
 operation, e.g. ``jit(step)/transpose(jvp(fed.client_phase))/...``, is the
@@ -20,15 +22,20 @@ field numbers of ``tsl/profiler/protobuf/xplane.proto`` (as in the
 joined to ``ProfileData``'s events by (plane, metadata name).
 
 An operation's phase is the outermost ``fed.*`` component of its path, the
-``jvp(`` / ``transpose(`` wrappers of ``grad`` stripped; ``model.attention``
-is counted wherever it appears.  Device time is split so that the phases
-and the unscoped rest add up to the busy time of ``bench.trace``: each
-instant goes to the leaf operation running then, or, between the leaves
-of a loop body, to the innermost loop or call around it.
+``jvp(`` / ``transpose(`` wrappers of ``grad`` stripped; a ``model.*``
+scope is counted wherever it appears, as the leaf operations under it.
+Device time is split so that the phases and the unscoped rest add up to
+the busy time of ``bench.trace``: each instant goes to the leaf operation
+running then, or, between the leaves of a loop body, to the innermost loop
+or call around it.  A Pallas kernel's time is keyed by its name as the
+instruction carries it (:attr:`bench.trace.Op.label` after the colon, e.g.
+``rolling_matmul_batched_multi`` or ``sgd_step``).
 """
 from __future__ import annotations
 
 import argparse
+import functools
+import heapq
 import json
 import re
 import sys
@@ -40,7 +47,7 @@ from bench import trace as tr
 PHASE_PREFIX = "fed."
 PHASES = ("fed.offsets", "fed.client_phase", "fed.aggregate",
           "fed.server_step")
-ATTENTION = "model.attention"
+MODEL_PREFIX = "model."
 PROGRAM_SPAN_PREFIX = "repro."
 ROUND_SPAN = "repro.round"
 UNSCOPED = "unscoped"
@@ -161,21 +168,46 @@ def metadata_scopes(path: str) -> Dict[Tuple[str, str], str]:
     return out
 
 
+@functools.lru_cache(maxsize=None)
+def _from_text(text: str) -> Tuple[str, str]:
+    """(opcode, label) of an instruction's text."""
+    op = tr.Op(text, 0, 0)
+    return op.opcode, op.label
+
+
+@functools.lru_cache(maxsize=None)
+def _from_path(path: str) -> Tuple[str, frozenset]:
+    """(phase, ``model.*`` scopes) of a name-stack path."""
+    parts = _PATH_SEP.split(path)
+    phase = next((p for p in parts if p.startswith(PHASE_PREFIX)), UNSCOPED)
+    return phase, frozenset(p for p in parts if p.startswith(MODEL_PREFIX))
+
+
 @dataclass
 class ScopedOp(tr.Op):
+    """An operation with its scope.  A window holds about a million events
+    of a few thousand instructions and paths, so what is read from their
+    text is worked out once per text."""
+
     scope: str = ""       # the name-stack path (tf_op), "" where none
+
+    @property
+    def opcode(self) -> str:
+        return _from_text(self.name)[0]
+
+    @property
+    def label(self) -> str:
+        return _from_text(self.name)[1]
 
     @property
     def phase(self) -> str:
         """The outermost ``fed.*`` scope of the path, else ``unscoped``."""
-        for part in _PATH_SEP.split(self.scope):
-            if part.startswith(PHASE_PREFIX):
-                return part
-        return UNSCOPED
+        return _from_path(self.scope)[0]
 
     @property
-    def attention(self) -> bool:
-        return ATTENTION in _PATH_SEP.split(self.scope)
+    def model_scopes(self) -> frozenset:
+        """Every ``model.*`` component of the path."""
+        return _from_path(self.scope)[1]
 
 
 def read(path: str) -> tr.Trace:
@@ -220,13 +252,21 @@ def _split(ops: List[ScopedOp], lo: float, hi: float) -> Dict[str, float]:
             acc[o.phase] = acc.get(o.phase, 0.0) + e - s
             covered.append((s, e))
             cursor = e
+    # the rest, in time order, goes to the shortest container open at its
+    # midpoint: a heap holds the containers begun so far by rank in length,
+    # and those that have ended leave from its top
     containers = sorted((o for o in sync if not o.leaf),
                         key=lambda o: o.end - o.start)
-    rest = tr.subtract(tr._busy(sync, lo, hi), tr.union(covered))
-    for s, e in rest:
+    begun = sorted(range(len(containers)), key=lambda i: containers[i].start)
+    open_, j = [], 0
+    for s, e in tr.subtract(tr._busy(sync, lo, hi), tr.union(covered)):
         t = (s + e) / 2
-        owner = next((c for c in containers if c.start <= t < c.end), None)
-        phase = owner.phase if owner is not None else UNSCOPED
+        while j < len(begun) and containers[begun[j]].start <= t:
+            heapq.heappush(open_, begun[j])
+            j += 1
+        while open_ and containers[open_[0]].end <= t:
+            heapq.heappop(open_)
+        phase = containers[open_[0]].phase if open_ else UNSCOPED
         acc[phase] = acc.get(phase, 0.0) + e - s
     return acc
 
@@ -243,15 +283,37 @@ def phase_s(trace: tr.Trace, window: tr.Interval) -> Dict[str, float]:
     return {k: v / n * 1e-9 for k, v in sorted(total.items())}
 
 
-def attention_s(trace: tr.Trace, window: tr.Interval) -> float:
-    """Device seconds of operations under ``model.attention``, averaged
-    over the devices."""
+def _by_key(trace: tr.Trace, window: tr.Interval, keys) -> Dict[str, float]:
+    """Device seconds of the leaf operations under each key that
+    ``keys(op)`` gives, averaged over the devices."""
     lo, hi = window
-    per = [tr.length(tr.union(tr.clip(
-        [(o.start, o.end) for o in ops
-         if o.leaf and not o.asynchronous and o.attention], lo, hi)))
-        for ops in trace.devices.values()]
-    return sum(per) / len(per) * 1e-9
+    spans: Dict[str, List[tr.Interval]] = {}
+    for ops in trace.devices.values():
+        per: Dict[str, List[tr.Interval]] = {}
+        for o in ops:
+            if o.leaf and not o.asynchronous:
+                for k in keys(o):
+                    per.setdefault(k, []).append((o.start, o.end))
+        for k, iv in per.items():
+            spans.setdefault(k, []).append(tr.union(tr.clip(iv, lo, hi)))
+    n = len(trace.devices)
+    return {k: sum(map(tr.length, v)) / n * 1e-9
+            for k, v in sorted(spans.items())}
+
+
+def model_scope_s(trace: tr.Trace, window: tr.Interval) -> Dict[str, float]:
+    """Device seconds under each ``model.*`` scope, averaged over the
+    devices."""
+    return _by_key(trace, window, lambda o: o.model_scopes)
+
+
+def kernel_s(trace: tr.Trace, window: tr.Interval) -> Dict[str, float]:
+    """Device seconds of each Pallas kernel by name, averaged over the
+    devices."""
+    def name(o):
+        kind, _, ident = o.label.partition(":")
+        return [ident] if kind in ("rolling_matmul", "pallas") else []
+    return _by_key(trace, window, name)
 
 
 def span_s(trace: tr.Trace, window: tr.Interval, name: str) -> float:
@@ -264,9 +326,9 @@ def span_s(trace: tr.Trace, window: tr.Interval, name: str) -> float:
 
 def reduce(path: str) -> dict:
     """``bench.trace.reduce``'s fields, from the same events, and the
-    device seconds by phase, of the attention core, and the host seconds
-    in ``repro.round``; idle gaps take the innermost span of either
-    kind."""
+    device seconds by phase, by ``model.*`` scope and by Pallas kernel, and
+    the host seconds in ``repro.round``; idle gaps take the innermost span
+    of either kind."""
     trace = read(path)
     window = trace.window()
     coll, exposed = tr.collective_s(trace, window)
@@ -282,7 +344,8 @@ def reduce(path: str) -> dict:
                              for o in tr._ops_in(ops, *window)
                              if tr.ROLLING_MATMUL.search(o.name)),
         "phase_s": phase_s(trace, window),
-        "attention_s": attention_s(trace, window),
+        "model_scope_s": model_scope_s(trace, window),
+        "kernel_s": kernel_s(trace, window),
         "round_host_s": span_s(trace, window, ROUND_SPAN),
         "round_spans": sum(1 for s in trace.spans if s.name == ROUND_SPAN
                            and window[0] <= s.start < window[1]),
@@ -299,8 +362,8 @@ def main(argv=None) -> int:
     r = reduce(args.xplane)
     if args.rounds:
         per = {f"{k}_ms": 1e3 * v / args.rounds
-               for k, v in r["phase_s"].items()}
-        per["attention_ms"] = 1e3 * r["attention_s"] / args.rounds
+               for group in ("phase_s", "model_scope_s", "kernel_s")
+               for k, v in r[group].items()}
         per["round_host_ms"] = 1e3 * r["round_host_s"] / args.rounds
         per["busy_ms"] = 1e3 * r["busy_s"] / args.rounds
         r["per_round"] = per

@@ -1,6 +1,8 @@
 """Cells of ``BENCHMARK.json`` resolved to the files that define them."""
 from __future__ import annotations
 
+import dataclasses
+import importlib
 import importlib.util
 import json
 import os
@@ -20,6 +22,20 @@ _CONFIG_KEYS = {
     "head_dim": "head_dim", "rms_norm_eps": "norm_eps",
     "rope_theta": "rope_theta", "sliding_window": "sliding_window",
     "tie_word_embeddings": "tie_embeddings", "hidden_act": "act",
+    "first_k_dense_replace": "n_dense_layers",
+}
+#: DeepSeek-V2/V3 names (Moonlight and Kimi-K2 use them too) of the expert
+#: layer and of latent attention, and the MoEConfig / MLAConfig fields they
+#: set.  A null ``q_lora_rank`` (no q compression) becomes 0.
+_MOE_KEYS = {
+    "n_routed_experts": "n_experts", "num_experts_per_tok": "top_k",
+    "moe_intermediate_size": "d_ff", "n_shared_experts": "n_shared",
+    "scoring_func": "router",
+}
+_MLA_KEYS = {
+    "q_lora_rank": "q_lora_rank", "kv_lora_rank": "kv_lora_rank",
+    "qk_nope_head_dim": "nope_head_dim", "qk_rope_head_dim": "rope_head_dim",
+    "v_head_dim": "v_head_dim",
 }
 
 
@@ -43,13 +59,29 @@ class Cell:
 
     def model_config(self):
         """The program's ModelConfig for this configuration file: its public
-        keys, then any ModelConfig fields it sets under ``"program"``."""
-        from repro.configs.base import ModelConfig
-        kw = {field: self.config[key] for key, field in _CONFIG_KEYS.items()
-              if key in self.config}
-        kw.update(self.config.get("program", {}))
-        return ModelConfig(name=self.config["name"],
-                           family=self.config.get("family", "dense"), **kw)
+        keys, then any ModelConfig fields it sets under ``"program"``; a
+        group there (``"moe"``, ``"mla"``) sets fields of that group.  The
+        family is ``moe`` where the file has routed experts."""
+        from repro.configs.base import MLAConfig, ModelConfig, MoEConfig
+        c = self.config
+        kw = {"family": "moe" if "n_routed_experts" in c else "dense",
+              **_fields(c, _CONFIG_KEYS)}
+        if "n_routed_experts" in c:
+            kw["moe"] = MoEConfig(**_fields(c, _MOE_KEYS))
+        if "kv_lora_rank" in c:
+            mla = _fields(c, _MLA_KEYS)
+            mla["q_lora_rank"] = mla.get("q_lora_rank") or 0
+            kw["mla"] = MLAConfig(**mla)
+        groups = {"moe": MoEConfig, "mla": MLAConfig}
+        for key, value in c.get("program", {}).items():
+            if isinstance(value, dict):
+                if key not in groups:
+                    raise ValueError(f"{c['name']}: \"program\" group "
+                                     f"{key!r} is none of {sorted(groups)}")
+                value = _group(groups[key], kw.get(key), value,
+                               f"{c['name']}: program.{key}")
+            kw[key] = value
+        return ModelConfig(name=c["name"], **kw)
 
     def submodel_config(self):
         """The program's SubmodelConfig for this traffic mix.  The window
@@ -63,6 +95,22 @@ class Cell:
                               client_lr=m["client_lr"],
                               server_lr=m.get("server_lr", 1.0),
                               stagger=m.get("stagger", False), seed=0)
+
+
+def _fields(config: dict, keys: dict) -> dict:
+    return {field: config[key] for key, field in keys.items()
+            if key in config}
+
+
+def _group(cls, base, fields: dict, where: str):
+    """``base`` (or a new ``cls``) with ``fields`` set; a key that is no
+    field of ``cls`` is an error."""
+    known = {f.name for f in dataclasses.fields(cls)}
+    unknown = sorted(set(fields) - known)
+    if unknown:
+        raise ValueError(f"{where}: {unknown} are not fields of "
+                         f"{cls.__name__} ({sorted(known)})")
+    return dataclasses.replace(base, **fields) if base else cls(**fields)
 
 
 def _load_json(*parts):
@@ -103,6 +151,13 @@ def _module(kind: str, name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def reference_module(config: dict):
+    """``bench/reference/<config["reference"]>.py``: the configuration's
+    plain reference (its ``Reference`` class) and FLOP count
+    (``model_flops``)."""
+    return importlib.import_module("bench.reference." + config["reference"])
 
 
 def metric_reader(name: str):

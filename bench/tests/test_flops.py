@@ -1,20 +1,24 @@
 """The operation and byte counts, against hand counts at small sizes."""
+import sys
+import types
+
 import pytest
 
-from bench import flops
+from bench import flops, spec
+from bench.reference import round as ref_round
 from bench.tests import tiny
 
 CONFIG = dict(hidden_size=8, intermediate_size=16, num_attention_heads=4,
               num_key_value_heads=4, head_dim=2, num_hidden_layers=1,
-              vocab_size=10)
+              vocab_size=10, reference="round")
 MIX = dict(capacity=0.5, seq_len=4, seqs_per_step=1, clients=1,
            local_steps=1, stagger=False)
 
 
 def test_causal_pairs():
-    assert flops.causal_pairs(4) == 10
-    assert flops.causal_pairs(4, window=2) == 7
-    assert flops.causal_pairs(4, window=9) == 10
+    assert ref_round.causal_pairs(4) == 10
+    assert ref_round.causal_pairs(4, window=2) == 7
+    assert ref_round.causal_pairs(4, window=9) == 10
 
 
 def test_model_flops_by_hand():
@@ -61,3 +65,26 @@ def test_cells_at_their_sizes():
     part = tiny.load("traffic/partition-2c-1x1024.json")
     assert flops.model_flops(phi, part) / 4096 == pytest.approx(1.63e9,
                                                                 rel=0.02)
+
+
+#: ``model_flops`` of each cell at its full size, as the dense count gave
+#: them before it moved beside the reference (``round_mfu_pct`` reads them).
+MEASURED = {"ds7b-silo": 18116474044416.0, "phi3-partition": 6711536517120.0,
+            "ds7b-mesh4-psum": 72465896177664.0}
+
+
+@pytest.mark.parametrize("workload", sorted(MEASURED))
+def test_cells_count_as_measured(workload):
+    cell = spec.load_cell(workload)
+    assert cell.config["reference"] == "round"
+    assert flops.model_flops(cell.config, cell.mix) == MEASURED[workload]
+
+
+def test_model_flops_is_the_references_count(monkeypatch):
+    seen = []
+    fake = types.SimpleNamespace(
+        model_flops=lambda config, mix: seen.append((config, mix)) or 7.0)
+    monkeypatch.setitem(sys.modules, "bench.reference.fake", fake)
+    config = {**tiny.MOE_MLA, "reference": "fake"}
+    assert flops.model_flops(config, MIX) == 7.0
+    assert seen == [(config, MIX)]

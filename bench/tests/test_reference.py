@@ -25,6 +25,26 @@ def test_reference_matches_the_program(workload):
     assert check.moved_leaves(ref) == sorted(ref.step1)
 
 
+def test_expert_latent_round_runs_through_the_harness():
+    """The tiny expert and latent-attention configuration runs the timed
+    path's first rounds (the program's fused round) and moves every leaf;
+    its own plain reference comes with the configuration that uses it."""
+    from bench import spec
+    silo = spec.load_cell("ds7b-silo")
+    cell = spec.Cell("moe-mla", 1, dict(tiny.MOE_MLA),
+                     {**silo.mix, "seq_len": 64}, silo.limits, [], [])
+    h = Harness(cell)
+    assert h.fed.use_fused
+    trainer, feed, s32 = h.start(2**31 + 9)
+    prog, batches = h.checked_rounds(trainer, feed, s32)
+    assert prog.losses.shape == (3, 2, 1) and np.isfinite(prog.losses).all()
+    assert {k for k in prog.step1 if k.startswith("moe_layers/moe/")} == {
+        f"moe_layers/moe/{k}" for k in ("router", "w_gate", "w_up", "w_down",
+                                        "shared/w_gate", "shared/w_up",
+                                        "shared/w_down")}
+    assert min(prog.step1.values()) > 0
+
+
 @pytest.mark.parametrize("stagger", [False, True])
 @pytest.mark.parametrize("n_ff,n_kv,clients", [(11008, 32, 1), (8192, 32, 2),
                                                 (256, 4, 4), (300, 6, 3)])

@@ -35,7 +35,9 @@ def test_reduce_keeps_every_reading_of_bench_trace():
     assert {k: new[k] for k in old} == old
     assert sum(new["phase_s"].values()) == pytest.approx(new["busy_s"])
     assert set(new["phase_s"]) == {sc.UNSCOPED}
-    assert new["attention_s"] == 0.0 and new["round_host_s"] == 0.0
+    assert new["model_scope_s"] == {} and new["round_host_s"] == 0.0
+    # the fixture's one kernel, named for its jitted function
+    assert new["kernel_s"] == {"f": pytest.approx(new["rolling_matmul_s"])}
 
 
 def _op(text, start, end, scope="", asynchronous=False):
@@ -61,7 +63,8 @@ def test_hand_counted_phases_attention_and_spans():
                       "fed.aggregate": pytest.approx(20e-9),
                       sc.UNSCOPED: pytest.approx(5e-9)}
     assert sum(phases.values()) == pytest.approx(tr.busy_s(t, w))
-    assert sc.attention_s(t, w) == pytest.approx(20e-9)
+    assert sc.model_scope_s(t, w) == {"model.attention":
+                                      pytest.approx(20e-9)}
     assert sc.span_s(t, w, sc.ROUND_SPAN) == pytest.approx(80e-9)
     # the longest gap, [135, 200], lies in repro.round.dispatch at its
     # midpoint: the innermost span of either kind
@@ -73,8 +76,10 @@ def test_phase_is_the_outermost_fed_scope():
     op = _op(FUSION, 0, 1, "jit(r)/transpose(jvp(fed.aggregate))/"
                            "fed.client_phase/x")
     assert op.phase == "fed.aggregate"
-    assert not op.attention
-    assert _op(FUSION, 0, 1, ATTN).attention
+    assert op.model_scopes == set()
+    assert _op(FUSION, 0, 1, ATTN).model_scopes == {"model.attention"}
+    nested = _op(FUSION, 0, 1, "jit(r)/model.moe/jvp(model.attention)/x")
+    assert nested.model_scopes == {"model.moe", "model.attention"}
     assert _op(FUSION, 0, 1, "jit(r)/model.attentions/x").phase == sc.UNSCOPED
 
 
@@ -170,6 +175,55 @@ def test_scoped_trace_pallas_ops_carry_kernel_names():
         assert any(k in o.label for k in ("rolling_matmul_batched_multi",
                                           "sgd_step")), o.label
         assert o.phase == "fed.client_phase"
+
+
+def test_scoped_trace_kernels_and_model_scopes_add_up(scoped):
+    kernels = scoped["kernel_s"]
+    # the names of test_scoped_trace_pallas_ops_carry_kernel_names
+    assert len(kernels) == 2 and all(
+        any(k in name for k in ("rolling_matmul_batched_multi", "sgd_step"))
+        for name in kernels)
+    rolling = [v for k, v in kernels.items() if "rolling_matmul" in k]
+    assert sum(rolling) == pytest.approx(scoped["rolling_matmul_s"],
+                                         rel=1e-12)
+    assert 0 < sum(kernels.values()) <= scoped["phase_s"]["fed.client_phase"]
+    assert sum(scoped["model_scope_s"].values()) <= scoped["busy_s"]
+
+
+def _split_by_scan(ops, lo, hi):
+    """``scopes._split`` as first written, kept as the pin: each rest
+    interval looks for its container by a scan of them all."""
+    sync = [o for o in ops if not o.asynchronous and o.end > lo
+            and o.start < hi]
+    leaves = sorted((o for o in sync if o.leaf), key=lambda o: o.start)
+    acc, covered, cursor = {}, [], lo
+    for o in leaves:
+        s, e = max(o.start, cursor), min(o.end, hi)
+        if e > s:
+            acc[o.phase] = acc.get(o.phase, 0.0) + e - s
+            covered.append((s, e))
+            cursor = e
+    containers = sorted((o for o in sync if not o.leaf),
+                        key=lambda o: o.end - o.start)
+    for s, e in tr.subtract(tr._busy(sync, lo, hi), tr.union(covered)):
+        t = (s + e) / 2
+        owner = next((c for c in containers if c.start <= t < c.end), None)
+        phase = owner.phase if owner is not None else sc.UNSCOPED
+        acc[phase] = acc.get(phase, 0.0) + e - s
+    return acc
+
+
+def test_split_as_by_scan():
+    t = sc.read(SCOPED)
+    lo, hi = t.window()
+    for ops in t.devices.values():
+        assert sc._split(ops, lo, hi) == _split_by_scan(ops, lo, hi)
+    # nested and overlapping containers, and rest outside any
+    ops = [_op(WHILE, 0, 100, CLIENT), _op(WHILE, 10, 50, AGG),
+           _op(WHILE, 40, 90, "jit(r)/fed.offsets/while"),
+           _op(FUSION, 20, 48, CLIENT), _op(FUSION, 60, 70, CLIENT),
+           _op(WHILE, 95, 120, "")]
+    assert sc._split(ops, 0, 130) == _split_by_scan(ops, 0, 130)
 
 
 def test_scoped_trace_keeps_bench_trace_readings(scoped):

@@ -74,15 +74,65 @@ def test_unknown_generator_is_refused():
         spec.feed_class("no-such-generator")
 
 
-def test_four_chip_cell_in_waiting_resolves():
-    from bench.tests import tiny
-    bench = spec.load_benchmark()
-    bench["workloads"].append(tiny.MESH4)
-    cell = spec.load_cell(tiny.MESH4["name"], bench)
+def test_four_chip_cell_in_waiting_resolves(bench):
+    """The accepted four-chip cell: its limits, a mix split over its chips
+    by ``mesh_agg``, and the collective readers that apply to it alone."""
+    cell = spec.load_cell("ds7b-mesh4-psum", bench)
+    assert cell.chips == 4
     assert set(cell.limits) == set(check.NUMBERS)
     assert cell.mix["clients"] % cell.chips == 0 and cell.mix["mesh_agg"]
-    for name in ("collective_ms", "collective_exposed_pct"):
+    collective = {"collective_ms", "collective_exposed_pct"}
+    assert collective <= {m["name"] for m in cell.per_layer}
+    for name in collective:
         assert callable(spec.metric_reader(name))
+    for w in bench["workloads"]:
+        if w["chips"] == 1:
+            assert not collective & {m["name"] for m in
+                                     spec.load_cell(w["name"]).per_layer}
+
+
+def test_expert_and_latent_keys_resolve_to_the_program(tmp_path):
+    """A configuration file with DeepSeek-V2-Lite's public expert and
+    latent-attention keys resolves through ``load_cell`` to the program's
+    ModelConfig, with no other file than its own."""
+    from bench.tests import tiny
+    path = tmp_path / "deepseek-v2-lite-tiny.json"
+    path.write_text(json.dumps(tiny.MOE_MLA))
+    bench = spec.load_benchmark()
+    bench["configs"].append({"name": "deepseek-v2-lite-tiny",
+                             "file": str(path)})
+    # the cell borrows ds7b-silo's name for its traffic mix and limits
+    bench["workloads"] = [{"name": "ds7b-silo", "config":
+                           "deepseek-v2-lite-tiny", "traffic": "silo-2x2048",
+                           "chips": 1}]
+    cfg = spec.load_cell("ds7b-silo", bench).model_config()
+    assert cfg.family == "moe" and cfg.n_dense_layers == 1
+    assert (cfg.moe.n_experts, cfg.moe.top_k, cfg.moe.d_ff,
+            cfg.moe.n_shared, cfg.moe.router) == (8, 6, 88, 2, "softmax")
+    assert (cfg.mla.q_lora_rank, cfg.mla.kv_lora_rank,
+            cfg.mla.nope_head_dim, cfg.mla.rope_head_dim,
+            cfg.mla.v_head_dim) == (48, 32, 8, 4, 8)
+    assert (cfg.d_ff, cfg.n_layers, cfg.d_model) == (684, 3, 128)
+
+
+def _cfg(**config):
+    return spec.Cell("c", 1, {"name": "c", **config}, {}, {}, [],
+                     []).model_config()
+
+
+def test_null_q_rank_and_program_groups():
+    from bench.tests import tiny
+    base = {**tiny.MOE_MLA, "q_lora_rank": None}
+    assert _cfg(**base).mla.q_lora_rank == 0
+    cfg = _cfg(**base, program={"moe": {"capacity_factor": 2.0}})
+    assert cfg.moe.capacity_factor == 2.0 and cfg.moe.n_experts == 8
+    dense = _cfg(**tiny.TINY)
+    assert dense.family == "dense" and dense.moe is None \
+        and dense.mla is None
+    with pytest.raises(ValueError, match="capacity"):
+        _cfg(**base, program={"moe": {"capacity": 2.0}})
+    with pytest.raises(ValueError, match="group"):
+        _cfg(**base, program={"router": {"kind": "softmax"}})
 
 
 def test_metrics(bench):

@@ -12,20 +12,25 @@ TINY = dict(hidden_size=128, intermediate_size=256, num_attention_heads=4,
             num_key_value_heads=4, head_dim=32, num_hidden_layers=2,
             vocab_size=512)
 
-#: The four-chip cell whose traffic mix, limits and per-layer readers are
-#: in place, for a later ``BENCHMARK.json`` entry; its path is tested here.
-MESH4 = {"name": "ds7b-mesh4-psum", "config": "deepseek-llm-7b",
-         "traffic": "mesh4-psum-2x2048", "chips": 4,
-         "why": "4 clients sharded one per chip, each the silo job: the "
-                "cross-chip psum of f32 window partials"}
+#: DeepSeek-V2-Lite's public keys (huggingface.co/deepseek-ai/
+#: DeepSeek-V2-Lite, config.json) with its widths divided down by 16 and
+#: 8 of its 64 experts: one leading dense layer, then expert layers with
+#: shared experts and softmax routing, and latent attention.  The
+#: published ``q_lora_rank`` is null (no q compression); it is set here
+#: because the program's latent attention always compresses q.
+MOE_MLA = dict(
+    name="deepseek-v2-lite-tiny", reference="round", hidden_size=128,
+    intermediate_size=684, num_attention_heads=4, num_key_value_heads=4,
+    num_hidden_layers=3, first_k_dense_replace=1, vocab_size=512,
+    n_routed_experts=8, num_experts_per_tok=6, moe_intermediate_size=88,
+    n_shared_experts=2, scoring_func="softmax", q_lora_rank=48,
+    kv_lora_rank=32, qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8,
+    rms_norm_eps=1e-6, rope_theta=10000.0, hidden_act="silu",
+    tie_word_embeddings=False)
 
 
 def cell(workload: str, seq_len: int = 64) -> spec.Cell:
-    bench = spec.load_benchmark()
-    if workload == MESH4["name"] and all(
-            w["name"] != workload for w in bench["workloads"]):
-        bench["workloads"].append(MESH4)
-    real = spec.load_cell(workload, bench)
+    real = spec.load_cell(workload)
     config = {**real.config, **TINY}
     if config.get("sliding_window"):
         config["sliding_window"] = seq_len // 2

@@ -5,7 +5,9 @@ The benchmark makes the weights itself (not through the program's own
 same seed without taking anything the program made.  The layout is the
 program's parameter tree; the scales follow the usual rules: norms at 1,
 the embedding at 0.02, each attention output projection at
-0.02 / sqrt(2 x layers), every other matrix at 1 / sqrt(fan-in).
+0.02 / sqrt(2 x layers), every other matrix at 1 / sqrt(fan-in).  An
+expert stack (``[E, in, out]`` under a ``moe`` group, after the layer
+axis) takes each expert's own fan-in, ``in``.
 """
 from __future__ import annotations
 
@@ -43,14 +45,17 @@ def path_name(path) -> str:
 def _scale(path, shape, n_layers):
     """Standard deviation of the leaf, or None for a norm weight (ones)."""
     name = path[-1]
-    if len(shape) - (path[0] in STACKS) == 1:
+    stacked = path[0] in STACKS
+    rank = len(shape) - stacked
+    if rank == 1:
         return None
     if name == "embed":
         return 0.02
     if name == "wo":
         return 0.02 / math.sqrt(2 * max(n_layers, 1))
-    fan_in = shape[1] if path[0] in STACKS else shape[0]
-    return 1.0 / math.sqrt(fan_in)
+    if "moe" in path[:-1] and rank == 3:
+        return 1.0 / math.sqrt(shape[-2])
+    return 1.0 / math.sqrt(shape[1] if stacked else shape[0])
 
 
 def _unflatten(items):
