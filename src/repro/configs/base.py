@@ -26,6 +26,24 @@ class MoEConfig:
     router: str = "softmax"        # "softmax" (mixtral) | "sigmoid" (deepseek-v3)
     capacity_factor: float = 1.25  # dispatch capacity factor
     aux_loss_weight: float = 0.01  # load-balance loss weight
+    # Router width: the experts of the whole layer, of which this model
+    # holds ``n_experts`` (ids 0 .. n_experts-1, the first share of a
+    # contiguous expert split).  0 = ``n_experts``: the layer is whole.
+    router_experts: int = 0
+    # softmax router: True takes the softmax over the top-k logits (the
+    # chosen weights sum to 1); False keeps the top-k probabilities of the
+    # softmax over every expert as they are (DeepSeek-V2).
+    norm_topk_prob: bool = True
+    aux_loss: str = "switch"       # "switch" (top-1) | "seq" (DeepSeek-V2)
+
+    @property
+    def n_router(self) -> int:
+        return self.router_experts or self.n_experts
+
+    @property
+    def is_share(self) -> bool:
+        """True when this model holds a share of a wider expert layer."""
+        return self.n_router != self.n_experts
 
 
 @dataclass(frozen=True)
@@ -40,11 +58,19 @@ class SSMConfig:
 
 @dataclass(frozen=True)
 class MLAConfig:
-    q_lora_rank: int = 1536
+    q_lora_rank: int = 1536        # 0 = no q compression: wq [D, H, qh]
     kv_lora_rank: int = 512
     rope_head_dim: int = 64        # decoupled rope dims (shared k_rope)
     nope_head_dim: int = 128
     v_head_dim: int = 128
+    # YaRN rope scaling of the decoupled rope dims (DeepSeek-V2's
+    # ``rope_scaling``); factor 0 = plain rope.
+    yarn_factor: float = 0.0
+    yarn_original_max: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -98,7 +124,10 @@ class ModelConfig:
             if self.mla is not None:
                 m = self.mla
                 qh = m.nope_head_dim + m.rope_head_dim
-                per += D * m.q_lora_rank + m.q_lora_rank * self.n_heads * qh
+                if m.q_lora_rank:
+                    per += D * m.q_lora_rank + m.q_lora_rank * self.n_heads * qh
+                else:
+                    per += D * self.n_heads * qh
                 per += D * (m.kv_lora_rank + m.rope_head_dim)
                 per += m.kv_lora_rank * self.n_heads * (m.nope_head_dim + m.v_head_dim)
                 per += self.n_heads * m.v_head_dim * D
@@ -114,7 +143,7 @@ class ModelConfig:
         if self.moe is not None:
             mo = self.moe
             n_moe = L - self.n_dense_layers
-            per_moe = (mo.n_experts + mo.n_shared) * 3 * D * mo.d_ff + D * mo.n_experts
+            per_moe = (mo.n_experts + mo.n_shared) * 3 * D * mo.d_ff + D * mo.n_router
             n += n_moe * per_moe + self.n_dense_layers * 3 * D * F
             n += L * per + 2 * L * D
             return n

@@ -355,11 +355,28 @@ def _offset_aligned(offset, block, assume_aligned):
         return assume_aligned
 
 
-def _rolling_tileable(M, K, win, offset, bm, bn, bk, assume_aligned):
-    """Static check that the forward Pallas grid divides evenly and the
-    offset lands on a ``bn`` (output-column) block boundary."""
+#: Lanes of one vreg: Mosaic takes a block whose last (lane) dim is a
+#: multiple of this or the array's whole dim, and refuses any other.
+_LANES = 128
+
+
+def _lane_block_ok(block: int, full: int) -> bool:
+    """True when a window-edge block of ``block`` lanes over a weight whose
+    windowed dim is ``full`` wide meets Mosaic's tiling rule.  The window
+    edge is the lane dim of the weight's block, of the forward's output
+    and of dx's incoming cotangent; a window whose block is neither a
+    multiple of 128 nor the whole dim (a 5472- or 704-wide window takes
+    96 or 88, a window narrower than 128 all of itself) never reaches
+    Mosaic, which interpret mode would not catch."""
+    return block % _LANES == 0 or block == full
+
+
+def _rolling_tileable(M, K, N, win, offset, bm, bn, bk, assume_aligned):
+    """Static check that the forward Pallas grid divides evenly, its
+    window block is a lane tile Mosaic takes, and the offset lands on a
+    ``bn`` (output-column) block boundary."""
     bm, bn, bk = min(bm, M), min(bn, win), min(bk, K)
-    if M % bm or win % bn or K % bk:
+    if M % bm or win % bn or K % bk or not _lane_block_ok(bn, N):
         return False
     return _offset_aligned(offset, bn, assume_aligned)
 
@@ -404,8 +421,9 @@ def _pallas_fwd(x, w, offset, win, bm, bn, bk):
 def _rolling_fwd_arm(x, w, offset, win, backend, bm, bn, bk, assume_aligned):
     M, K = x.shape
     if _takes_pallas(backend,
-                     _rolling_tileable(M, K, win, offset, bm, bn, bk,
-                                       assume_aligned), "rolling_matmul"):
+                     _rolling_tileable(M, K, w.shape[-1], win, offset, bm,
+                                       bn, bk, assume_aligned),
+                     "rolling_matmul"):
         return _pallas_fwd(x, w, offset, win, bm, bn, bk)
     return ref.rolling_matmul_ref(x, w, offset, win)
 
@@ -448,6 +466,7 @@ def _rolling_dx_arm(dy, w, offset, win, backend, bm, bn, bk, assume_aligned):
     K = w.shape[0]
     bm_, bn_, bk_ = min(bm, M), min(bn, K), min(bk, win)
     tileable = (M % bm_ == 0 and K % bn_ == 0 and win % bk_ == 0
+                and _lane_block_ok(bk_, w.shape[-1])
                 and _offset_aligned(offset, bk_, assume_aligned))
     if _takes_pallas(backend, tileable, "rolling_matmul_dx"):
         return _pallas_dx(dy, w, offset, win, bm, bn, bk)
@@ -543,6 +562,7 @@ def _rolling_b_fwd_arm(x, w, offsets, win, backend, bm, bn, bk,
     _, M, K = x.shape
     bm_, bn_, bk_ = min(bm, M), min(bn, win), min(bk, K)
     tileable = (M % bm_ == 0 and win % bn_ == 0 and K % bk_ == 0
+                and _lane_block_ok(bn_, w.shape[-1])
                 and _batched_offsets_aligned(offsets, bn_, assume_aligned))
     if _takes_pallas(backend, tileable, "rolling_matmul_batched"):
         return _rolling_mm_batched_pallas(x, w, offsets, win, bm=bm, bn=bn,
@@ -558,6 +578,7 @@ def _rolling_b_dx_arm(dy, w, offsets, win, backend, bm, bn, bk,
     K = w.shape[1]
     bm_, bn_, bk_ = min(bm, M), min(bn, K), min(bk, win)
     tileable = (M % bm_ == 0 and K % bn_ == 0 and win % bk_ == 0
+                and _lane_block_ok(bk_, w.shape[-1])
                 and _batched_offsets_aligned(offsets, bk_, assume_aligned))
     if _takes_pallas(backend, tileable, "rolling_matmul_batched_dx"):
         return _rolling_dx_batched_pallas(dy, w, offsets, win, bm=bm, bn=bn,
@@ -673,7 +694,8 @@ def _pallas_multi_fwd(x, ws, offset, win, bm, bn, bk):
 def _multi_fwd_arm(x, ws, offset, win, backend, bm, bn, bk, assume_aligned):
     M, K = x.shape
     uniform = len({w.shape for w in ws}) == 1
-    tileable = uniform and _rolling_tileable(M, K, win, offset, bm, bn, bk,
+    tileable = uniform and _rolling_tileable(M, K, ws[0].shape[-1], win,
+                                             offset, bm, bn, bk,
                                              assume_aligned)
     if _takes_pallas(backend, tileable, "rolling_matmul_multi"):
         ys = _pallas_multi_fwd(x, jnp.stack(ws), offset, win, bm, bn, bk)
@@ -726,6 +748,7 @@ def _multi_dx_arm(dys, ws, offset, win, backend, bm, bn, bk, assume_aligned):
     uniform = len({w.shape for w in ws}) == 1
     tileable = (uniform and M % bm_ == 0 and K % bn_ == 0
                 and win % bk_ == 0
+                and _lane_block_ok(bk_, ws[0].shape[-1])
                 and _offset_aligned(offset, bk_, assume_aligned))
     if _takes_pallas(backend, tileable, "rolling_matmul_multi_dx"):
         return _pallas_multi_dx(jnp.stack(dys), jnp.stack(ws), offset, win,

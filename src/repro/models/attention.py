@@ -23,7 +23,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro import tracing
-from repro.models.layers import ParamBuilder, apply_rope, head_proj, rms_norm
+from repro.models.layers import (ParamBuilder, apply_rope, head_proj,
+                                 rms_norm, yarn_freqs, yarn_get_mscale)
 from repro.sharding.spmd import shard_map
 
 
@@ -53,12 +54,16 @@ def attn_params(b: ParamBuilder, prefix, cfg, layers=0):
 def mla_params(b: ParamBuilder, prefix, cfg, layers=0):
     m, D, H = cfg.mla, cfg.d_model, cfg.n_heads
     qh = m.nope_head_dim + m.rope_head_dim
-    b.dense(f"{prefix}/w_dq", (D, m.q_lora_rank), ("d_model", "mla_q_rank"),
-            layers=layers)
-    b.const(f"{prefix}/q_norm", (m.q_lora_rank,), ("mla_q_rank",), 1.0,
-            layers=layers)
-    b.dense(f"{prefix}/w_uq", (m.q_lora_rank, H, qh),
-            ("mla_q_rank", "heads", "head_dim"), layers=layers)
+    if m.q_lora_rank:
+        b.dense(f"{prefix}/w_dq", (D, m.q_lora_rank),
+                ("d_model", "mla_q_rank"), layers=layers)
+        b.const(f"{prefix}/q_norm", (m.q_lora_rank,), ("mla_q_rank",), 1.0,
+                layers=layers)
+        b.dense(f"{prefix}/w_uq", (m.q_lora_rank, H, qh),
+                ("mla_q_rank", "heads", "head_dim"), layers=layers)
+    else:  # no q compression (DeepSeek-V2-Lite): one direct projection
+        b.dense(f"{prefix}/wq", (D, H, qh), ("d_model", "heads", "head_dim"),
+                layers=layers)
     b.dense(f"{prefix}/w_dkv", (D, m.kv_lora_rank), ("d_model", "mla_kv_rank"),
             layers=layers)
     b.const(f"{prefix}/kv_norm", (m.kv_lora_rank,), ("mla_kv_rank",), 1.0,
@@ -330,19 +335,45 @@ def gqa_decode(p, x, cfg, cache, pos, mesh=None, cp=False,
 # ---------------------------------------------------------------------------
 
 
+def _mla_rope(x, positions, cfg):
+    """Rope of the decoupled rope dims, YaRN-scaled where the config says
+    so (the cos/sin factor mscale / mscale_all_dim; the softmax scale's
+    factor is :func:`_mla_scale`'s)."""
+    m = cfg.mla
+    if not m.yarn_factor:
+        return apply_rope(x, positions, cfg.rope_theta)
+    freqs = yarn_freqs(m.rope_head_dim, cfg.rope_theta, m.yarn_factor,
+                       m.yarn_beta_fast, m.yarn_beta_slow,
+                       m.yarn_original_max)
+    scale = (yarn_get_mscale(m.yarn_factor, m.yarn_mscale)
+             / yarn_get_mscale(m.yarn_factor, m.yarn_mscale_all_dim))
+    return apply_rope(x, positions, cfg.rope_theta, freqs=freqs, scale=scale)
+
+
+def _mla_scale(cfg):
+    """Softmax scale: 1/sqrt(q head dim), times mscale_all_dim's YaRN
+    factor squared when that is set."""
+    m = cfg.mla
+    scale = 1.0 / math.sqrt(m.nope_head_dim + m.rope_head_dim)
+    if m.yarn_factor and m.yarn_mscale_all_dim:
+        scale *= yarn_get_mscale(m.yarn_factor, m.yarn_mscale_all_dim) ** 2
+    return scale
+
+
 def _mla_q(p, x, cfg, positions, hspec=None, backend=None):
     m = cfg.mla
-    cq = rms_norm(x @ p["w_dq"], p["q_norm"], cfg.norm_eps)
-    q = head_proj(cq, p["w_uq"], hspec, backend)
+    if m.q_lora_rank:
+        cq = rms_norm(x @ p["w_dq"], p["q_norm"], cfg.norm_eps)
+        q = head_proj(cq, p["w_uq"], hspec, backend)
+    else:
+        q = head_proj(x, p["wq"], hspec, backend)
     q_nope, q_rope = q[..., :m.nope_head_dim], q[..., m.nope_head_dim:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-    return q_nope, q_rope
+    return q_nope, _mla_rope(q_rope, positions, cfg)
 
 
 def _mla_ckv(p, x, cfg, positions):
     c = rms_norm(x @ p["w_dkv"], p["kv_norm"], cfg.norm_eps)
-    kr = apply_rope((x @ p["w_kr"])[:, :, None, :], positions,
-                    cfg.rope_theta)[:, :, 0]
+    kr = _mla_rope((x @ p["w_kr"])[:, :, None, :], positions, cfg)[:, :, 0]
     return c, kr
 
 
@@ -352,7 +383,8 @@ def mla_train(p, x, cfg, positions, q_chunk=0, kv_chunk=0, window=None):
     ``window`` (a ``WindowMap`` or None) applies a *standalone* ``heads``
     window: unlike GQA there is no kv grouping to couple to — every head
     draws its k/v from the shared compressed ``c`` — so the per-head
-    up-projections (``w_uq``/``w_uk``/``w_uv``) window independently via
+    projections (``w_uq``, or ``wq`` without q compression, and
+    ``w_uk``/``w_uv``) window independently via
     :func:`repro.models.layers.head_proj` and ``wo`` contracts over the
     active heads only.  The shared low-rank down-projections and the
     decoupled rope key stay full (they carry no ``heads`` axis)."""
@@ -367,7 +399,7 @@ def mla_train(p, x, cfg, positions, q_chunk=0, kv_chunk=0, window=None):
                               + (m.rope_head_dim,))
     q = jnp.concatenate([q_nope, q_rope], -1)
     k = jnp.concatenate([k_nope, k_rope], -1)
-    scale = 1.0 / math.sqrt(m.nope_head_dim + m.rope_head_dim)
+    scale = _mla_scale(cfg)
     # pad v to k's head_dim so blockwise_attention can share hd, then slice
     pad = k.shape[-1] - v.shape[-1]
     vp = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, pad)))
@@ -391,7 +423,6 @@ def mla_prefill(p, x, cfg, positions):
 def mla_decode(p, x, cfg, cache, pos, mesh=None, cp=False,
                valid_override=None, rope_pos=None):
     """Absorbed path — attends in compressed space; cache {c:[B,S,r], kr}."""
-    m = cfg.mla
     B = x.shape[0]
     posv = rope_pos[:, None] if rope_pos is not None \
         else jnp.full((B, 1), pos)
@@ -407,7 +438,7 @@ def mla_decode(p, x, cfg, cache, pos, mesh=None, cp=False,
     S = cc.shape[1]
     valid = valid_override if valid_override is not None else \
         jnp.broadcast_to(jnp.arange(S) <= pos, (B, S))
-    scale = 1.0 / math.sqrt(m.nope_head_dim + m.rope_head_dim)
+    scale = _mla_scale(cfg)
     if cp and mesh is not None:
         ctx = cp_decode_attention(mesh, q_cat, k_cat, v, valid,
                                   softmax_scale=scale)

@@ -10,9 +10,9 @@ single source of truth consumed by
 
 Axis names used across the zoo::
 
-  layers vocab d_model d_ff heads kv_heads head_dim experts moe_d_ff
-  ssm_heads ssm_head_dim ssm_state conv_w mla_q_rank mla_kv_rank rope_dim
-  v_head_dim codebooks vision_d none
+  layers vocab d_model d_ff heads kv_heads head_dim experts router_experts
+  moe_d_ff ssm_heads ssm_head_dim ssm_state conv_w mla_q_rank mla_kv_rank
+  rope_dim v_head_dim codebooks vision_d none
 """
 from __future__ import annotations
 
@@ -162,13 +162,51 @@ def rope_freqs(head_dim, theta):
                             / head_dim))
 
 
-def apply_rope(x, positions, theta):
-    """x: [..., S, H, hd]; positions: [..., S] (broadcastable)."""
+def yarn_get_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature factor ``0.1 * mscale * ln(factor) + 1``
+    (1 for a factor of at most 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(dim, theta, beta_fast, beta_slow, original_max):
+    """``(low, high)`` rope-frequency indices between which YaRN blends
+    interpolated into extrapolated frequencies: the index at which a
+    frequency turns ``beta`` times over the original context,
+    ``d(beta) = dim * ln(original_max / (2 pi beta)) / (2 ln theta)``,
+    floored for ``beta_fast`` and ceiled for ``beta_slow``."""
+    def d(beta):
+        return (dim * math.log(original_max / (beta * 2 * math.pi))
+                / (2 * math.log(theta)))
+    return (max(math.floor(d(beta_fast)), 0),
+            min(math.ceil(d(beta_slow)), dim - 1))
+
+
+def yarn_freqs(head_dim, theta, factor, beta_fast, beta_slow, original_max):
+    """DeepSeek-V2's YaRN inverse frequencies: the plain ones (``extra``)
+    below the correction range, ``extra / factor`` above it, and a linear
+    blend across it."""
+    extra = rope_freqs(head_dim, theta)
+    low, high = yarn_correction_range(head_dim, theta, beta_fast, beta_slow,
+                                      original_max)
+    high = high + 0.001 if low == high else high
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return extra / factor * (1.0 - keep) + extra * keep
+
+
+def apply_rope(x, positions, theta, freqs=None, scale=1.0):
+    """x: [..., S, H, hd]; positions: [..., S] (broadcastable).  ``freqs``
+    ([hd/2], default the plain ``rope_freqs``) and ``scale`` (cos/sin
+    factor) carry a rope scaling such as YaRN."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # [hd/2]
+    if freqs is None:
+        freqs = rope_freqs(hd, theta)                   # [hd/2]
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., S, hd/2]
     cos = jnp.cos(angles)[..., None, :]                 # [..., S, 1, hd/2]
     sin = jnp.sin(angles)[..., None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

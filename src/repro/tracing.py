@@ -18,6 +18,10 @@ Scopes of the federated round (``core/fedavg.py``):
 
 ``model.attention`` (``models/attention.py``) covers the attention core:
 scores, softmax and the weighted values, not the q/k/v/o projections.
+``model.moe`` (``models/moe.py``) covers the whole expert layer, with the
+children ``model.moe.dispatch`` (routing and top-k, the balance loss, the
+dense path's weighted combine) and ``model.moe.experts`` (the expert
+matmuls, forward and backward).
 
 Host spans of the trainers (``core/trainer.py``, ``fleet/server.py``):
 ``repro.round`` is one round's host work, with the children
@@ -38,6 +42,9 @@ CLIENT_PHASE = "fed.client_phase"
 AGGREGATE = "fed.aggregate"
 SERVER_STEP = "fed.server_step"
 ATTENTION = "model.attention"
+MOE = "model.moe"
+MOE_DISPATCH = "model.moe.dispatch"
+MOE_EXPERTS = "model.moe.experts"
 
 ROUND = "repro.round"
 ROUND_PUT = "repro.round.put"

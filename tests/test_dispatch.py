@@ -452,8 +452,9 @@ def test_tuned_call_passes_a_vmem_limit_that_holds_its_blocks(
         fresh_tuner, M, K, win):
     """Trace (no run) one batched call and its VJP on the pallas arm: each
     kernel records the tuner's blocks for its role, its grid steps a call,
-    and a vmem_limit_bytes that holds its double-buffered working set."""
-    B, N = 2, win + 128
+    and a vmem_limit_bytes that holds its double-buffered working set.  A
+    window off the 128-lane grid reaches Mosaic only as the whole dim."""
+    B, N = 2, win + 128 if win % 128 == 0 else win
 
     def loss(x, w, offs):
         return dispatch.rolling_matmul_batched(
@@ -485,13 +486,16 @@ def test_block_override_validates(fresh_tuner):
     dispatch.set_block_override(None)
 
 
-def test_autotuned_rolling_matmul_matches_oracle(fresh_tuner):
+def test_autotuned_rolling_matmul_matches_oracle(fresh_tuner, monkeypatch):
     """End to end: dispatch.rolling_matmul with tuner-chosen blocks (block
-    args left None) == the jnp oracle on an unaligned-tail shape."""
-    M, K, N, off, win = 96, 160, 288, 32, 96
+    args left None) == the jnp oracle on a shape with unaligned M and K
+    tails, its window on the 128-lane grid so that the Pallas arm runs."""
+    monkeypatch.setattr(dispatch, "ORACLE_FALLBACKS", Counter())
+    M, K, N, off, win = 96, 160, 384, 128, 256
     x = jax.random.normal(jax.random.PRNGKey(0), (M, K))
     w = jax.random.normal(jax.random.PRNGKey(1), (K, N))
     y = dispatch.rolling_matmul(x, w, off, win, backend="pallas")
+    assert not dispatch.ORACLE_FALLBACKS
     np.testing.assert_allclose(np.asarray(y),
                                np.asarray(ref.rolling_matmul_ref(x, w, off,
                                                                  win)),

@@ -356,8 +356,10 @@ _EXPERTS_MLA_CACHE = []
 
 def _experts_mla_maxdelta():
     """fused-vs-extract round maxdelta for the one known-caveat point: an
-    ``experts`` window on the MLA+shared+sigmoid family, K>1 local steps.
-    Computed once, shared by the tolerance pin and the 0-ulp xfail."""
+    ``experts`` window on the MLA+shared+sigmoid family, K>1 local steps,
+    the largest over the two rounds the bitwise MULTI_AXIS matrix runs
+    (each round from the fused round's parameters, as there).  Computed
+    once, shared by the tolerance pin and the 0-ulp xfail."""
     if not _EXPERTS_MLA_CACHE:
         cfg = replace(get_reduced_config("deepseek_v3_671b"), n_layers=2)
         m = build_model(cfg, remat=False)
@@ -367,10 +369,14 @@ def _experts_mla_maxdelta():
                               axes=("experts",))
         fused, extract = _pair(m, scfg)
         batch = _batch(cfg)
-        pf, _ = jax.jit(fused.round)(params, batch, 0, jax.random.PRNGKey(1))
-        pe, _ = jax.jit(extract.round)(params, batch, 0,
-                                       jax.random.PRNGKey(1))
-        _EXPERTS_MLA_CACHE.append(_maxdelta(pf, pe))
+        step_f, step_e = jax.jit(fused.round), jax.jit(extract.round)
+        worst = 0.0
+        for r in range(2):
+            pf, _ = step_f(params, batch, r, jax.random.PRNGKey(1))
+            pe, _ = step_e(params, batch, r, jax.random.PRNGKey(1))
+            worst = max(worst, _maxdelta(pf, pe))
+            params = pf
+        _EXPERTS_MLA_CACHE.append(worst)
     return _EXPERTS_MLA_CACHE[0]
 
 
@@ -378,18 +384,22 @@ def test_fused_experts_window_mla_family_close():
     """Known f32 caveat (pre-dates the fused staggered arm): an `experts`
     window on the MLA+shared+sigmoid family with K>1 local steps agrees
     with extract only to float32 roundoff — XLA reassociates the scanned
-    client phase differently for the two program shapes.  Pinned here as a
-    tolerance so a real regression (>> 1 ulp) still fails; every other
-    family/axis combination in this file is pinned at exactly 0."""
+    client phase differently for the two program shapes.  On jax 0.9 the
+    first round agrees to 0 ulp and the second does not (1 ulp).  Pinned
+    here as a tolerance over both rounds so a real regression (>> 1 ulp)
+    still fails; every other family/axis combination in this file is
+    pinned at exactly 0."""
     assert _experts_mla_maxdelta() <= 5e-7
 
 
 @pytest.mark.xfail(strict=True,
                    reason="documented caveat: experts windows with K>1 on "
                           "the MLA family agree with extract to f32 "
-                          "roundoff only, not 0 ulp.  If this starts "
-                          "PASSING (strict xfail -> suite failure), XLA "
-                          "stopped reassociating the two program shapes "
+                          "roundoff only, not 0 ulp, over the two rounds "
+                          "of the bitwise matrix (round 0 is 0 ulp on jax "
+                          "0.9, round 1 is not).  If this starts PASSING "
+                          "(strict xfail -> suite failure), XLA stopped "
+                          "reassociating the two program shapes "
                           "differently: delete both pins and fold the arch "
                           "into the bitwise MULTI_AXIS matrix above.")
 def test_fused_experts_window_mla_family_zero_ulp():

@@ -10,6 +10,7 @@ The mesh paths are covered in ``tests/test_mesh.py``.
 """
 import ast
 import os
+import re
 from dataclasses import replace
 
 import jax
@@ -91,6 +92,29 @@ def test_attention_scope_on_forward_and_backward_ops():
     # the attention core sits inside the client phase, never outside it
     assert {hlo_check.outermost_scope(n, "fed.") for n in attn} == {
         tracing.CLIENT_PHASE}
+
+
+def test_expert_scopes_on_forward_and_backward_ops():
+    """A share of an expert layer (the router over twice the experts held)
+    puts ``model.moe`` and its ``dispatch`` and ``experts``
+    children on the round's forward and transposed instructions."""
+    base = get_reduced_config("deepseek_v3_671b")
+    cfg = replace(base, n_layers=2, vocab=64, moe=replace(
+        base.moe, router="softmax", router_experts=2 * base.moe.n_experts,
+        norm_topk_prob=False, aux_loss="seq"))
+    model = build_model(cfg, remat=True)
+    scfg = SubmodelConfig(scheme="rolling", capacity=0.5, local_steps=2,
+                          clients_per_round=1, client_lr=0.1)
+    fed = api.fed_round(model, scfg)
+    assert fed.use_fused
+    batch = {k: jnp.asarray(v) for k, v in
+             next(lm_batches(cfg.vocab, (2, 1, 1), 16, seed=0)).items()}
+    names = hlo_check.op_names(_round_hlo(
+        fed, model.init(jax.random.PRNGKey(0)), batch))
+    for scope in (tracing.MOE, tracing.MOE_DISPATCH, tracing.MOE_EXPERTS):
+        ops = [n for n in names if scope in re.split(r"[/()]", n)]
+        assert any("transpose(" not in n for n in ops), (scope, "forward")
+        assert any("transpose(" in n for n in ops), (scope, "backward")
 
 
 def test_outermost_scope_strips_grad_wrappers():
