@@ -32,9 +32,7 @@ from jax import custom_batching
 
 from repro.core import submodel as sm
 from repro.kernels import ref
-from repro.kernels.masked_update import sgd_2d
-from repro.kernels.ops import (_from_2d, _to_2d, fillin_agg_tree,
-                               masked_sgd_tree)
+from repro.kernels.ops import fillin_agg_tree, masked_sgd_tree
 from repro.kernels.rolling_matmul import LAUNCHES, tile_vmem_bytes
 from repro.kernels.rolling_matmul import rolling_matmul as _rolling_mm_pallas
 from repro.kernels.rolling_matmul import \
@@ -299,16 +297,20 @@ def masked_sgd(params, masks, grads, lr, backend=None):
 
 def sgd_step(params, grads, lr, backend=None):
     """Unmasked client update w ← w − η·g (window mode trains compact
-    sub-models, so no mask exists)."""
-    if resolve_backend(backend) == "jnp":
-        return jax.tree_util.tree_map(
-            lambda p, g: p - lr * g.astype(p.dtype), params, grads)
-    interp = interpret_mode()
+    sub-models, so no mask exists), in f32 and cast back to each leaf's
+    dtype.
+
+    One arm on every backend: XLA's own elementwise fusion on each leaf as
+    it lies.  A Pallas call would need the leaf flattened and padded into
+    its rows × lanes layout, which on a TPU is a physical relayout of the
+    whole carried tree (and a custom call forces the default layout on
+    the rest).  ``backend`` is kept so the client optimizers that route
+    through here keep one signature."""
+    del backend
 
     def leaf(p, g):
-        p2, shape, pad = _to_2d(p)
-        g2, _, _ = _to_2d(g.astype(p.dtype))
-        return _from_2d(sgd_2d(p2, g2, lr, interpret=interp), shape, pad)
+        return (p.astype(jnp.float32)
+                - lr * g.astype(jnp.float32)).astype(p.dtype)
 
     return jax.tree_util.tree_map(leaf, params, grads)
 

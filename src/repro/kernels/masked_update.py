@@ -8,8 +8,11 @@ training:
 
 Both kernels operate on 2-D tiles (rows × 128-lane multiples, 8-sublane
 aligned) — ``ops.py`` flattens/pads arbitrary parameter leaves into this
-layout.  Validated against ``ref.py`` in interpret mode on CPU; TPU is the
-compile target (VMEM-resident tiles, VPU elementwise).
+layout.  On a TPU that flatten is a physical relayout of each leaf, not a
+bitcast, so the hot unmasked update of window mode (w ← w − η·g) has no
+kernel here: ``dispatch.sgd_step`` leaves it to XLA's fusion on each leaf
+as it lies.  Validated against ``ref.py`` in interpret mode on CPU; TPU is
+the compile target (VMEM-resident tiles, VPU elementwise).
 """
 from __future__ import annotations
 
@@ -44,29 +47,6 @@ def masked_sgd_2d(p, m, g, lr, block_rows=256, interpret=True):
         out_shape=jax.ShapeDtypeStruct(p.shape, p.dtype),
         interpret=interpret,
     )(p, m, g)
-
-
-def _sgd_kernel(p_ref, g_ref, o_ref, *, lr):
-    o_ref[...] = (p_ref[...].astype(jnp.float32)
-                  - lr * g_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
-
-
-def sgd_2d(p, g, lr, block_rows=256, interpret=True):
-    """Unmasked client update w ← w − η·g (window mode trains the compact
-    sub-model, so there is no mask to apply); same fused RMW layout as
-    ``masked_sgd_2d``."""
-    R, C = p.shape
-    br = min(block_rows, R)
-    spec = pl.BlockSpec((br, C), lambda i: (i, 0))
-    return pl.pallas_call(
-        functools.partial(_sgd_kernel, lr=float(lr)),
-        name="sgd_step",
-        grid=(pl.cdiv(R, br),),
-        in_specs=[spec, spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct(p.shape, p.dtype),
-        interpret=interpret,
-    )(p, g)
 
 
 def _fillin_kernel(w_ref, wc_ref, mc_ref, o_ref, *, scale, n_clients):

@@ -6,8 +6,9 @@ makes that update a plug-point so both executable forms of the round
 richer local optimizers without touching the round code:
 
 * ``client_sgd``      — the paper's update (default); routes through the
-  dispatched kernels (``dispatch.sgd_step`` / ``dispatch.masked_sgd``) so
-  backend equivalence (pallas == jnp) holds per local step.
+  dispatch layer: ``dispatch.sgd_step`` (one XLA elementwise arm on every
+  backend) in window mode, ``dispatch.masked_sgd`` (pallas == jnp per
+  local step) in mask mode.
 * ``client_momentum`` — heavy-ball local steps; the velocity lives in the
   scan carry and is discarded at round end (state is round-local, exactly
   like the paper's client state).

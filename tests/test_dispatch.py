@@ -2,7 +2,8 @@
 
 * compat.py resolves Pallas TPU symbols on the installed JAX, and is the
   ONLY module importing ``jax.experimental.pallas.tpu`` (grep assertion).
-* every dispatched op's pallas arm matches its jnp-oracle arm,
+* every dispatched op's pallas arm matches its jnp-oracle arm; the plain
+  client update has one arm, the f32 formula bit for bit,
 * a full ``MaskFedAvg.round`` is backend-equivalent (max|Δ| < 1e-5 fp32),
 * ``WindowFedAvg.round_with_server_opt`` honors the importance scheme.
 """
@@ -92,10 +93,33 @@ def test_masked_sgd_arms_match():
                         dispatch.masked_sgd(p, m, g, 0.07, backend="jnp"))
 
 
-def test_sgd_step_arms_match():
-    p, g = _tree(), _tree(1)
-    _assert_trees_close(dispatch.sgd_step(p, g, 0.07, backend="pallas"),
-                        dispatch.sgd_step(p, g, 0.07, backend="jnp"))
+# a norm, odd 2-D sides, a stacked expert leaf, the Phi-3 head's 32,064
+# columns (off the 128-lane grid) and a bf16 leaf
+SGD_LEAVES = {
+    "norm": ((33,), jnp.float32),
+    "7x13": ((7, 13), jnp.float32),
+    "12x96": ((12, 96), jnp.float32),
+    "experts": ((2, 16, 1408), jnp.float32),
+    "head": ((2, 24, 32064), jnp.float32),
+    "bf16": ((12, 96), jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("backend", ["pallas", "jnp"])
+@pytest.mark.parametrize("leaf", sorted(SGD_LEAVES))
+def test_sgd_step_is_the_f32_update_bit_for_bit(leaf, backend):
+    """One arm on every backend: the update's formula on each leaf as it
+    lies, f32 arithmetic cast back to the leaf's dtype."""
+    shape, dtype = SGD_LEAVES[leaf]
+    k = jax.random.PRNGKey(len(shape))
+    p = jax.random.normal(k, shape).astype(dtype)
+    g = jax.random.normal(jax.random.fold_in(k, 1), shape).astype(dtype)
+    got = dispatch.sgd_step({"w": p}, {"w": g}, 0.07, backend=backend)["w"]
+    want = (p.astype(jnp.float32)
+            - 0.07 * g.astype(jnp.float32)).astype(dtype)
+    assert got.shape == shape and got.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)),
+                                  np.asarray(want.astype(jnp.float32)))
 
 
 def test_fillin_agg_arms_match():

@@ -160,6 +160,25 @@ def test_masked_sgd_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_sgd_step_updates_leaves_in_place_for_v5e(one_chip):
+    """The client update runs on each leaf in its own layout: no reshape,
+    pad or copy of the carried tree around it, and no custom call (which
+    would force the default layout) -- here on phi-3-mini's stacked
+    two-client leaves, the 32,064-wide head off the 128-lane grid."""
+    from repro.kernels import dispatch
+
+    tree = {name: _spec(one_chip, shape, jnp.float32)
+            for name, shape in (("w_up", (2, 3072, 8192)),
+                                ("w_down", (2, 8192, 3072)),
+                                ("head", (2, 3072, 32064)))}
+    compiled = jax.jit(
+        lambda p, g: dispatch.sgd_step(p, g, 0.05, backend="pallas")
+    ).lower(tree, tree).compile()
+    text = compiled.as_text()
+    for op in ("reshape(", "pad(", "copy(", "tpu_custom_call"):
+        assert op not in text, op
+
+
 def test_fused_round_compiles_for_v5e(one_chip, monkeypatch):
     """One fused window round at TinyLlama-1.1B published widths, cut to 2
     layers and 2 clients: compiles with the Pallas kernels in it and fits

@@ -160,7 +160,7 @@ def test_every_pallas_call_has_a_literal_unique_name():
         assert isinstance(kw["name"], ast.Constant) and isinstance(
             kw["name"].value, str), f"{fname}:{call.lineno} name= not literal"
         names.append(kw["name"].value)
-    assert len(names) == 14
+    assert len(names) == 13
     assert len(set(names)) == len(names), sorted(names)
 
 
