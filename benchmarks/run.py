@@ -21,7 +21,6 @@ System benches:
                        under straggler fractions {0, 0.25, 0.5}
   fed_round_mesh       shard_map round on a forced-host-device mesh:
                        bitwise gate vs single device + 2k-client scale arm
-  roofline             aggregate the dry-run JSONs into the roofline table
 
 Prints ``name,metric,value`` CSV rows and writes
 experiments/bench_results.json.
@@ -29,7 +28,6 @@ experiments/bench_results.json.
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import os
 import time
@@ -756,19 +754,6 @@ def fed_round_mesh(rounds):
 C_OVERRIDE = None  # test hook: shrink the scale arm's client count
 
 
-def roofline(rounds):
-    files = sorted(glob.glob("experiments/dryrun/*.json"))
-    if not files:
-        emit("roofline", "note", "no dryrun JSONs; run repro.launch.dryrun")
-        return
-    for f in files:
-        with open(f) as fh:
-            r = json.load(fh)
-        tag = f"{r['arch']}.{r['shape']}.{r['mesh']}"
-        emit("roofline", f"{tag}.bottleneck", r["bottleneck"])
-        emit("roofline", f"{tag}.step_lb_s", f"{r['step_lb_s']:.4g}")
-
-
 BENCHES = {
     "fig1_heterogeneity": fig1_heterogeneity,
     "fig2_low_hetero": fig2_low_hetero,
@@ -783,7 +768,6 @@ BENCHES = {
     "fed_round_fused": fed_round_fused,
     "fed_round_async": fed_round_async,
     "fed_round_mesh": fed_round_mesh,
-    "roofline": roofline,
 }
 
 
@@ -882,7 +866,6 @@ BENCH_SCHEMA = {
                     "scale_round_maxdelta": str, "vmap_round_ms": _NUM},
         "gates": ["mesh_round_bitwise_equal"],
     },
-    "roofline": {"metrics": {}},
     "curves": {"metrics": {}},
     "paper_protocol": {"metrics": {}},
 }

@@ -40,11 +40,7 @@ NUMPY_ONLY = {
     "repro/fleet/simulator.py",
     "repro/data/federated.py",
     "repro/data/synthetic.py",
-    "repro/analysis/report.py",
-    "repro/analysis/hlo.py",
     "repro/analysis/hlo_check.py",
-    "repro/analysis/hlo_cost.py",
-    "repro/analysis/roofline.py",
     "repro/checkpoint/checkpoint.py",
 }
 NUMPY_ONLY_PREFIXES = ("repro/analysis/lint/",)

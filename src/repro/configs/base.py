@@ -1,4 +1,4 @@
-"""Config system: architecture + input-shape + run configs.
+"""Config system: architecture configs + the sub-model training config.
 
 Every assigned architecture gets a ``ModelConfig`` in its own module under
 ``repro.configs``; the registry maps ``--arch <id>`` to it.  ``reduced()``
@@ -101,7 +101,7 @@ class ModelConfig:
     n_codebooks: int = 0           # musicgen: EnCodec codebook streams
     vision_stub: bool = False      # phi-3-vision: patch-embedding frontend
     vision_d: int = 1024           # stub patch-embedding width
-    vision_patches: int = 256      # patches prepended in train/prefill
+    vision_patches: int = 256      # patches prepended to the tokens
     source: str = ""               # citation
 
     def __post_init__(self):
@@ -165,27 +165,6 @@ class ModelConfig:
 
 
 # ---------------------------------------------------------------------------
-# Input shapes (assigned)
-# ---------------------------------------------------------------------------
-
-
-@dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str  # "train" | "prefill" | "decode"
-
-
-INPUT_SHAPES = {
-    "train_4k": ShapeConfig("train_4k", 4096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524288, 1, "decode"),
-}
-
-
-# ---------------------------------------------------------------------------
 # Sub-model training (the paper's technique) run config
 # ---------------------------------------------------------------------------
 
@@ -220,7 +199,7 @@ class SubmodelConfig:
     axes: Tuple[str, ...] = ("d_ff", "heads", "kv_heads", "experts",
                              "ssm_heads", "moe_d_ff")
     local_steps: int = 2           # K
-    clients_per_round: int = 16    # C, laid out on the mesh `data` (x pod) axis
+    clients_per_round: int = 16    # C, laid out on the mesh `data` axis
     client_lr: float = 0.05        # eta
     server_lr: float = 1.0
     proj_radius: float = 0.0       # W: l2 projection radius (0 = off)
@@ -234,17 +213,6 @@ class SubmodelConfig:
     # forces the per-client scatter baseline (the old REPRO_NO_SHARED_WINDOW
     # env knob, now only a documented default in launch/train.py).
     shared_window: Optional[bool] = None
-
-
-@dataclass(frozen=True)
-class RunConfig:
-    arch: str
-    shape: str
-    submodel: SubmodelConfig = SubmodelConfig()
-    dtype: str = "bfloat16"
-    remat: bool = True
-    fsdp: bool = True              # shard big params over the data axis too
-    multi_pod: bool = False
 
 
 # ---------------------------------------------------------------------------

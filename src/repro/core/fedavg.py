@@ -3,12 +3,12 @@
 Two executable forms of one algorithm family, one code path each:
 
 * **window mode** (`WindowFedAvg`) — the production TPU path.  Clients
-  live on the mesh `data` (x `pod`) axis; each round every client group
+  live on the mesh `data` axis; each round every client group
   extracts a *compact* sub-model (contiguous windows per semantic axis),
   runs K local optimizer steps (`lax.scan`), and the server applies the
   fill-in average in delta form (shared-window scatter or sequential
   scatter-add) followed by the optional l2 projection.  The whole round is
-  one jitted SPMD program — this is what the multi-pod dry-run lowers.
+  one jitted SPMD program — what the chip benchmark times.
 
 * **mask mode** (`MaskFedAvg`) — the paper's literal formulation with
   dense masks (supports unstructured Bernoulli masks of Algorithm 1 and

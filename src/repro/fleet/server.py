@@ -1,10 +1,10 @@
 """The asynchronous federated round server (``api.AsyncTrainer``).
 
-Event loop tying the fleet pieces together: idle slots (the
-``launch/batching.py`` slot-pool idiom, one slot per in-flight client)
-dispatch as a *cohort* at the current virtual instant — one stacked call
-of the UNCHANGED fused/extract client phase from ``core/fedavg.py`` —
-and their completion times go on a ``(time, seq)`` heap drawn from the
+Event loop tying the fleet pieces together: idle slots (a slot pool, one
+slot per in-flight client) dispatch as a *cohort* at the current virtual
+instant — one stacked call of the UNCHANGED fused/extract client phase
+from ``core/fedavg.py`` — and their completion times go on a
+``(time, seq)`` heap drawn from the
 :class:`~repro.fleet.simulator.FleetSimulator`.  Completed reports land
 in the :class:`~repro.fleet.buffer.DeltaBuffer`; once M of the N
 in-flight clients have reported, the buffered deltas are aggregated

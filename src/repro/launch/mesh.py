@@ -1,8 +1,7 @@
-"""Production mesh construction.
+"""Host mesh construction: (data, model) meshes over the visible devices.
 
-A function (not a module constant) so importing this module never touches
-jax device state.  Single pod: 16x16 = 256 v5e chips (data x model).
-Multi-pod: 2 pods x 256 = 512 chips with a leading `pod` axis (DCN-ish).
+Functions (not module constants) so importing this module never touches
+jax device state.
 """
 from __future__ import annotations
 
@@ -17,12 +16,6 @@ def _make_mesh(shape, axes):
     default) refuse."""
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):

@@ -24,7 +24,7 @@ import time
 def _force_host_devices():
     """--devices N must reach XLA before the backend initializes, which
     happens at (transitive) ``import jax`` below — so pre-scan sys.argv
-    here instead of waiting for argparse (same idiom as launch/dryrun.py).
+    here instead of waiting for argparse.
     """
     if "jax" in sys.modules:        # backend may already be up; too late
         return

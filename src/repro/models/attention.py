@@ -1,31 +1,24 @@
-"""Attention: GQA (full / sliding-window / qk-norm), MLA, decode paths.
+"""Attention: GQA (full / sliding-window / qk-norm) and MLA, training path.
 
-Training / prefill use *blockwise online-softmax attention* (flash-style in
-pure jnp, scan over kv chunks) so the 32k-prefill never materializes an SxS
-score matrix and the HLO stays small for the dry-run.  Sliding-window
-attention only visits the kv chunks inside the window (sub-quadratic).
-
-Decode is one-token attention against a KV cache.  For `long_500k` the cache
-is sharded along the sequence dim over the mesh `data` axis and combined with
-an exact log-sum-exp psum (`cp_decode_attention`) — context-parallel decode.
+Both use *blockwise online-softmax attention* (flash-style in pure jnp, scan
+over kv chunks), so a long sequence never materializes an SxS score matrix
+and the HLO stays small.  Sliding-window attention only visits the kv chunks
+inside the window (sub-quadratic).
 
 The attention core (scores, softmax, weighted values; not the q/k/v/o
-projections) runs under the ``model.attention`` named scope in every path.
+projections) runs under the ``model.attention`` named scope.
 """
 from __future__ import annotations
 
 import math
 import os
-from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from repro import tracing
 from repro.models.layers import (ParamBuilder, apply_rope, head_proj,
                                  rms_norm, yarn_freqs, yarn_get_mscale)
-from repro.sharding.spmd import shard_map
 
 
 NEG_INF = -1e30
@@ -80,7 +73,7 @@ def mla_params(b: ParamBuilder, prefix, cfg, layers=0):
 
 
 # ---------------------------------------------------------------------------
-# Blockwise (flash-style) attention — train / prefill
+# Blockwise (flash-style) attention
 # ---------------------------------------------------------------------------
 
 
@@ -165,71 +158,7 @@ def blockwise_attention(q, k, v, *, causal=True, window=0, q_chunk=0,
 
 
 # ---------------------------------------------------------------------------
-# Decode attention (one token vs cache)
-# ---------------------------------------------------------------------------
-
-
-@tracing.scoped(tracing.ATTENTION)
-def decode_attention(q, k, v, valid, softmax_scale=None):
-    """q [B,H,hd]; k,v [B,Sc,KV,hd]; valid [B,Sc] bool.  -> [B,H,vdim]."""
-    B, H, hd = q.shape
-    KV = k.shape[2]
-    G = H // KV
-    scale = softmax_scale or 1.0 / math.sqrt(hd)
-    qg = q.reshape(B, KV, G, hd)
-    s = jnp.einsum("bkgd,bskd->bkgs", qg, k,
-                   preferred_element_type=jnp.float32) * scale
-    s = jnp.where(valid[:, None, None, :], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bkgs,bskd->bkgd", p.astype(v.dtype), v,
-                     preferred_element_type=jnp.float32)
-    return out.reshape(B, H, -1)
-
-
-@tracing.scoped(tracing.ATTENTION)
-def cp_decode_attention(mesh, q, k, v, valid, axis="data", softmax_scale=None):
-    """Context-parallel exact decode attention.
-
-    k/v/valid are sharded along their sequence dim over ``axis``; q is
-    replicated on ``axis``.  Heads stay sharded on `model` (manual there too).
-    One psum_max + two psums — linear in local S.
-    """
-    B, H, hd = q.shape
-    KV = k.shape[2]
-    scale = softmax_scale or 1.0 / math.sqrt(hd)
-    msize = mesh.shape.get("model", 1)
-    # shard heads over `model` only when the GQA grouping survives the split
-    if KV % msize == 0 and H % msize == 0:
-        qh_spec = kvh_spec = "model"
-    else:
-        qh_spec = kvh_spec = None
-
-    def local(qh, kh, vh, validh):
-        G = qh.shape[1] // kh.shape[2]
-        qg = qh.reshape(B, kh.shape[2], G, hd)
-        s = jnp.einsum("bkgd,bskd->bkgs", qg, kh,
-                       preferred_element_type=jnp.float32) * scale
-        s = jnp.where(validh[:, None, None, :], s, NEG_INF)
-        m = s.max(-1)
-        gm = jax.lax.pmax(m, axis)
-        p = jnp.exp(s - gm[..., None])
-        l = jax.lax.psum(p.sum(-1), axis)
-        o = jnp.einsum("bkgs,bskd->bkgd", p.astype(vh.dtype), vh,
-                       preferred_element_type=jnp.float32)
-        o = jax.lax.psum(o, axis)
-        out = o / jnp.maximum(l, 1e-30)[..., None]
-        return out.reshape(B, qh.shape[1], -1)
-
-    fn = shard_map(
-        local, mesh,
-        in_specs=(P(None, qh_spec, None), P(None, axis, kvh_spec, None),
-                  P(None, axis, kvh_spec, None), P(None, axis)),
-        out_specs=P(None, qh_spec, None))
-    return fn(q, k, v, valid)
-
-
-# ---------------------------------------------------------------------------
-# GQA module (train / prefill / decode)
+# GQA module
 # ---------------------------------------------------------------------------
 
 
@@ -284,50 +213,6 @@ def gqa_train(p, x, cfg, positions, q_chunk=0, kv_chunk=0, window=None):
         # outside — the dynamic_slice transpose)
         wo = jax.lax.dynamic_slice_in_dim(wo, hspec.offset, hspec.win, 0)
     return jnp.einsum("bshe,hed->bsd", out, wo)
-
-
-def gqa_prefill(p, x, cfg, positions, cache_len):
-    q, k, v = _qkv(p, x, cfg, positions)
-    out = blockwise_attention(q, k, v, causal=True,
-                              window=cfg.sliding_window)
-    S = x.shape[1]
-    if cache_len < S:  # ring (sliding-window) cache holds the last cache_len
-        shift = (S - cache_len) % cache_len if cache_len else 0
-        kc = jnp.roll(k[:, -cache_len:], shift, axis=1)
-        vc = jnp.roll(v[:, -cache_len:], shift, axis=1)
-    else:
-        kc, vc = k, v
-    return jnp.einsum("bshe,hed->bsd", out, p["wo"]), {"k": kc, "v": vc}
-
-
-def gqa_decode(p, x, cfg, cache, pos, mesh=None, cp=False,
-               valid_override=None, rope_pos=None):
-    """x [B,1,D]; cache {k,v: [B,Sc,KV,hd]}; pos scalar int (cache write
-    slot / causal horizon).
-
-    valid_override [B,Sc] bool: per-slot cache validity; rope_pos [B]: per-
-    slot logical positions (continuous batching timelines with gaps)."""
-    positions = rope_pos[:, None] if rope_pos is not None \
-        else jnp.full((x.shape[0], 1), pos)
-    q, k, v = _qkv(p, x, cfg, positions)
-    Sc = cache["k"].shape[1]
-    slot = pos % Sc
-    kc = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, slot, 1)
-    vc = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, slot, 1)
-    idx = jnp.arange(Sc)
-    if valid_override is not None:
-        valid = valid_override
-    elif cfg.sliding_window and Sc <= cfg.sliding_window:
-        valid = (idx <= pos) | (pos + 1 >= Sc)     # ring fully valid once wrapped
-        valid = jnp.broadcast_to(valid, (x.shape[0], Sc))
-    else:
-        valid = jnp.broadcast_to(idx <= pos, (x.shape[0], Sc))
-    if cp and mesh is not None:
-        out = cp_decode_attention(mesh, q[:, 0], kc, vc, valid)
-    else:
-        out = decode_attention(q[:, 0], kc, vc, valid)
-    out = jnp.einsum("bhe,hed->bd", out.astype(x.dtype), p["wo"])
-    return out[:, None, :], {"k": kc, "v": vc}
 
 
 # ---------------------------------------------------------------------------
@@ -412,38 +297,3 @@ def mla_train(p, x, cfg, positions, q_chunk=0, kv_chunk=0, window=None):
         # exact zeros outside (the dynamic_slice transpose)
         wo = jax.lax.dynamic_slice_in_dim(wo, hspec.offset, hspec.win, 0)
     return jnp.einsum("bshe,hed->bsd", out, wo)
-
-
-def mla_prefill(p, x, cfg, positions):
-    out = mla_train(p, x, cfg, positions)
-    c, kr = _mla_ckv(p, x, cfg, positions)
-    return out, {"c": c, "kr": kr}
-
-
-def mla_decode(p, x, cfg, cache, pos, mesh=None, cp=False,
-               valid_override=None, rope_pos=None):
-    """Absorbed path — attends in compressed space; cache {c:[B,S,r], kr}."""
-    B = x.shape[0]
-    posv = rope_pos[:, None] if rope_pos is not None \
-        else jnp.full((B, 1), pos)
-    q_nope, q_rope = _mla_q(p, x, cfg, posv)          # [B,1,H,*]
-    c_t, kr_t = _mla_ckv(p, x, cfg, posv)             # [B,1,r],[B,1,rd]
-    cc = jax.lax.dynamic_update_slice_in_dim(cache["c"], c_t, pos, 1)
-    krc = jax.lax.dynamic_update_slice_in_dim(cache["kr"], kr_t, pos, 1)
-    # absorb W_uk into q:  q_c [B,H,r]
-    q_c = jnp.einsum("bhe,rhe->bhr", q_nope[:, 0], p["w_uk"])
-    q_cat = jnp.concatenate([q_c, q_rope[:, 0]], -1)  # [B,H,r+rd]
-    k_cat = jnp.concatenate([cc, krc], -1)[:, :, None, :]  # [B,S,1,r+rd]
-    v = cc[:, :, None, :]                              # [B,S,1,r]
-    S = cc.shape[1]
-    valid = valid_override if valid_override is not None else \
-        jnp.broadcast_to(jnp.arange(S) <= pos, (B, S))
-    scale = _mla_scale(cfg)
-    if cp and mesh is not None:
-        ctx = cp_decode_attention(mesh, q_cat, k_cat, v, valid,
-                                  softmax_scale=scale)
-    else:
-        ctx = decode_attention(q_cat, k_cat, v, valid, softmax_scale=scale)
-    out = jnp.einsum("bhr,rhe->bhe", ctx.astype(x.dtype), p["w_uv"])
-    out = jnp.einsum("bhe,hed->bd", out, p["wo"])
-    return out[:, None, :], {"c": cc, "kr": krc}

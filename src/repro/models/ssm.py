@@ -1,13 +1,10 @@
 """Mamba-2 (SSD, state-space duality) mixer — TPU-native chunked form.
 
-Training/prefill use the *chunked SSD block decomposition* [arXiv:2405.21060]:
+Training uses the *chunked SSD block decomposition* [arXiv:2405.21060]:
 intra-chunk quadratic (attention-like, MXU matmuls) + inter-chunk state
 recurrence via ``lax.scan`` over chunks — O(S) with matmul-dominated compute,
 which is the right adaptation of the selective-scan to the MXU (no
 warp-shuffle scan tricks needed on TPU).
-
-Decode carries per-layer recurrent state [B, nh, hd, N] + depthwise-conv tail
-buffers; one step is a pure elementwise recurrence (O(1) in S).
 
 Projections are kept *split* (w_z/w_x/w_B/w_C/w_dt instead of one fused
 in_proj) so each carries clean semantic axis tags for sub-model windowing —
@@ -141,8 +138,8 @@ def ssd_chunked(xr, dt, A, Br, Cr, chunk):
     return y.astype(xr.dtype), hT
 
 
-def ssm_train(p, x, cfg, return_state=False, window=None):
-    """x [B,S,D] -> [B,S,D] (optionally + decode cache).
+def ssm_train(p, x, cfg, window=None):
+    """x [B,S,D] -> [B,S,D].
 
     ``window`` (a ``WindowMap`` or None) applies an ``ssm_heads`` window on
     the FULL weights: the windowed SSD projections
@@ -164,9 +161,6 @@ def ssm_train(p, x, cfg, return_state=False, window=None):
         conv_x, A_log = p["conv_x"], p["A_log"]
         D_skip, y_norm, w_out = p["D_skip"], p["y_norm"], p["w_out"]
     else:
-        if return_state:
-            raise ValueError("ssm_heads windows are a training-path "
-                             "feature; prefill/decode use full heads")
         z, xr, Br, Cr, dt_raw = _projections_windowed(
             p, x, spec, backend=window.backend)
         sl = lambda w, d: jax.lax.dynamic_slice_in_dim(  # noqa: E731
@@ -182,57 +176,7 @@ def ssm_train(p, x, cfg, return_state=False, window=None):
     Crc = jax.nn.silu(_causal_conv(Cr, p["conv_C"]))
     dt = jax.nn.softplus(dt_raw)
     A = -jnp.exp(A_log.astype(jnp.float32))
-    y, hT = ssd_chunked(xr, dt, A, Brc, Crc, s.chunk)
+    y, _ = ssd_chunked(xr, dt, A, Brc, Crc, s.chunk)
     y = y + D_skip[:, None] * xr
     y = rms_norm(y * jax.nn.silu(z), y_norm, cfg.norm_eps)
-    out = jnp.einsum("bshe,hed->bsd", y, w_out)
-    if not return_state:
-        return out
-    cw = s.conv_width
-    cache = {
-        "h": hT,                                          # [B,nh,hd,N]
-        "conv_x": xr_raw_tail(z, x, p, nh, hd, cw),
-        "conv_B": Br[:, -(cw - 1):],
-        "conv_C": Cr[:, -(cw - 1):],
-    }
-    return out, cache
-
-
-def xr_raw_tail(z, x, p, nh, hd, cw):
-    xr_raw = jnp.einsum("bsd,dhe->bshe", x, p["w_x"])
-    return xr_raw[:, -(cw - 1):].reshape(x.shape[0], cw - 1, nh * hd)
-
-
-def ssm_decode(p, x, cfg, cache, pos):
-    """x [B,1,D]; cache {h, conv_x, conv_B, conv_C}."""
-    s = cfg.ssm
-    del pos
-    z, xr, Br, Cr, dt_raw = _projections(p, x)           # seq dim = 1
-    B = x.shape[0]
-    nh, hd = xr.shape[2], xr.shape[3]
-    cw = s.conv_width
-
-    def conv_step(buf, new, w):
-        # buf [B,cw-1,ch]; new [B,1,ch]; w [cw,ch]
-        win = jnp.concatenate([buf, new], axis=1)        # [B,cw,ch]
-        out = jnp.einsum("bwc,wc->bc", win, w)
-        return out, win[:, 1:]
-
-    xr_f, conv_x = conv_step(cache["conv_x"], xr.reshape(B, 1, nh * hd),
-                             p["conv_x"].reshape(cw, nh * hd))
-    Br_f, conv_B = conv_step(cache["conv_B"], Br, p["conv_B"])
-    Cr_f, conv_C = conv_step(cache["conv_C"], Cr, p["conv_C"])
-    xr_f = jax.nn.silu(xr_f).reshape(B, nh, hd)
-    Br_f = jax.nn.silu(Br_f)
-    Cr_f = jax.nn.silu(Cr_f)
-    dt = jax.nn.softplus(dt_raw[:, 0])                   # [B,nh]
-    A = -jnp.exp(p["A_log"].astype(jnp.float32))
-    decay = jnp.exp(dt * A)                              # [B,nh]
-    h = cache["h"] * decay[:, :, None, None] + jnp.einsum(
-        "bhp,bn,bh->bhpn", xr_f.astype(jnp.float32), Br_f.astype(jnp.float32),
-        dt)
-    y = jnp.einsum("bhpn,bn->bhp", h, Cr_f.astype(jnp.float32))
-    y = y.astype(x.dtype) + p["D_skip"][:, None] * xr_f
-    y = rms_norm(y[:, None] * jax.nn.silu(z), p["y_norm"], cfg.norm_eps)
-    out = jnp.einsum("bshe,hed->bsd", y, p["w_out"])
-    return out, {"h": h, "conv_x": conv_x, "conv_B": conv_B, "conv_C": conv_C}
+    return jnp.einsum("bshe,hed->bsd", y, w_out)

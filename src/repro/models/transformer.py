@@ -2,17 +2,16 @@
 architectures (dense / MoE / SSM / hybrid / audio / VLM).
 
 * Parameters are stacked over layers (leading ``layers`` dim) and the stack is
-  executed with ``lax.scan`` — small HLO, fast multi-pod compiles, remat-able.
+  executed with ``lax.scan`` — small HLO, fast compiles, remat-able.
 * Each leaf carries semantic axis tags (see ``repro.models.layers``), which
   drive sub-model windowing, masking, and sharding.
-* Three entry points per model: ``loss``/``forward`` (train), ``prefill``
-  (build KV/SSM caches from a prompt), ``decode_step`` (one token).
+* One entry point per model: ``forward``, and ``loss`` on top of it — the
+  training path every federated round runs.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -104,8 +103,7 @@ def build_params(cfg: ModelConfig, key, dtype=jnp.float32) -> Tuple[Dict, Dict]:
 # ---------------------------------------------------------------------------
 
 
-def _attn_any(p, x, cfg, positions, mode, cache=None, pos=None, mesh=None,
-              cp=False, valid=None, rope_pos=None, window=None):
+def _attn_any(p, x, cfg, positions, window=None):
     if cfg.mla is not None:
         if window is not None and \
                 window.get("kv_heads", cfg.n_kv_heads) is not None:
@@ -115,27 +113,13 @@ def _attn_any(p, x, cfg, positions, mode, cache=None, pos=None, mesh=None,
                 "MLA attention has no kv_heads axis to window; window the "
                 "standalone heads axis instead (windowed per-head "
                 "up-projections)")
-        if mode == "train":
-            return attn.mla_train(p, x, cfg, positions,
-                                  window=window), None
-        if mode == "prefill":
-            return attn.mla_prefill(p, x, cfg, positions)
-        return attn.mla_decode(p, x, cfg, cache, pos, mesh=mesh, cp=cp,
-                               valid_override=valid, rope_pos=rope_pos)
-    if mode == "train":
-        return attn.gqa_train(p, x, cfg, positions, window=window), None
-    if mode == "prefill":
-        S = x.shape[1]
-        clen = min(S, cfg.sliding_window) if cfg.sliding_window else S
-        return attn.gqa_prefill(p, x, cfg, positions, clen)
-    return attn.gqa_decode(p, x, cfg, cache, pos, mesh=mesh, cp=cp,
-                           valid_override=valid, rope_pos=rope_pos)
+        return attn.mla_train(p, x, cfg, positions, window=window)
+    return attn.gqa_train(p, x, cfg, positions, window=window)
 
 
-def block_apply(p, h, cfg, stack, positions, mode="train", cache=None,
-                pos=None, mesh=None, cp=False, moe_path="dropping",
-                valid=None, rope_pos=None, window=None):
-    """One layer.  Returns (h, aux_loss, new_cache_layer).
+def block_apply(p, h, cfg, stack, positions, moe_path="dropping",
+                window=None):
+    """One layer.  Returns (h, aux_loss).
 
     ``window`` (a :class:`WindowMap`, or None) routes every windowed
     matmul through the fused sub-model forward on the FULL weights — MLP
@@ -143,32 +127,13 @@ def block_apply(p, h, cfg, stack, positions, mode="train", cache=None,
     ``experts``/``moe_d_ff`` — so only the active windows are read from
     HBM and no compact W_sub copy exists."""
     aux = jnp.zeros((), jnp.float32)
-    new_cache = {}
     x = rms_norm(h, p["ln1"], cfg.norm_eps)
     if cfg.family == "ssm":
-        if mode == "train":
-            out = ssm_mod.ssm_train(p["ssm"], x, cfg, window=window)
-        elif mode == "prefill":
-            out, c = ssm_mod.ssm_train(p["ssm"], x, cfg, return_state=True)
-            new_cache.update(c)
-        else:
-            out, c = ssm_mod.ssm_decode(p["ssm"], x, cfg, cache, pos)
-            new_cache.update(c)
-        return h + out, aux, new_cache
-    a, acache = _attn_any(p["attn"], x, cfg, positions, mode, cache, pos,
-                          mesh, cp, valid, rope_pos, window)
-    if acache:
-        new_cache.update(acache)
+        out = ssm_mod.ssm_train(p["ssm"], x, cfg, window=window)
+        return h + out, aux
+    a = _attn_any(p["attn"], x, cfg, positions, window)
     if cfg.hybrid:
-        if mode == "train":
-            s_out = ssm_mod.ssm_train(p["ssm"], x, cfg, window=window)
-        elif mode == "prefill":
-            s_out, c = ssm_mod.ssm_train(p["ssm"], x, cfg, return_state=True)
-            new_cache.update(c)
-        else:
-            scache = {k: cache[k] for k in ("h", "conv_x", "conv_B", "conv_C")}
-            s_out, c = ssm_mod.ssm_decode(p["ssm"], x, cfg, scache, pos)
-            new_cache.update(c)
+        s_out = ssm_mod.ssm_train(p["ssm"], x, cfg, window=window)
         a = 0.5 * (rms_norm(a, p["fuse_a"], cfg.norm_eps)
                    + rms_norm(s_out, p["fuse_s"], cfg.norm_eps))
     h = h + constrain(a, "batch", "seq", "d_model")
@@ -185,7 +150,7 @@ def block_apply(p, h, cfg, stack, positions, mode="train", cache=None,
         else:
             out = mlp_apply(p["mlp"], x2, cfg.act)
     h = h + constrain(out, "batch", "seq", "d_model")
-    return h, aux, new_cache
+    return h, aux
 
 
 # ---------------------------------------------------------------------------
@@ -265,43 +230,21 @@ class Model:
             if cfg.n_codebooks else constrain(logits, "batch", "seq", "vocab")
 
     # -- stacks ---------------------------------------------------------------
-    def _run_stacks(self, params, h, positions, mode, caches=None, pos=None,
-                    mesh=None, cp=False, valid=None, rope_pos=None,
-                    window=None):
+    def _run_stacks(self, params, h, positions, window=None):
         cfg = self.cfg
         aux_total = jnp.zeros((), jnp.float32)
-        new_caches = {}
         for stack in _layer_kind(cfg):
-            stack_params = params[stack]
-            cache_stack = caches.get(stack) if caches else None
+            def body(carry, lp, stack=stack):
+                h, aux = carry
+                h, a = block_apply(lp, h, cfg, stack, positions,
+                                   self.moe_path, window)
+                return (h, aux + a), None
 
-            if cache_stack is None:
-                def body(carry, lp, stack=stack):
-                    h, aux = carry
-                    h, a, nc = block_apply(lp, h, cfg, stack, positions,
-                                           mode, None, pos, mesh, cp,
-                                           self.moe_path, valid, rope_pos,
-                                           window)
-                    return (h, aux + a), nc
-                xs = stack_params
-            else:
-                def body(carry, xs_, stack=stack):
-                    h, aux = carry
-                    lp, lc = xs_
-                    h, a, nc = block_apply(lp, h, cfg, stack, positions,
-                                           mode, lc, pos, mesh, cp,
-                                           self.moe_path, valid, rope_pos,
-                                           window)
-                    return (h, aux + a), nc
-                xs = (stack_params, cache_stack)
-
-            fn = jax.checkpoint(body) if (self.remat and mode == "train") \
-                else body
-            (h, aux_total), ys = jax.lax.scan(fn, (h, aux_total), xs,
-                                              unroll=self.layer_unroll)
-            if mode in ("prefill", "decode") and ys:
-                new_caches[stack] = ys
-        return h, aux_total, new_caches
+            fn = jax.checkpoint(body) if self.remat else body
+            (h, aux_total), _ = jax.lax.scan(fn, (h, aux_total),
+                                             params[stack],
+                                             unroll=self.layer_unroll)
+        return h, aux_total
 
     def _norm_window(self, window):
         """Normalize ``window`` to a :class:`WindowMap` (or None).
@@ -328,8 +271,7 @@ class Model:
         h = self._embed(params, tokens, extra)
         B, S = h.shape[0], h.shape[1]
         positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-        h, aux, _ = self._run_stacks(params, h, positions, "train",
-                                     window=window)
+        h, aux = self._run_stacks(params, h, positions, window=window)
         h = rms_norm(h, params["final_norm"], cfg.norm_eps)
         return self._head(params, h), aux, h
 
@@ -356,9 +298,9 @@ class Model:
             B, S = tokens.shape[:2]
             positions = jnp.broadcast_to(jnp.arange(hp.shape[1]),
                                          (B, hp.shape[1]))
-            hmtp, _, _ = block_apply(params["mtp"], hp, cfg, "layers",
-                                     positions, "train",
-                                     moe_path=self.moe_path, window=window)
+            hmtp, _ = block_apply(params["mtp"], hp, cfg, "layers",
+                                  positions, moe_path=self.moe_path,
+                                  window=window)
             hmtp = rms_norm(hmtp, params["mtp"]["final"], cfg.norm_eps)
             mtp_logits = self._head(params, hmtp)
             mtp = softmax_xent(mtp_logits[:, :-2], tokens[:, 2:])
@@ -366,109 +308,6 @@ class Model:
             metrics["mtp_loss"] = mtp
         metrics["loss"] = total
         return total, metrics
-
-    def prefill(self, params, tokens, extra=None, max_len=None,
-                pos_offset=0, return_all_logits=False):
-        """max_len: total cache capacity for subsequent decode_steps.
-        pos_offset: absolute position of the first token (continuous
-        batching timelines).  return_all_logits: per-position logits for
-        ragged-prompt cohorts."""
-        cfg = self.cfg
-        h = self._embed(params, tokens, extra)
-        B, S = h.shape[0], h.shape[1]
-        positions = jnp.broadcast_to(pos_offset + jnp.arange(S), (B, S))
-        h, _, caches = self._run_stacks(params, h, positions, "prefill")
-        h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-        logits = self._head(params, h if return_all_logits else h[:, -1:])
-        if max_len is not None:
-            caches = self._pad_caches(caches, max_len)
-        return (logits if return_all_logits else logits[:, 0]), caches
-
-    def _pad_caches(self, caches, max_len):
-        cfg = self.cfg
-        kv_target = min(max_len, cfg.sliding_window) if cfg.sliding_window \
-            else max_len
-
-        def pad(path, x):
-            key = path[-1].key if hasattr(path[-1], "key") else path[-1]
-            if key in ("k", "v", "c", "kr"):
-                tgt = kv_target if key in ("k", "v") else max_len
-                cur = x.shape[2]
-                if cur < tgt:
-                    padw = [(0, 0)] * x.ndim
-                    padw[2] = (0, tgt - cur)
-                    return jnp.pad(x, padw)
-            return x
-
-        return jax.tree_util.tree_map_with_path(pad, caches)
-
-    def decode_step(self, params, tokens, caches, pos, mesh=None, cp=False,
-                    valid=None, rope_pos=None):
-        """tokens [B] (or [B,CB]); caches from prefill/init_cache; pos
-        scalar; valid [B, cache_len] optional per-slot mask (continuous
-        batching)."""
-        cfg = self.cfg
-        tok = tokens[:, None] if tokens.ndim == 1 else tokens[:, None, :]
-        h = self._embed_decode(params, tok, pos)
-        positions = None
-        h, _, caches = self._run_stacks(params, h, positions, "decode",
-                                        caches, pos, mesh, cp, valid,
-                                        rope_pos)
-        h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-        logits = self._head(params, h)
-        return logits[:, 0], caches
-
-    def _embed_decode(self, params, tok, pos):
-        cfg = self.cfg
-        if cfg.n_codebooks:
-            h = 0.0
-            for cb in range(cfg.n_codebooks):
-                h = h + params["embed"][cb][tok[..., cb]]
-        else:
-            h = params["embed"][tok]
-        if cfg.pos_embed == "sinusoidal":
-            p = jnp.full((1, 1), pos)
-            h = h + sinusoidal_positions(p, h.shape[-1]).astype(h.dtype)
-        return constrain(h, "batch", "seq", "d_model")
-
-    # -- cache construction ---------------------------------------------------
-    def init_cache(self, batch: int, seq_len: int, dtype=jnp.bfloat16):
-        cfg = self.cfg
-        caches = {}
-        for stack in _layer_kind(cfg):
-            if stack == "dense_layers":
-                L = cfg.n_dense_layers
-            elif stack == "moe_layers":
-                L = cfg.n_layers - cfg.n_dense_layers
-            else:
-                L = cfg.n_layers
-            c = {}
-            if cfg.family != "ssm":
-                if cfg.mla is not None:
-                    m = cfg.mla
-                    c["c"] = jnp.zeros((L, batch, seq_len, m.kv_lora_rank),
-                                       dtype)
-                    c["kr"] = jnp.zeros((L, batch, seq_len, m.rope_head_dim),
-                                        dtype)
-                else:
-                    Sc = min(seq_len, cfg.sliding_window) \
-                        if cfg.sliding_window else seq_len
-                    kvh, hd = cfg.n_kv_heads, cfg.head_dim
-                    c["k"] = jnp.zeros((L, batch, Sc, kvh, hd), dtype)
-                    c["v"] = jnp.zeros((L, batch, Sc, kvh, hd), dtype)
-            if cfg.family == "ssm" or cfg.hybrid:
-                s = cfg.ssm
-                nh = s.n_heads or (s.expand * cfg.d_model) // s.head_dim
-                c["h"] = jnp.zeros((L, batch, nh, s.head_dim, s.d_state),
-                                   jnp.float32)
-                c["conv_x"] = jnp.zeros((L, batch, s.conv_width - 1,
-                                         nh * s.head_dim), dtype)
-                c["conv_B"] = jnp.zeros((L, batch, s.conv_width - 1,
-                                         s.d_state), dtype)
-                c["conv_C"] = jnp.zeros((L, batch, s.conv_width - 1,
-                                         s.d_state), dtype)
-            caches[stack] = c
-        return caches
 
 
 def build_model(cfg: ModelConfig, **kw) -> Model:

@@ -72,19 +72,10 @@ def constrain(x, *axes):
 
 # default rule-sets -----------------------------------------------------------
 
-def default_rules(multi_pod: bool = False) -> dict:
-    data = ("pod", "data") if multi_pod else "data"
+def default_rules() -> dict:
     return {
-        "batch": data, "clients": data, "seq": None, "cache_seq": None,
+        "batch": "data", "clients": "data", "seq": None,
         "d_model": None, "heads": "model", "kv_heads": "model",
         "d_ff": "model", "moe_d_ff": "model", "experts": "model",
         "vocab": "model", "ssm_heads": None,
     }
-
-
-def cp_rules(multi_pod: bool = False) -> dict:
-    """long-context decode: KV cache sequence sharded over the data axis."""
-    r = default_rules(multi_pod)
-    r["cache_seq"] = ("pod", "data") if multi_pod else "data"
-    r["batch"] = None           # global_batch=1 — cannot shard
-    return r

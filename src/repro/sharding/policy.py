@@ -5,14 +5,11 @@ Rules map axis name -> desired mesh axis; a leaf dim is sharded only if the
 mesh axis divides it and the mesh axis is not already used by an earlier dim
 of the same leaf (first-match-wins).  This one table produces:
 
-* ``param_specs``      — PartitionSpecs for server parameters (dry-run
-  in_shardings / with_sharding_constraint),
+* ``param_specs``      — PartitionSpecs for server parameters,
 * ``constrain_tree``   — axis-aware activation/sub-model constraints used
   inside the fed round (client axis + per-leaf tags).
 """
 from __future__ import annotations
-
-from typing import Optional
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -21,9 +18,8 @@ from repro.sharding.ctx import current_policy
 from repro.sharding.spmd import axis_size as _axis_size
 
 
-def default_param_rules(multi_pod: bool = False, fsdp: bool = True) -> dict:
-    data = ("pod", "data") if multi_pod else "data"
-    rules = {
+def default_param_rules() -> dict:
+    return {
         "vocab": "model",
         "d_ff": "model", "moe_d_ff": "model",
         "heads": "model", "kv_heads": "model",
@@ -31,11 +27,9 @@ def default_param_rules(multi_pod: bool = False, fsdp: bool = True) -> dict:
         "ssm_heads": "model",
         "mla_q_rank": "model",
         "channels": None,
-        "clients": data,
+        "clients": "data",
+        "d_model": "data",               # ZeRO-3-style shard of the residual dim
     }
-    if fsdp:
-        rules["d_model"] = data          # ZeRO-3-style shard of the residual dim
-    return rules
 
 
 def leaf_spec(shape, axes, rules, mesh: Mesh) -> P:
@@ -60,13 +54,6 @@ def param_specs(abstract, axes_tree, rules, mesh: Mesh):
             return {k: walk(p[k], a[k]) for k in p}
         return leaf_spec(p.shape, a, rules, mesh)
     return walk(abstract, axes_tree)
-
-
-def param_shardings(abstract, axes_tree, rules, mesh: Mesh):
-    return jax.tree_util.tree_map(
-        lambda s: NamedSharding(mesh, s),
-        param_specs(abstract, axes_tree, rules, mesh),
-        is_leaf=lambda x: isinstance(x, P))
 
 
 def round_input_shardings(mesh: Mesh, axis, abstract, batch):

@@ -2,9 +2,9 @@ import os
 import sys
 
 # NOTE: no XLA_FLAGS by default on purpose — smoke tests and benches must
-# see the single real device; only launch/dryrun.py requests 512
-# placeholders.  REPRO_HOST_DEVICES=N opts a run into N forced host
-# devices for the mesh tests (tests/test_mesh.py; the CI mesh job sets 4).
+# see the single real device.  REPRO_HOST_DEVICES=N opts a run into N
+# forced host devices for the mesh tests (tests/test_mesh.py; the CI mesh
+# job sets 4).
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 _n = os.environ.get("REPRO_HOST_DEVICES")

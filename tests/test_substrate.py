@@ -120,31 +120,3 @@ def test_leaf_spec_divisibility():
     rules = {"heads": "model"}
     spec = leaf_spec((25, 4), ("heads", "head_dim"), rules, mesh)
     assert spec == P("model", None)  # 25 % 1 == 0 trivially sharded
-
-
-# -- HLO cost analyzer --------------------------------------------------------
-
-
-def test_hlo_cost_counts_loop_bodies():
-    def f(x, w):
-        def body(h, wl):
-            return jnp.tanh(h @ wl), None
-        return jax.lax.scan(body, x, w)[0]
-
-    x = jax.ShapeDtypeStruct((64, 128), jnp.float32)
-    w = jax.ShapeDtypeStruct((7, 128, 128), jnp.float32)
-    txt = jax.jit(f).lower(x, w).compile().as_text()
-    from repro.analysis.hlo_cost import analyze
-    r = analyze(txt)
-    expect = 7 * 2 * 64 * 128 * 128
-    assert abs(r["flops"] - expect) / expect < 0.05
-
-
-def test_roofline_terms():
-    from repro.analysis.roofline import Roofline
-    rl = Roofline(flops_per_dev=197e12, bytes_per_dev=819e9,
-                  coll_bytes_per_dev=50e9, chips=256, model_flops=1e15)
-    assert abs(rl.t_compute - 1.0) < 1e-9
-    assert abs(rl.t_memory - 1.0) < 1e-9
-    assert abs(rl.t_collective - 1.0) < 1e-9
-    assert rl.step_time_lower_bound == pytest.approx(1.0)

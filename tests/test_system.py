@@ -1,5 +1,5 @@
-"""End-to-end behaviour tests: every assigned architecture trains a step and
-serves (prefill + decode == full forward)."""
+"""End-to-end behaviour tests: every assigned architecture builds, runs its
+forward and trains a step."""
 import jax
 import jax.numpy as jnp
 import pytest
@@ -57,25 +57,6 @@ def test_smoke_logits_shape(arch):
     else:
         assert logits.shape == (2, S_total, cfg.vocab)
     assert not bool(jnp.any(jnp.isnan(logits)))
-
-
-@pytest.mark.parametrize("arch", ARCHS)
-def test_decode_matches_forward(arch):
-    """prefill(t[:-1]) + decode(t[-1]) == forward(t) at the last position."""
-    cfg = get_reduced_config(arch)
-    m = build_model(cfg, moe_path="dense", remat=False)
-    params = m.init(jax.random.PRNGKey(0))
-    B, S = 2, 16
-    batch = _batch(cfg, B, S)
-    tokens = batch["tokens"]
-    extra = batch if cfg.vision_stub else None
-    P = cfg.vision_patches if cfg.vision_stub else 0
-    ref, _, _ = m.forward(params, tokens, extra)
-    _, cache = m.prefill(params, tokens[:, :S - 1], extra, max_len=P + S)
-    logits, _ = m.decode_step(params, tokens[:, S - 1], cache, P + S - 1)
-    err = float(jnp.max(jnp.abs(logits - ref[:, -1])))
-    scale = float(jnp.max(jnp.abs(ref[:, -1]))) + 1e-9
-    assert err / scale < 2e-2, f"{arch}: rel err {err/scale}"
 
 
 @pytest.mark.parametrize("arch", ARCHS)
