@@ -4,15 +4,17 @@
     python chip_smoke.py --chips 4   # four chips: the mesh round only
 
 Phase A (correctness, small): one round of the reduced TinyLlama config
-(4 clients, K=2, rolling windows at capacity 0.5, align 128) on the Pallas
-kernels and on the jnp oracles; the two must agree within the tolerances
-below.
+(4 clients, K=2, rolling windows at capacity 0.5, align 128) with the
+kernel backend set to Pallas and to the jnp oracles; the two must agree
+within the tolerances below.  The round cuts each client's windows and
+carries the cut weights, so neither lowered round may hold a Pallas
+kernel (``tpu_custom_call``): its dots are XLA's.
 
 Phase B (main path, published widths): ``repro.launch.train.main`` trains
 TinyLlama-1.1B (d_model 2048, d_ff 5632, 32/4 heads, vocab 32000, f32) for
 3 rounds of 1 client.  Every loss must be finite, the lowered round must
-hold compiled kernels (``tpu_custom_call``), and no windowed matmul may
-have fallen back to its jnp oracle.
+hold no Pallas kernel (the cut carry), and no windowed matmul may have
+fallen back to its jnp oracle.
 
 ``--chips 4`` runs only the cross-chip phase: the same round with 4
 clients under ``--mesh 4``, once with ``--mesh-agg gather`` and once with
@@ -80,7 +82,7 @@ def max_abs_diff(a, b) -> float:
 
 
 def custom_calls(fed, params, batch) -> int:
-    """Compiled Pallas kernels in the lowered round (0 = none ran)."""
+    """Compiled Pallas kernels in the lowered round (0 = none runs)."""
     import jax
     lowered = jax.jit(fed.round).lower(params, batch, 0,
                                        jax.random.PRNGKey(0))
@@ -109,14 +111,14 @@ def phase_a():
         loss = float(trainer.step(batch)["loss"])
         print(f"phase A: {backend:6s} loss {loss!r}", flush=True)
         out[backend] = (loss, trainer.params)
-        if backend == "pallas":
-            if dispatch.ORACLE_FALLBACKS:
-                fail(f"phase A oracle fallbacks: "
-                     f"{dict(dispatch.ORACLE_FALLBACKS)}")
-            n = custom_calls(fed, params0, batch)
-            print(f"phase A: pallas tpu_custom_call {n}", flush=True)
-            if n == 0:
-                fail("phase A pallas round holds no tpu_custom_call")
+        if backend == "pallas" and dispatch.ORACLE_FALLBACKS:
+            fail(f"phase A oracle fallbacks: "
+                 f"{dict(dispatch.ORACLE_FALLBACKS)}")
+        n = custom_calls(fed, params0, batch)
+        print(f"phase A: {backend} tpu_custom_call {n}", flush=True)
+        if n:
+            fail(f"phase A {backend} round holds a tpu_custom_call: it "
+                 "should carry cut weights")
     (lp, pp), (lj, pj) = out["pallas"], out["jnp"]
     dparams = max_abs_diff(pp, pj)
     update = max_abs_diff(pj, params0)
@@ -133,7 +135,7 @@ def phase_a():
 
 def train_run(phase, clients, rounds, extra=()):
     """One ``train.main`` run of ``LAYERS`` layers at published widths;
-    checks losses, kernels and fallbacks."""
+    checks losses, that no kernel runs, and fallbacks."""
     import jax
     import jax.numpy as jnp
     from repro.kernels import dispatch
@@ -160,8 +162,9 @@ def train_run(phase, clients, rounds, extra=()):
     tokens = jax.ShapeDtypeStruct((STEPS, clients, MB, SEQ), jnp.int32)
     n = custom_calls(trainer.fed, trainer.params, {"tokens": tokens})
     print(f"{phase}: tpu_custom_call {n}", flush=True)
-    if n == 0:
-        fail(f"{phase}: the lowered round holds no tpu_custom_call")
+    if n:
+        fail(f"{phase}: the lowered round holds a tpu_custom_call: it "
+             "should carry cut weights")
     return trainer
 
 

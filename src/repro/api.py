@@ -194,21 +194,25 @@ def fed_round(model, scfg: SubmodelConfig, *, mode: str = "auto",
         homogeneous rounds (pinned in ``tests/test_hetero.py``).
         Window-mode capacities require ``mesh=None`` and are incompatible
         with ``shared_window=True``; values must lie in ``(0, 1]``.
-      fused_forward: window mode only — ``"auto"`` (default) routes the
-        client phase through the fused multi-axis window forward (no
-        extract/scatter, no W_sub copy; the model reads only the active
-        windows from HBM) whenever the model exposes a window-aware
-        ``loss(params, batch, window=...)`` and every properly-windowed
-        axis has a fused forward: ``d_ff`` (MLP/MTP), GQA-coupled
+      fused_forward: window mode only — ``"auto"`` (default) takes the
+        fused multi-axis window client phase: each client's window is cut
+        from the tree once a round and the cut tree is carried through
+        the K local steps, except axes the forward must index absolutely
+        (a share's ``experts`` window), which stay whole and are narrowed
+        by the model's window-aware forward.  On a dense model that is
+        the extract round, and the rolling-matmul kernels do not run in
+        it (the cut weights take plain XLA dots).  It is taken whenever
+        the model exposes a window-aware ``loss(params, batch,
+        window=...)`` and every properly-windowed axis has a fused
+        forward: ``d_ff`` (MLP/MTP), GQA-coupled
         ``heads``/``kv_heads`` (windowed q/k/v/o projections), MLA's
         standalone ``heads`` (windowed per-head up-projections),
         ``experts`` and ``moe_d_ff`` (MoE routing + per-expert/shared
         MLPs), and ``ssm_heads`` (windowed SSD projections) — the full
         default ``SubmodelConfig.axes`` tuple across the model zoo.
-        Shared-window schemes (rolling/static/importance without stagger)
-        fuse through the scalar-offset kernels; per-client schemes
-        (staggered rolling, random, staggered importance) fuse through
-        the batched-offset kernels (one prefetched offset per client).
+        A shared window (rolling/static/importance without stagger) is
+        cut once for all clients; per-client schemes (staggered rolling,
+        random, staggered importance) cut once per client.
         ``"on"``/True forces fusion (error when unavailable),
         ``"off"``/False keeps the extract-based client phase.  Fused and
         extract rounds are bitwise-equal on f32 (property-tested; see the

@@ -1,12 +1,13 @@
 """Window extraction / scatter on axis-tagged parameter trees.
 
 ``extract`` materializes a client's *compact* sub-model (contiguous slices on
-every windowed axis — the TPU-native form of the paper's m ⊙ w), and
+every windowed axis — the TPU-native form of the paper's m ⊙ w),
 ``scatter_delta`` places a sub-model delta back into a full-shaped zero tree
-(the delta form of the paper's fill-in averaging).
+(the delta form of the paper's fill-in averaging), and ``add_at_window`` adds
+one into a full-shaped accumulator in place.
 
 Offsets may be traced (per-client, per-round); window sizes are static.
-Both functions are vmap-safe over client offsets.
+These functions are vmap-safe over client offsets.
 """
 from __future__ import annotations
 
@@ -50,6 +51,25 @@ def scatter_delta(delta, full_abstract, axes_tree, offsets, sizes):
         return jax.lax.dynamic_update_slice(out, sub, tuple(starts))
 
     return _tree_map_with_axes2(f, delta, full_abstract, axes_tree)
+
+
+def add_at_window(acc, delta, axes_tree, offsets, sizes):
+    """``acc`` (full-shaped) plus ``delta`` (cut to ``sizes``) at its
+    window, in place: no zero-padded full-shaped copy of ``delta``, and
+    bitwise the sum with the scattered delta (``x + 0 == x``)."""
+
+    def f(a, d, axes):
+        d = d.astype(a.dtype)
+        dims = _windowed_dims(a.shape, axes, sizes)
+        if not dims:
+            return a + d
+        starts = [0] * a.ndim
+        for dim, key in dims:
+            starts[dim] = offsets[key]
+        cur = jax.lax.dynamic_slice(a, starts, d.shape)
+        return jax.lax.dynamic_update_slice(a, cur + d, starts)
+
+    return _tree_map_with_axes2(f, acc, delta, axes_tree)
 
 
 def window_mask(full_abstract, axes_tree, offsets, sizes, dtype=jnp.float32):

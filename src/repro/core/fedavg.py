@@ -126,16 +126,17 @@ class WindowFedAvg:
     client_opt: Optional[ClientOpt] = None  # None = the paper's plain SGD
     server_opt: Any = None              # ServerOpt used by Trainer (optional)
     shared_window: Optional[bool] = None  # None = resolve from scfg
-    # Fused multi-axis window forward: clients skip extract/scatter
-    # entirely and run K steps on the FULL tree through a window-aware
-    # model forward (loss_fn(params, batch, window={axis: (offset, win)})).
-    # "auto" takes the fused arm whenever a windowed loss is attached and
-    # every properly-windowed axis has a fused forward (d_ff, GQA-coupled
-    # heads/kv_heads, MLA standalone heads, experts, moe_d_ff, ssm_heads).
-    # Shared-window schemes close one WindowMap over the client vmap;
-    # per-client schemes (staggered rolling / random / staggered
-    # importance) vmap clients over their own WindowMaps — the batched-
-    # offset rolling-matmul arm (kernels.rolling_matmul_batched).
+    # Fused multi-axis window arm: each client's window is cut from the
+    # tree once a round and the cut tree is carried through the K steps,
+    # except where the forward needs a weight's absolute index (a share's
+    # `experts` window: routing spans `router_experts`).  Those axes stay
+    # whole and the window-aware forward (loss_fn(params, batch,
+    # window={axis: (offset, win)})) narrows them; for dense models the
+    # carry is the extract arm's.  "auto" takes the fused arm whenever a
+    # windowed loss is attached and every properly-windowed axis has a
+    # fused forward (d_ff, GQA-coupled heads/kv_heads, MLA standalone
+    # heads, experts, moe_d_ff, ssm_heads).  The rolling-matmul kernels do
+    # not run in these rounds: the cut weights take plain XLA dots.
     windowed_loss_fn: Optional[Callable] = None
     fused_forward: Any = "auto"         # "auto" | True/"on" | False/"off"
     # Heterogeneous per-client capacities: a [clients_per_round] vector of
@@ -275,14 +276,35 @@ class WindowFedAvg:
         # head_dim before the check).
         self._fused_keys = proper
         self._fused_mults = {k: self.scheme.grid_multiple(k) for k in proper}
+        # A share's experts keep their ids only on the whole stack (its
+        # router spans `router_experts`), so its `experts` windows are
+        # carried whole and narrowed by the forward; every other window is
+        # cut once a round.
+        routed = any(name == "router_experts" for (name, _) in
+                     collect_axis_dims(self.abstract, self.axes_tree))
+        self._whole_keys = {k: w for k, w in proper.items()
+                            if routed and k[0] == "experts"}
+        self._cut_sizes = {k: w for k, w in self.scheme.sizes.items()
+                           if k not in self._whole_keys}
         return True
 
+    def _phase_for(self, offsets):
+        """``(client phase, window sizes its deltas are cut to)`` for a
+        round with these offsets: the fused arm when it resolved and some
+        axis is windowed, else extract."""
+        if self.use_fused and offsets:
+            return self._client_phase_fused, self._cut_sizes
+        return self._client_phase, self.scheme.sizes
+
     def _fused_window(self, off_scalars):
-        """The per-axis WindowMap for one client's scalar offsets."""
+        """The WindowMap of the axes a fused client carries whole, for one
+        client's scalar offsets (None when it carries none)."""
         from repro.models.layers import AxisWindow, WindowMap
+        if not self._whole_keys:
+            return None
         return WindowMap(
             {k: AxisWindow(off_scalars[k], w, self._fused_mults[k])
-             for k, w in self._fused_keys.items()},
+             for k, w in self._whole_keys.items()},
             backend=self.kernel_backend)
 
     def _vmap(self, f, **kw):
@@ -344,11 +366,9 @@ class WindowFedAvg:
                 bb = jax.tree_util.tree_map(
                     lambda x: jnp.take(jnp.asarray(x), lanes, axis=1), batch)
             boff = b.fed._client_offsets(params, round_idx, rng)
-            bfused = b.fed.use_fused and bool(boff)
-            phase = (b.fed._client_phase_fused if bfused
-                     else b.fed._client_phase)
+            phase, sizes = b.fed._phase_for(boff)
             _, delta, bl = phase(params, bb, boff)
-            part = b.fed._local_delta_sum(delta, boff, bfused)
+            part = b.fed._local_delta_sum(delta, boff, sizes)
             with jax.named_scope(tracing.AGGREGATE):
                 acc = jax.tree_util.tree_map(lambda a, d: a + d, acc, part)
             parts.append(bl)
@@ -381,11 +401,12 @@ class WindowFedAvg:
         ``phase(params, batch, offsets)`` takes batch leaves
         ``[K, m, ...]`` and cohort-sliced union offsets ``{axis: [m]}``
         (both in slot order) and returns FULL-shaped per-client f32
-        deltas ``[m, ...]`` — exact zeros outside each client's window,
-        extract buckets scattered per client — plus losses ``[K, m]``,
-        reassembled in slot order.  Full-shaped deltas make buffered
-        aggregation width-agnostic: they ride the ``*_fused`` arms'
-        scan-of-adds regardless of which buckets reported."""
+        deltas ``[m, ...]`` — each bucket's cut deltas scattered per
+        client, exact zeros outside each client's window — plus losses
+        ``[K, m]``, reassembled in slot order.  Full-shaped deltas make
+        buffered aggregation width-agnostic: they aggregate as deltas cut
+        to no window (``sizes={}``) regardless of which buckets
+        reported."""
         slots = tuple(int(s) for s in slots)
         pos = {s: j for j, s in enumerate(slots)}
         plan = []
@@ -406,19 +427,15 @@ class WindowFedAvg:
                     lambda x: jnp.take(x, colsj, axis=1), batch)
                 boff = {k: jnp.take(offsets[k], colsj, axis=0)
                         for k in b.fed.scheme.sizes}
-                bfused = b.fed.use_fused and bool(boff)
-                if bfused:
-                    _, dfull, bl = b.fed._client_phase_fused(params, bb,
-                                                             boff)
-                else:
-                    _, dsub, bl = b.fed._client_phase(params, bb, boff)
-                    if boff:
-                        dfull = jax.vmap(
-                            lambda d, off: ex.scatter_delta(
-                                d, self.abstract, self.axes_tree, off,
-                                b.fed.scheme.sizes))(dsub, boff)
-                    else:  # beta = 1.0: deltas are already full-shaped
-                        dfull = dsub
+                phase, sizes = b.fed._phase_for(boff)
+                _, dsub, bl = phase(params, bb, boff)
+                if boff:
+                    dfull = jax.vmap(
+                        lambda d, off: ex.scatter_delta(
+                            d, self.abstract, self.axes_tree, off,
+                            sizes))(dsub, boff)
+                else:  # beta = 1.0: deltas are already full-shaped
+                    dfull = dsub
                 dparts.append(dfull)
                 lparts.append(bl)
                 order.append(cols)
@@ -430,36 +447,46 @@ class WindowFedAvg:
 
         return phase
 
-    def _extract_clients(self, params, offsets, count=None):
+    def _extract_clients(self, params, offsets, count=None, sizes=None):
         """Per-client compact sub-models, stacked on a leading C axis.
 
         ``count`` overrides the stacked-axis length (the shard-LOCAL client
-        count under the mesh round); None keeps the global ``C``."""
+        count under the mesh round); None keeps the global ``C``.
+        ``sizes`` are the windows cut (default: every window).  A shared
+        window is cut once and broadcast over the clients."""
         C = self.scfg.clients_per_round if count is None else count
-        if offsets:
+        sizes = self.scheme.sizes if sizes is None else sizes
+        if offsets and not self.shared_window:
             sub0 = self._vmap(
-                lambda off: ex.extract(params, self.axes_tree, off,
-                                       self.scheme.sizes)
+                lambda off: ex.extract(params, self.axes_tree, off, sizes)
             )(offsets)
-        else:  # full-model training: every client gets a replica
+        else:  # one window for every client, or the whole model
+            sub = (ex.extract(params, self.axes_tree,
+                              {k: v[0] for k, v in offsets.items()}, sizes)
+                   if offsets else params)
             sub0 = jax.tree_util.tree_map(
-                lambda x: jnp.broadcast_to(x[None], (C,) + x.shape), params)
+                lambda x: jnp.broadcast_to(x[None], (C,) + x.shape), sub)
         return constrain_tree(sub0, self.axes_tree)
 
-    @tracing.scoped(tracing.CLIENT_PHASE)
-    def _client_phase(self, params, batch, offsets):
-        """extract → K local-optimizer steps (scan) → delta."""
+    def _local_steps(self, params, batch, offsets, sizes, vgrad):
+        """The client phase shared by both arms: cut each client's windows
+        (``sizes``), run K local-optimizer steps (scan) with ``vgrad(p,
+        mb)``, return ``(sub0, f32 delta, losses)``.  Records the share of
+        the tree a client carries (:func:`repro.tracing.record_carry`)."""
         c = self.scfg
         # client count from the batch layout [K, C, ...]: the global C, or
         # the shard-local C/S inside the mesh round's shard_map body
         C = jax.tree_util.tree_leaves(batch)[0].shape[1]
-        sub0 = self._extract_clients(params, offsets, count=C)
-        grad_fn = jax.value_and_grad(self.loss_fn, has_aux=True)
+        sub0 = self._extract_clients(params, offsets, count=C, sizes=sizes)
+        tracing.record_carry(
+            _tree_bytes(ex.sub_abstract(self.abstract, self.axes_tree,
+                                        sizes if offsets else {})),
+            _tree_bytes(self.abstract))
         opt = self.client_opt
 
         def kstep(carry, mb):
             subp, ost = carry
-            (loss, metrics), g = self._vmap(grad_fn)(subp, mb)
+            (loss, metrics), g = vgrad(subp, mb)
             subp, ost = opt.update(subp, g, ost, c.client_lr,
                                    backend=self.kernel_backend)
             subp = constrain_tree(subp, self.axes_tree)
@@ -477,102 +504,45 @@ class WindowFedAvg:
         return sub0, delta, losses
 
     @tracing.scoped(tracing.CLIENT_PHASE)
+    def _client_phase(self, params, batch, offsets):
+        """extract → K local-optimizer steps (scan) → delta."""
+        grad_fn = jax.value_and_grad(self.loss_fn, has_aux=True)
+        return self._local_steps(params, batch, offsets, self.scheme.sizes,
+                                 self._vmap(grad_fn))
+
+    @tracing.scoped(tracing.CLIENT_PHASE)
     def _client_phase_fused(self, params, batch, offsets):
-        """Fused multi-axis window client phase: K steps on the FULL tree.
+        """Fused multi-axis window client phase: cut once, carry the cut.
 
-        No ``extract``/``scatter_delta`` and no compact W_sub copy: the
-        model's window-aware forward (``mlp_apply_rolling`` /
-        ``head_proj`` through the ``dispatch.rolling_matmul`` custom VJP,
-        windowed expert slices in the MoE block) reads only the active
-        windows from HBM, and out-of-window coordinates of every windowed
-        axis see an exactly-zero gradient, so their K-step delta is
-        exactly 0.  Returns the FULL-shaped f32 delta (consumed by the
-        ``*_fused`` aggregations, which slice/scatter the multi-axis
-        window like the extract path does).
-
-        Shared-window schemes close ONE WindowMap over the client vmap;
-        per-client schemes (staggered rolling / random / staggered
-        importance) additionally vmap the per-client offset scalars, so
-        each client trains its own window — the windowed matmuls then
-        lower to the batched-offset Pallas arm
-        (``kernels.rolling_matmul_batched``: one grid row per client, each
-        prefetching its own offset).
+        Each client's windows are cut from the tree once a round (a shared
+        window once for all clients, broadcast) and the K steps run on the
+        cut tree, so the carry, the update and the f32 delta are
+        window-sized.  Axes the forward must index absolutely — a share's
+        ``experts`` window — stay whole and are narrowed by the model's
+        window-aware forward (``loss_fn(params, batch, window=...)``),
+        where out-of-window coordinates see an exactly-zero gradient.  With
+        no such axis (every dense model) the round is the extract arm's,
+        and the cut weights take plain XLA dots: the rolling-matmul kernels
+        do not run.  Returns the f32 delta cut to ``_cut_sizes``, which the
+        aggregations add back at each client's window.
         """
-        c = self.scfg
-        # batch layout [K, C, ...]: global C, or shard-local C/S on the mesh
-        C = jax.tree_util.tree_leaves(batch)[0].shape[1]
-        full0 = jax.tree_util.tree_map(
-            lambda x: jnp.broadcast_to(x[None], (C,) + x.shape), params)
-        full0 = constrain_tree(full0, self.axes_tree)
         wloss = self.windowed_loss_fn
-        opt = self.client_opt
 
+        def grad_one(p, mb, off):
+            window = self._fused_window(off)
+            return jax.value_and_grad(
+                lambda p, mb: wloss(p, mb, window=window),
+                has_aux=True)(p, mb)
+
+        whole = {k: offsets[k] for k in self._whole_keys}  # [C]
         if self.shared_window:
-            window = self._fused_window(
-                {k: offsets[k][0] for k in self._fused_keys})
-            grad_fn = jax.value_and_grad(
-                lambda p, mb: wloss(p, mb, window=window), has_aux=True)
-
-            def vgrad(p, mb):
-                return self._vmap(grad_fn)(p, mb)
+            off0 = {k: v[0] for k, v in whole.items()}
+            vgrad = self._vmap(lambda p, mb: grad_one(p, mb, off0))
         else:
-            per_client = {k: offsets[k] for k in self._fused_keys}  # [C]
-
-            def grad_one(p, mb, off):
-                window = self._fused_window(off)
-                return jax.value_and_grad(
-                    lambda p, mb: wloss(p, mb, window=window),
-                    has_aux=True)(p, mb)
-
             def vgrad(p, mb):
-                return self._vmap(grad_one)(p, mb, per_client)
-
-        def kstep(carry, mb):
-            p, ost = carry
-            (loss, metrics), g = vgrad(p, mb)
-            p, ost = opt.update(p, g, ost, c.client_lr,
-                                backend=self.kernel_backend)
-            p = constrain_tree(p, self.axes_tree)
-            return (p, ost), loss
-
-        (fullK, _), losses = jax.lax.scan(kstep, (full0, opt.init(full0)),
-                                          batch)
-        delta_full = jax.tree_util.tree_map(
-            lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32),
-            fullK, full0)
-        return full0, delta_full, losses
-
-    @tracing.scoped(tracing.AGGREGATE)
-    def _apply_mean_delta(self, params, delta, offsets):
-        """Plain averaging (the paper's fill-in update, delta form)."""
-        c = self.scfg
-        C = c.clients_per_round
-        if self.shared_window and offsets:
-            # Rolling/static without stagger: every client trains the SAME
-            # window (Algorithm 2), so average client deltas first (one
-            # sub-model-sized reduction over the client/data axis), then a
-            # single in-place scatter — instead of C full-model scatters.
-            off0 = {k: v[0] for k, v in offsets.items()}
-            dbar = jax.tree_util.tree_map(
-                lambda d: jnp.mean(d.astype(jnp.float32), axis=0), delta)
-            return _scatter_update(params, dbar, self.abstract,
-                                   self.axes_tree, off0, self.scheme.sizes,
-                                   c.server_lr)
-
-        def acc_step(acc, xs):
-            d_c, off_c = xs
-            full_d = ex.scatter_delta(d_c, self.abstract, self.axes_tree,
-                                      off_c, self.scheme.sizes)
-            acc = jax.tree_util.tree_map(
-                lambda a, b: a + b.astype(a.dtype), acc, full_d)
-            return constrain_tree(acc, self.axes_tree, leading=()), None
-
-        acc0 = jax.tree_util.tree_map(
-            lambda x: jnp.zeros(x.shape, jnp.float32), self.abstract)
-        acc, _ = jax.lax.scan(acc_step, acc0, (delta, offsets))
-        return jax.tree_util.tree_map(
-            lambda w, d: (w + c.server_lr * d.astype(jnp.float32) / C
-                          ).astype(w.dtype), params, acc)
+                return self._vmap(grad_one)(p, mb, whole)
+        return self._local_steps(params, batch, offsets, self._cut_sizes,
+                                 vgrad)
 
     def _uplink(self, tree):
         """Simulated client→server uplink of a delta tree (leaves may carry
@@ -588,77 +558,36 @@ class WindowFedAvg:
             lambda d: d.astype(jnp.bfloat16).astype(f32), tree)
 
     @tracing.scoped(tracing.AGGREGATE)
-    def _apply_mean_delta_fused(self, params, delta_full, offsets):
-        """Aggregation for the fused client phase's FULL-shaped delta.
+    def _apply_mean_delta(self, params, delta, offsets, sizes=None):
+        """Plain averaging (the paper's fill-in update, delta form) of
+        client deltas cut to ``sizes`` (default: every window; ``{}`` for
+        full-shaped deltas), after the simulated uplink.
 
-        Shared window: out-of-window coordinates of the fused delta are
-        exactly 0, so the window slice commutes with the per-coordinate
-        client mean — extract each client's compact window FIRST, mean the
-        [C, sub] stack, then the same single in-place scatter as the
-        extract path.  Extract-then-mean is bitwise-identical to the
-        mean-then-extract order (same elements, same reduction order) but
-        does O(C·sub) aggregation arithmetic instead of O(C·full) — the
-        shared-window wall-clock win.
-
-        Per-client windows (staggered/random): each client's full-shaped
-        delta already IS its scattered form (exact zeros outside its own
-        window), so the extract path's per-client scatter-add collapses to
-        a scan of plain adds — op-for-op the same accumulation order, which
-        keeps the round bitwise-equal to extract on f32."""
+        Shared window: every client trained the SAME window (Algorithm 2),
+        so the client mean of the cut deltas is taken first (one
+        sub-model-sized reduction over the client/data axis), then a
+        single in-place scatter.  Per-client windows: each client's delta
+        is added into the f32 accumulator at its own window, in place
+        (:meth:`_window_sum`)."""
         c = self.scfg
-        C = c.clients_per_round
-        if self.shared_window:
+        sizes = self.scheme.sizes if sizes is None else sizes
+        delta = self._uplink(delta)
+        if self.shared_window and offsets:
             off0 = {k: v[0] for k, v in offsets.items()}
-            delta_sub = self._vmap(
-                lambda d: ex.extract(d, self.axes_tree, off0,
-                                     self.scheme.sizes))(delta_full)
             dbar = jax.tree_util.tree_map(
-                lambda d: jnp.mean(d.astype(jnp.float32), axis=0),
-                self._uplink(delta_sub))
+                lambda d: jnp.mean(d.astype(jnp.float32), axis=0), delta)
             return _scatter_update(params, dbar, self.abstract,
-                                   self.axes_tree, off0, self.scheme.sizes,
-                                   c.server_lr)
-
-        def acc_step(acc, d_c):
-            acc = jax.tree_util.tree_map(
-                lambda a, b: a + b.astype(a.dtype), acc, d_c)
-            return constrain_tree(acc, self.axes_tree, leading=()), None
-
-        acc0 = jax.tree_util.tree_map(
-            lambda x: jnp.zeros(x.shape, jnp.float32), self.abstract)
-        acc, _ = jax.lax.scan(acc_step, acc0, self._uplink(delta_full))
+                                   self.axes_tree, off0, sizes, c.server_lr)
+        acc = self._window_sum(delta, offsets, sizes)
         return jax.tree_util.tree_map(
-            lambda w, d: (w + c.server_lr * d.astype(jnp.float32) / C
-                          ).astype(w.dtype), params, acc)
+            lambda w, d: (w + c.server_lr * d.astype(jnp.float32)
+                          / c.clients_per_round).astype(w.dtype),
+            params, acc)
 
     @tracing.scoped(tracing.AGGREGATE)
-    def _mean_delta_full_fused(self, delta_full):
-        """Server pseudo-gradient from the fused phase: already full-shaped
-        with exact zeros outside each client's window — the shared-window
-        mean IS the scattered mean of the extract path; per-client windows
-        mirror the extract path's scatter-average scan (same accumulation
-        order, bitwise).  ``uplink_compression`` rounds each client delta
-        through the simulated uplink before the f32 mean."""
-        delta_full = self._uplink(delta_full)
-        if self.shared_window:
-            return jax.tree_util.tree_map(
-                lambda d: jnp.mean(d.astype(jnp.float32), axis=0),
-                delta_full)
-        C = self.scfg.clients_per_round
-
-        def acc_step(acc, d_c):
-            acc = jax.tree_util.tree_map(
-                lambda a, b: a + b.astype(a.dtype) / C, acc, d_c)
-            return constrain_tree(acc, self.axes_tree, leading=()), None
-
-        z = jax.tree_util.tree_map(
-            lambda x: jnp.zeros(x.shape, jnp.float32), self.abstract)
-        full, _ = jax.lax.scan(acc_step, z, delta_full)
-        return full
-
-    @tracing.scoped(tracing.AGGREGATE)
-    def _mean_delta_full(self, params, delta, offsets):
-        """Full-shaped f32 mean client delta (the server pseudo-gradient).
+    def _mean_delta_full(self, params, delta, offsets, sizes=None):
+        """Full-shaped f32 mean client delta (the server pseudo-gradient)
+        of deltas cut to ``sizes`` (as :meth:`_apply_mean_delta`).
 
         Deliberately separate from :meth:`_apply_mean_delta`: stateful
         server optimizers need the delta materialized full-shaped (their
@@ -668,56 +597,45 @@ class WindowFedAvg:
         the scatter logic mirrored between both helpers.
         """
         C = self.scfg.clients_per_round
-        dbar = jax.tree_util.tree_map(
-            lambda d: jnp.mean(d.astype(jnp.float32), axis=0), delta)
-        if not offsets:
-            return dbar
-        if self.shared_window:
+        sizes = self.scheme.sizes if sizes is None else sizes
+        delta = self._uplink(delta)
+        if not offsets or self.shared_window:
+            dbar = jax.tree_util.tree_map(
+                lambda d: jnp.mean(d.astype(jnp.float32), axis=0), delta)
+            if not offsets:
+                return dbar
             off0 = {k: v[0] for k, v in offsets.items()}
             return ex.scatter_delta(dbar, self.abstract, self.axes_tree,
-                                    off0, self.scheme.sizes)
+                                    off0, sizes)
+        # staggered/random windows: average the per-client windows
+        return self._window_sum(
+            jax.tree_util.tree_map(
+                lambda d: d.astype(jnp.float32) / C, delta),
+            offsets, sizes)
 
-        # staggered/random windows: average the per-client scatters
+    def _window_sum(self, delta, offsets, sizes):
+        """f32 sum over the client axis of deltas cut to ``sizes``: a scan
+        that adds each client's delta into the accumulator at its own
+        window, in place — no zero-padded full-shaped delta is formed
+        (``x + 0 == x``, so the sum is bitwise the padded one's)."""
         def acc_step(acc, xs):
             d_c, off_c = xs
-            fd = ex.scatter_delta(d_c, self.abstract, self.axes_tree,
-                                  off_c, self.scheme.sizes)
-            acc = jax.tree_util.tree_map(
-                lambda a, b: a + b.astype(a.dtype) / C, acc, fd)
+            acc = ex.add_at_window(acc, d_c, self.axes_tree, off_c, sizes)
             return constrain_tree(acc, self.axes_tree, leading=()), None
 
-        z = jax.tree_util.tree_map(
+        acc0 = jax.tree_util.tree_map(
             lambda x: jnp.zeros(x.shape, jnp.float32), self.abstract)
-        full, _ = jax.lax.scan(acc_step, z, (delta, offsets))
-        return full
+        acc, _ = jax.lax.scan(acc_step, acc0, (delta, offsets))
+        return acc
 
     # -- mesh scale-out: the client axis under shard_map -----------------------
 
     @tracing.scoped(tracing.AGGREGATE)
-    def _local_delta_sum(self, delta, offsets, fused):
-        """Shard-local f32 scatter-add of client deltas (no /C) — the
-        summand of the client-axis ``psum``.  Mirrors the per-client scan
-        arms of :meth:`_apply_mean_delta` / ``*_fused`` so that
-        ``psum(local_sum) / C`` is the sharded mean delta."""
-        acc0 = jax.tree_util.tree_map(
-            lambda x: jnp.zeros(x.shape, jnp.float32), self.abstract)
-
-        if fused:  # delta already full-shaped, exact 0 outside each window
-            def acc_step(acc, d_c):
-                return jax.tree_util.tree_map(
-                    lambda a, b: a + b.astype(a.dtype), acc, d_c), None
-            acc, _ = jax.lax.scan(acc_step, acc0, delta)
-            return acc
-
-        def acc_step(acc, xs):
-            d_c, off_c = xs
-            fd = ex.scatter_delta(d_c, self.abstract, self.axes_tree,
-                                  off_c, self.scheme.sizes)
-            return jax.tree_util.tree_map(
-                lambda a, b: a + b.astype(a.dtype), acc, fd), None
-
-        acc, _ = jax.lax.scan(acc_step, acc0, (delta, offsets))
-        return acc
+    def _local_delta_sum(self, delta, offsets, sizes):
+        """Shard-local f32 sum of client deltas cut to ``sizes``, each at
+        its window (no /C) — the summand of the client-axis ``psum``, so
+        that ``psum(local_sum) / C`` is the sharded mean delta."""
+        return self._window_sum(self._uplink(delta), offsets, sizes)
 
     def _client_phase_sharded(self, params, batch, offsets):
         """The client phase under ``shard_map`` on ``self.mesh``.
@@ -729,27 +647,26 @@ class WindowFedAvg:
         fused == extract bitwise contract is exactly the single-device
         one.  Crossing shards:
 
-        * ``mesh_agg="gather"`` returns the per-client deltas all_gather'd
-          back to the full client axis in client order — pure data
-          movement, so the caller can replay the UNCHANGED single-device
-          aggregation bitwise;
-        * ``mesh_agg="psum"`` returns the f32 scatter-add partial sums
-          psum'd over the client axis (the scalable arm: O(model) comm,
-          fp-reassociated).
+        * ``mesh_agg="gather"`` returns the per-client (cut) deltas
+          all_gather'd back to the full client axis in client order — pure
+          data movement, so the caller can replay the UNCHANGED
+          single-device aggregation bitwise;
+        * ``mesh_agg="psum"`` returns the full-shaped f32 partial sums
+          (each client's delta added at its window) psum'd over the client
+          axis (the scalable arm: O(model) comm, fp-reassociated).
 
         Per-client losses are always gathered exactly ([K, C]).  The
         exchange runs under the ``fed.aggregate`` scope, the rest of the
         body under the phase's own.
         """
         axis = self.spmd_axis
-        fused = self.use_fused and bool(offsets)
+        phase, sizes = self._phase_for(offsets)
         psum = self.mesh_agg == "psum"
 
         def body(p, b, off):
-            phase = self._client_phase_fused if fused else self._client_phase
             _, delta, losses = phase(p, b, off)
             if psum:
-                delta = self._local_delta_sum(delta, off, fused)
+                delta = self._local_delta_sum(delta, off, sizes)
             with jax.named_scope(tracing.AGGREGATE):
                 losses = jax.lax.all_gather(losses, axis, axis=1, tiled=True)
                 if psum:
@@ -776,10 +693,9 @@ class WindowFedAvg:
                 new = jax.tree_util.tree_map(
                     lambda w, d: (w + c.server_lr * d / c.clients_per_round
                                   ).astype(w.dtype), params, out)
-        elif self.use_fused and offsets:
-            new = self._apply_mean_delta_fused(params, out, offsets)
         else:
-            new = self._apply_mean_delta(params, out, offsets)
+            new = self._apply_mean_delta(params, out, offsets,
+                                         self._phase_for(offsets)[1])
         with jax.named_scope(tracing.SERVER_STEP):
             new = sm.project_l2(new, c.proj_radius)
         return new, {"loss": losses.mean(), "client_loss": losses}
@@ -791,10 +707,9 @@ class WindowFedAvg:
             with jax.named_scope(tracing.AGGREGATE):
                 full_delta = jax.tree_util.tree_map(
                     lambda d: d / self.scfg.clients_per_round, out)
-        elif self.use_fused and offsets:
-            full_delta = self._mean_delta_full_fused(out)
         else:
-            full_delta = self._mean_delta_full(params, out, offsets)
+            full_delta = self._mean_delta_full(params, out, offsets,
+                                               self._phase_for(offsets)[1])
         return full_delta, losses
 
     # -- public rounds (both delegate to the phases above) ---------------------
@@ -806,13 +721,9 @@ class WindowFedAvg:
         offsets = self._client_offsets(params, round_idx, rng)
         if self.mesh is not None:
             return self._round_mesh(params, batch, offsets)
-        if self.use_fused and offsets:
-            _, delta_full, losses = self._client_phase_fused(params, batch,
-                                                             offsets)
-            new = self._apply_mean_delta_fused(params, delta_full, offsets)
-        else:
-            _, delta, losses = self._client_phase(params, batch, offsets)
-            new = self._apply_mean_delta(params, delta, offsets)
+        phase, sizes = self._phase_for(offsets)
+        _, delta, losses = phase(params, batch, offsets)
+        new = self._apply_mean_delta(params, delta, offsets, sizes)
         with jax.named_scope(tracing.SERVER_STEP):
             new = sm.project_l2(new, self.scfg.proj_radius)
         return new, {"loss": losses.mean(), "client_loss": losses}
@@ -852,12 +763,15 @@ class WindowFedAvg:
         offsets = self._client_offsets(params, round_idx, rng)
         if self.mesh is not None:
             return self._mean_delta_full_mesh(params, batch, offsets)
-        if self.use_fused and offsets:
-            _, delta_full, losses = self._client_phase_fused(params, batch,
-                                                             offsets)
-            return self._mean_delta_full_fused(delta_full), losses
-        _, delta, losses = self._client_phase(params, batch, offsets)
-        return self._mean_delta_full(params, delta, offsets), losses
+        phase, sizes = self._phase_for(offsets)
+        _, delta, losses = phase(params, batch, offsets)
+        return self._mean_delta_full(params, delta, offsets, sizes), losses
+
+
+def _tree_bytes(tree) -> int:
+    """Bytes of a tree of arrays or ShapeDtypeStructs."""
+    return sum(int(np.prod(x.shape)) * np.dtype(x.dtype).itemsize
+               for x in jax.tree_util.tree_leaves(tree))
 
 
 def _scatter_update(params, dbar, abstract, axes_tree, off0, sizes,

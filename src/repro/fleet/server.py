@@ -8,8 +8,8 @@ from ``core/fedavg.py`` — and their completion times go on a
 :class:`~repro.fleet.simulator.FleetSimulator`.  Completed reports land
 in the :class:`~repro.fleet.buffer.DeltaBuffer`; once M of the N
 in-flight clients have reported, the buffered deltas are aggregated
-through the round object's OWN aggregation arms (`_apply_mean_delta*`,
-``_mean_delta_full*`` + ``ServerOpt``), with staleness weights and the
+through the round object's OWN aggregation arms (``_apply_mean_delta``,
+``_mean_delta_full`` + ``ServerOpt``), with staleness weights and the
 server-lr schedule folded into a per-entry scale vector.
 
 Exactness anchor (pinned in ``tests/test_fleet.py``, gated by
@@ -166,13 +166,16 @@ class AsyncTrainer:
         self._idle: List[int] = list(range(self._C))
         self._round_offsets: Dict[int, Any] = {}   # tag -> full [C] offsets
         self._offsets_host: Dict[int, Any] = {}    # host mirror, same tags
-        self._fused: Optional[bool] = None  # resolved at first dispatch
+        self._arm = None                    # client phase, and the windows
+        self._sizes = None                  # its deltas are cut to: both
+                                            # resolved at first dispatch
         self._phase = None
         self._scatter_fed = None            # shared_window=False clone
         self._agg_cache: Dict[Any, Any] = {}
         # Heterogeneous capacities (window mode, capacities=): dispatch
         # cohorts run the bucket-loop phase and report FULL-shaped
-        # per-client deltas, so buffered aggregation is width-agnostic.
+        # per-client deltas (cut to no window), so buffered aggregation is
+        # width-agnostic.
         self._hetero = getattr(fed, "hetero", None)
         self._phase_cache: Dict[Any, Any] = {}
 
@@ -207,12 +210,8 @@ class AsyncTrainer:
                 self._phase_cache[key] = jax.jit(f) if self.jit else f
             return self._phase_cache[key]
         if self._phase is None:
-            fed = self.fed
-
             def f(params, batch, offsets):
-                phase = (fed._client_phase_fused if self._fused
-                         else fed._client_phase)
-                _, delta, losses = phase(params, batch, offsets)
+                _, delta, losses = self._arm(params, batch, offsets)
                 return delta, losses
 
             self._phase = jax.jit(f) if self.jit else f
@@ -262,11 +261,12 @@ class AsyncTrainer:
         ids = self._pair_capacities(self.sampler.sample(len(slots)), slots)
         tag = self.round_idx
         offsets = self._offsets_for(tag)
-        if self._fused is None:
+        if self._arm is None:
             # heterogeneous cohorts report FULL-shaped per-client deltas
-            # (exact zeros outside each window) → the *_fused agg arms
-            self._fused = (True if self._hetero is not None
-                           else self.fed.use_fused and bool(offsets))
+            # (exact zeros outside each window): cut to no window
+            self._arm, self._sizes = self.fed._phase_for(offsets)
+            if self._hetero is not None:
+                self._sizes = {}
         lanes = jnp.asarray(slots, jnp.int32)
         cohort_off = {k: jnp.take(v, lanes, axis=0)
                       for k, v in offsets.items()}
@@ -314,14 +314,15 @@ class AsyncTrainer:
             g = lr_mult * weights * (denom / weights.sum())
         return g
 
-    def _agg_fn(self, fused, shared_arm, scale, with_opt):
-        key = (fused, shared_arm, scale, with_opt)
+    def _agg_fn(self, shared_arm, scale, with_opt):
+        key = (shared_arm, scale, with_opt)
         if key in self._agg_cache:
             return self._agg_cache[key]
         fed = self.fed
         arm = fed if (shared_arm or not fed.shared_window) \
             else self._scatter_arm()
         server_opt = self.server_opt
+        sizes = self._sizes
 
         def scaled(delta, g):
             if not scale:
@@ -333,8 +334,7 @@ class AsyncTrainer:
             def f(params, opt_state, delta, offsets, g):
                 with jax.named_scope(tracing.AGGREGATE):
                     delta = scaled(delta, g)
-                full = (arm._mean_delta_full_fused(delta) if fused
-                        else arm._mean_delta_full(params, delta, offsets))
+                full = arm._mean_delta_full(params, delta, offsets, sizes)
                 with jax.named_scope(tracing.SERVER_STEP):
                     new, opt_state = server_opt.update(params, full,
                                                        opt_state)
@@ -344,9 +344,7 @@ class AsyncTrainer:
             def f(params, delta, offsets, g):
                 with jax.named_scope(tracing.AGGREGATE):
                     delta = scaled(delta, g)
-                new = (arm._apply_mean_delta_fused(params, delta, offsets)
-                       if fused else
-                       arm._apply_mean_delta(params, delta, offsets))
+                new = arm._apply_mean_delta(params, delta, offsets, sizes)
                 with jax.named_scope(tracing.SERVER_STEP):
                     return sm.project_l2(new, fed.scfg.proj_radius)
 
@@ -377,8 +375,7 @@ class AsyncTrainer:
         scale = not np.all(g == 1.0)
         gj = jnp.asarray(g, jnp.float32)
 
-        fn = self._agg_fn(self._fused, shared_arm, scale,
-                          self.server_opt is not None)
+        fn = self._agg_fn(shared_arm, scale, self.server_opt is not None)
         if self.server_opt is None:
             self.params = fn(self.params, delta, offsets, gj)
         else:

@@ -4,8 +4,8 @@ The shared-window kernels (``rolling_matmul.py`` / ``rolling_matmul_bwd.py``)
 take ONE scalar window offset: every client trains the same contiguous
 column window of W, which is exactly the non-staggered rolling/static/
 importance schemes.  The *staggered* rolling scheme (and the random
-structured scheme) give every client its OWN window, so the fused client
-phase needs the batched form
+structured scheme) give every client its OWN window, so a client-vmapped
+window-aware forward on full weights needs the batched form
 
     y[b, M, win] = x[b, M, K] @ W[b, K, off[b] : off[b]+win]      b = 0..B-1
 
@@ -19,8 +19,9 @@ Both kernels prefetch the whole ``off_blocks`` vector through
 ``pltpu.PrefetchScalarGridSpec`` and index it with the leading (batch) grid
 coordinate — one scalar-prefetch row per client — so each client's kernel
 instance reads only its active window of W from HBM and no per-client
-W_sub stack is ever materialized.  This is what lets the staggered fused
-round keep the zero-copy property of the shared-window arm.
+W_sub stack is ever materialized, as in the shared-window arm.  (The fed
+round no longer runs these kernels: it cuts each client's windows once a
+round and carries the cut tree, ``core/fedavg.py``.)
 
 The weight gradient needs no kernel (per-row window scatter-add of
 ``x[b]^T @ dy[b]``); see ``dispatch.rolling_matmul_batched``'s custom VJP,

@@ -101,16 +101,15 @@ def main(argv=None):
                          "the REPRO_KERNEL_BACKEND env var, else auto")
     ap.add_argument("--fused-forward", default="auto",
                     choices=["auto", "on", "off"],
-                    help="window mode: run the client phase through the "
-                         "fused multi-axis window forward (no extract/"
-                         "scatter, no W_sub copy) when every windowed axis "
-                         "has a fused arm (d_ff, GQA-coupled heads/"
-                         "kv_heads, MLA standalone heads, experts, "
-                         "moe_d_ff, ssm_heads); per-client schemes "
-                         "(--stagger, random) fuse through the batched-"
-                         "offset kernels; 'on' forces it, 'off' keeps the "
-                         "extract-based client phase (see the README "
-                         "fused-coverage matrix)")
+                    help="window mode: run the fused multi-axis window "
+                         "client phase (each client's window cut once a "
+                         "round and carried; a share's experts window "
+                         "whole, narrowed by the window-aware forward) "
+                         "when every windowed axis has a fused arm (d_ff, "
+                         "GQA-coupled heads/kv_heads, MLA standalone "
+                         "heads, experts, moe_d_ff, ssm_heads); 'on' "
+                         "forces it, 'off' keeps the extract-based client "
+                         "phase (see the README fused-coverage matrix)")
     ap.add_argument("--kernel-block", default=None, metavar="BMxBNxBK",
                     help="override the rolling-matmul block autotuner with "
                          "a fixed (bm, bn, bk) triple, e.g. 128x128x64 "

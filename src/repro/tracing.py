@@ -23,6 +23,10 @@ children ``model.moe.dispatch`` (routing and top-k, the balance loss, the
 dense path's weighted combine) and ``model.moe.experts`` (the expert
 matmuls, forward and backward).
 
+``CLIENT_CARRY`` counts, per traced client phase, the bytes of the tree a
+client carries through its K local steps against the whole tree's (the
+fused arm cuts each client's windows once a round and carries the cut).
+
 Host spans of the trainers (``core/trainer.py``, ``fleet/server.py``):
 ``repro.round`` is one round's host work, with the children
 ``repro.round.put`` (the batch to the device) and ``repro.round.dispatch``
@@ -54,6 +58,19 @@ SYNC = "repro.sync"
 #: Fires once per executable compiled or loaded from the persistent cache
 #: (``jax._src.dispatch.BACKEND_COMPILE_EVENT``): once per jit cache miss.
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+#: Bytes of the tree one client carries through its local steps, and of
+#: the whole tree, summed over every client phase traced in this process
+#: (``core/fedavg.py``, recorded when a round is traced, not when it runs).
+CLIENT_CARRY = {"carried": 0, "whole": 0}
+
+
+def record_carry(carried: int, whole: int) -> None:
+    """Add one traced client phase's carried and whole-tree bytes to
+    :data:`CLIENT_CARRY`."""
+    CLIENT_CARRY["carried"] += carried
+    CLIENT_CARRY["whole"] += whole
 
 
 def scoped(name: str) -> Callable:

@@ -252,3 +252,102 @@ def test_share_config_counts_the_whole_router():
     cfg = _cell().model_config()
     whole = replace(cfg, moe=replace(cfg.moe, router_experts=0))
     assert cfg.n_params() - whole.n_params() == 2 * cfg.d_model * (16 - 4)
+
+
+def _share_round_setup():
+    cell = _cell()
+    model = build_model(cell.model_config(), remat=False)
+    fed = api.fed_round(model, cell.submodel_config())
+    params = model.init(jax.random.PRNGKey(0))
+    m = cell.mix
+    tokens = jax.random.randint(
+        jax.random.PRNGKey(2), (m["local_steps"], m["clients"],
+                                m["seqs_per_step"], m["seq_len"]),
+        0, cell.model_config().vocab)
+    return model, fed, params, {"tokens": tokens}
+
+
+def test_share_carries_its_experts_whole_and_cuts_the_rest():
+    """The fused round cuts every window of a share once a round but its
+    ``experts`` window: routing needs each held expert's id, so the expert
+    stacks keep all 4 held experts (their ``moe_d_ff`` cut to 32 of 64)
+    and the window-aware forward narrows them to 2.  The MLA heads, the
+    dense layer's ``d_ff`` and the shared experts' width are cut; the
+    router stays whole."""
+    model, fed, params, batch = _share_round_setup()
+    assert fed.use_fused
+    assert fed._whole_keys == {("experts", 4): 2}
+    assert ("experts", 4) not in fed._cut_sizes
+
+    def phase(p, b, rng):
+        off = fed._client_offsets(p, 0, rng)
+        return fed._client_phase_fused(p, b, off)[0]
+
+    sub0 = jax.eval_shape(phase, params, batch, jax.random.PRNGKey(1))
+    moe_p, C = sub0["moe_layers"]["moe"], 1
+    L = params["moe_layers"]["moe"]["w_gate"].shape[0]
+    assert moe_p["w_gate"].shape == (C, L, 4, 128, 32)
+    assert moe_p["w_down"].shape == (C, L, 4, 32, 128)
+    assert moe_p["router"].shape == (C, L, 128, 16)
+    assert moe_p["shared"]["w_up"].shape == (C, L, 128, 64)
+    assert sub0["moe_layers"]["attn"]["wq"].shape[-2] == 2       # of 4
+    assert sub0["dense_layers"]["mlp"]["w_gate"].shape[-1] == 128  # of 256
+
+
+def _full_carry_round(model, fed, params, batch, round_idx, rng):
+    """The fused round as it was before the cut carry: K steps of every
+    client on the WHOLE tree through the window-aware forward with every
+    window in the WindowMap, full-shaped f32 deltas, the client mean added
+    to the parameters."""
+    from repro.models.layers import AxisWindow, WindowMap
+    c = fed.scfg
+    offsets = fed._client_offsets(params, round_idx, rng)
+    window = WindowMap(
+        {k: AxisWindow(offsets[k][0], w, fed._fused_mults[k])
+         for k, w in fed._fused_keys.items()}, backend=fed.kernel_backend)
+    grad = jax.vmap(jax.value_and_grad(
+        lambda p, mb: model.loss(p, mb, window=window), has_aux=True))
+    C = batch["tokens"].shape[1]
+    full0 = jax.tree_util.tree_map(
+        lambda x: jnp.broadcast_to(x[None], (C,) + x.shape), params)
+
+    def kstep(p, mb):
+        (loss, _), g = grad(p, mb)
+        return dispatch.sgd_step(p, g, c.client_lr), loss
+
+    fullK, _ = jax.lax.scan(kstep, full0, batch)
+    return jax.tree_util.tree_map(
+        lambda w, a, b: w + c.server_lr * jnp.mean(a - b, axis=0),
+        params, fullK, full0)
+
+
+def test_share_round_matches_the_full_carry_round():
+    """The cut carry trains a share as the whole-tree carry did.  The
+    shared experts' cut width (2 x 64 cut to 64) equals the per-expert
+    ``moe_d_ff`` of 64, which a WindowMap keyed by (axis, size) could
+    mistake for a whole per-expert width; the map names only ``experts``,
+    so that alias changes nothing.  Tolerance: the two programs run the
+    same float32 arithmetic in different shapes (plain dots on cut
+    weights against dots on windows of whole weights, whose gradients add
+    into zero-filled whole buffers), so XLA may order their accumulations
+    differently; a pin to float32 roundoff of one round, 1e-6 against
+    changes of order 0.07, survives that (on jax 0.9's CPU backend the two
+    agree bit for bit), while a wrong window moves weights by 1e-2."""
+    from repro.models.layers import WindowMap
+    model, fed, params, batch = _share_round_setup()
+    window = fed._fused_window({k: 0 for k in fed._whole_keys})
+    assert isinstance(window, WindowMap)
+    assert set(window.windows) == {("experts", 4)}
+    assert window.get("moe_d_ff", 64) is None
+    rng = jax.random.PRNGKey(1)
+    new, _ = jax.jit(fed.round)(params, batch, 0, rng)
+    want = jax.jit(lambda p, b, r: _full_carry_round(model, fed, p, b, 0, r)
+                   )(params, batch, rng)
+    moved = 0
+    for (path, a), b, p in zip(
+            jax.tree_util.tree_leaves_with_path(new),
+            jax.tree_util.tree_leaves(want), jax.tree_util.tree_leaves(params)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0,
+                                   atol=1e-6, err_msg=str(path))
+        moved += int(not np.array_equal(np.asarray(a), np.asarray(p)))
+    assert moved > 0

@@ -141,7 +141,7 @@ def test_async_anchor_fused_transformer():
     p_sync, _ = tr.run(iter(items), 2)
     at = api.AsyncTrainer(fed, params, rng=jax.random.PRNGKey(5))
     p_async, _ = at.run(iter(items), 2)
-    assert at._fused is True          # the fused phase actually ran
+    assert at._arm == fed._client_phase_fused   # the fused phase ran
     assert _maxdelta(p_sync, p_async) == 0.0
 
 

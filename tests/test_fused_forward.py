@@ -1,14 +1,17 @@
 """The fused multi-axis window forward (tentpole property tests).
 
-When ``WindowFedAvg`` resolves a shared window and every properly-windowed
-axis has a fused forward (``d_ff``, GQA-coupled ``heads``/``kv_heads``,
-``experts``, ``moe_d_ff``), the client phase skips extract/scatter
-entirely: clients run K steps on the FULL tree through the window-aware
-``Model.forward`` (``mlp_apply_rolling``, the head-flattened
-``_head_proj``, windowed MoE routing/experts).  The fused round must be
-**bitwise equal (f32, 0 ulp)** to the extract-based round — pinned here
-across schemes, multi-axis combinations, model families, optimizers,
-backends, and the unaligned exact-tail grid entry.
+When every properly-windowed axis has a fused forward (``d_ff``,
+GQA-coupled ``heads``/``kv_heads``, ``experts``, ``moe_d_ff``,
+``ssm_heads``), ``WindowFedAvg`` resolves the fused arm: each client's
+windows are cut once a round and the cut tree is carried through the K
+steps, but for axes the forward must index absolutely (a share's
+``experts``), which stay whole and are narrowed by the window-aware
+``Model.forward``.  On every model here nothing stays whole, so the fused
+round must be **bitwise equal (f32, 0 ulp)** to the extract-based round —
+pinned across schemes, multi-axis combinations, model families,
+optimizers, backends, and the unaligned exact-tail grid entry.  The
+layer-level window-aware forward is pinned against the compact forward at
+the end of the file.
 """
 from dataclasses import replace
 
@@ -141,8 +144,8 @@ def test_resolve_fused_full_default_axes(arch, windowed):
 
 def test_fused_round_bitwise_on_unaligned_tail():
     """align=8 with d_ff=100 puts the exact-tail offset (52) off the
-    alignment grid — the fused arm must drop to the oracle matmul there and
-    stay bitwise-equal to extraction."""
+    alignment grid: its certificate refuses the fused Pallas arm, and the
+    round stays bitwise-equal to extraction on every grid window."""
     cfg, m = _tiny_model(d_ff=100)
     params = m.init(jax.random.PRNGKey(0))
     scfg = SubmodelConfig(scheme="rolling", capacity=0.5, local_steps=2,
@@ -181,9 +184,8 @@ def test_fused_round_backends(backend):
 
 
 def test_fused_with_server_opt_bitwise():
-    """round_with_server_opt: the fused full-shaped mean delta (exact zeros
-    outside the window) must reproduce the extract path's scattered
-    pseudo-gradient bit for bit."""
+    """round_with_server_opt: the fused arm's cut mean delta, scattered,
+    must reproduce the extract path's pseudo-gradient bit for bit."""
     from repro.core.server_opt import server_momentum
     cfg, m = _tiny_model()
     params = m.init(jax.random.PRNGKey(0))
@@ -234,9 +236,9 @@ PER_CLIENT = [("rolling", True), ("random", False), ("importance", True)]
 
 @pytest.mark.parametrize("scheme,stagger", PER_CLIENT)
 def test_staggered_fused_round_bitwise_equals_extract(scheme, stagger):
-    """Per-client windows run fused (clients vmap over their own
-    WindowMaps; dispatch lowers to the batched-offset rolling matmul) and
-    must stay bitwise-equal to the per-client extract/scatter round."""
+    """Per-client windows run fused (one cut per client, each client's
+    delta added in place at its window) and must stay bitwise-equal to the
+    per-client extract/scatter round."""
     cfg, m = _tiny_model()
     params = m.init(jax.random.PRNGKey(0))
     scfg = SubmodelConfig(scheme=scheme, capacity=0.5, local_steps=2,
@@ -291,9 +293,9 @@ def test_staggered_fused_bitwise_on_unaligned_tail():
 
 
 def test_staggered_fused_with_server_opt_bitwise():
-    """round_with_server_opt on per-client windows: the fused full-shaped
-    deltas feed the same scatter-average scan as extract — pseudo-gradient
-    and optimizer state must match bit for bit."""
+    """round_with_server_opt on per-client windows: the fused cut deltas
+    feed the same scatter-average scan as extract — pseudo-gradient and
+    optimizer state must match bit for bit."""
     from repro.core.server_opt import server_momentum
     cfg, m = _tiny_model()
     params = m.init(jax.random.PRNGKey(0))
@@ -319,7 +321,7 @@ def test_staggered_fused_with_server_opt_bitwise():
 @pytest.mark.parametrize("arch", ["hymba_1_5b", "mamba2_130m"])
 def test_staggered_fused_default_axes_families(arch):
     """Acceptance pin: the staggered scheme runs fused on the default axes
-    tuple for the SSM families (windowed SSD projection per client) and
+    tuple for the SSM families (each client's ssm_heads window cut) and
     stays bitwise-equal to extract."""
     cfg = replace(get_reduced_config(arch), n_layers=2)
     m = build_model(cfg, remat=False)
@@ -354,12 +356,11 @@ def test_staggered_fused_mla_heads_bitwise():
 _EXPERTS_MLA_CACHE = []
 
 
-def _experts_mla_maxdelta():
-    """fused-vs-extract round maxdelta for the one known-caveat point: an
-    ``experts`` window on the MLA+shared+sigmoid family, K>1 local steps,
-    the largest over the two rounds the bitwise MULTI_AXIS matrix runs
-    (each round from the fused round's parameters, as there).  Computed
-    once, shared by the tolerance pin and the 0-ulp xfail."""
+def _experts_mla_round_pair():
+    """``(fused, worst fused-vs-extract maxdelta)`` for an ``experts``
+    window on the MLA+shared+sigmoid family, K>1 local steps, over the two
+    rounds the bitwise MULTI_AXIS matrix runs (each round from the fused
+    round's parameters, as there).  Computed once, shared by both pins."""
     if not _EXPERTS_MLA_CACHE:
         cfg = replace(get_reduced_config("deepseek_v3_671b"), n_layers=2)
         m = build_model(cfg, remat=False)
@@ -376,34 +377,26 @@ def _experts_mla_maxdelta():
             pe, _ = step_e(params, batch, r, jax.random.PRNGKey(1))
             worst = max(worst, _maxdelta(pf, pe))
             params = pf
-        _EXPERTS_MLA_CACHE.append(worst)
+        _EXPERTS_MLA_CACHE.append((fused, worst))
     return _EXPERTS_MLA_CACHE[0]
 
 
 def test_fused_experts_window_mla_family_close():
-    """Known f32 caveat (pre-dates the fused staggered arm): an `experts`
-    window on the MLA+shared+sigmoid family with K>1 local steps agrees
-    with extract only to float32 roundoff — XLA reassociates the scanned
-    client phase differently for the two program shapes.  On jax 0.9 the
-    first round agrees to 0 ulp and the second does not (1 ulp).  Pinned
-    here as a tolerance over both rounds so a real regression (>> 1 ulp)
-    still fails; every other family/axis combination in this file is
-    pinned at exactly 0."""
-    assert _experts_mla_maxdelta() <= 5e-7
+    """A whole layer's ``experts`` window (its router spans the experts it
+    holds) is cut once a round like every dense window: the fused arm keeps
+    no axis whole there.  Until the cut carry this point agreed with
+    extract only to f32 roundoff (1 ulp in round 1), because the full-tree
+    carry was another program shape; the round still stays within that old
+    5e-7 pin."""
+    fused, worst = _experts_mla_round_pair()
+    assert fused.use_fused and fused._whole_keys == {}
+    assert worst <= 5e-7
 
 
-@pytest.mark.xfail(strict=True,
-                   reason="documented caveat: experts windows with K>1 on "
-                          "the MLA family agree with extract to f32 "
-                          "roundoff only, not 0 ulp, over the two rounds "
-                          "of the bitwise matrix (round 0 is 0 ulp on jax "
-                          "0.9, round 1 is not).  If this starts PASSING "
-                          "(strict xfail -> suite failure), XLA stopped "
-                          "reassociating the two program shapes "
-                          "differently: delete both pins and fold the arch "
-                          "into the bitwise MULTI_AXIS matrix above.")
 def test_fused_experts_window_mla_family_zero_ulp():
-    assert _experts_mla_maxdelta() == 0.0
+    """The same point at 0 ulp over both rounds: with the cut carry both
+    arms run the same program, so the former roundoff caveat is gone."""
+    assert _experts_mla_round_pair()[1] == 0.0
 
 
 # -- resolution / validation --------------------------------------------------

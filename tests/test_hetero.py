@@ -90,10 +90,9 @@ def _compose_delta_sum(model, scfg, buckets, params, batch, round_idx,
         bb = jax.tree_util.tree_map(
             lambda x: jnp.take(x, lanes, axis=1), batch)
         boff = ref._client_offsets(params, round_idx, rng)
-        fused = ref.use_fused and bool(boff)
-        phase = ref._client_phase_fused if fused else ref._client_phase
+        phase, sizes = ref._phase_for(boff)
         _, delta, _ = phase(params, bb, boff)
-        part = ref._local_delta_sum(delta, boff, fused)
+        part = ref._local_delta_sum(delta, boff, sizes)
         acc = part if acc is None else jax.tree_util.tree_map(
             lambda a, d: a + d, acc, part)
     return acc
@@ -273,7 +272,7 @@ def test_async_hetero_m_equals_n_allclose():
     at = api.AsyncTrainer(fed, params, rng=jax.random.PRNGKey(5))
     p_async, h_async = at.run(iter(items), n)
 
-    assert at._fused is True            # full-shaped deltas ride fused agg
+    assert at._sizes == {}      # full-shaped deltas, cut to no window
     assert _maxdelta(p_sync, p_async) < 1e-5
     for rs, ra in zip(h_sync, h_async):
         np.testing.assert_allclose(np.asarray(rs["client_loss"]),

@@ -1,6 +1,6 @@
 """analysis/hlo_check.py — the reusable HLO invariant predicates.
 
-The heavyweight consumers (bench ``fused_no_wsub_alloc`` gate, the mesh
+The heavyweight consumers (bench ``fused_carries_window`` gate, the mesh
 all-gather witness in tests/test_mesh.py) exercise the real invariants;
 this file pins the module's own contract on small functions.
 """

@@ -122,12 +122,20 @@ def test_mesh_extract_round_bitwise_equals_single_device(n, scheme, stagger):
 
 
 @pytest.mark.parametrize("n", MESHES)
-def test_mesh_fused_equals_mesh_extract(n):
-    """Per shard, the fused == extract contract is the single-device one."""
+@pytest.mark.parametrize("agg", ["gather", "psum"])
+@pytest.mark.parametrize("stagger", [False, True],
+                         ids=["rolling", "staggered"])
+def test_mesh_fused_equals_mesh_extract(n, agg, stagger):
+    """Per shard, the fused == extract contract is the single-device one:
+    both arms carry the same cut tree, and both aggregation arms (the
+    gathered cut deltas, the psum of full-shaped partials) add each
+    client's delta at its window the same way, bit for bit."""
     mesh = _mesh(n)
-    m, params, scfg, batch = _lm_setup(stagger=True)
-    fused = api.fed_round(m, scfg, fused_forward="on", mesh=mesh)
-    extract = api.fed_round(m, scfg, fused_forward="off", mesh=mesh)
+    m, params, scfg, batch = _lm_setup(stagger=stagger)
+    fused = api.fed_round(m, scfg, fused_forward="on", mesh=mesh,
+                          mesh_agg=agg)
+    extract = api.fed_round(m, scfg, fused_forward="off", mesh=mesh,
+                            mesh_agg=agg)
     _assert_rounds_bitwise(fused, extract, params, batch)
 
 

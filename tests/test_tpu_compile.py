@@ -181,8 +181,11 @@ def test_sgd_step_updates_leaves_in_place_for_v5e(one_chip):
 
 def test_fused_round_compiles_for_v5e(one_chip, monkeypatch):
     """One fused window round at TinyLlama-1.1B published widths, cut to 2
-    layers and 2 clients: compiles with the Pallas kernels in it and fits
-    the chip's HBM, parameters in and out included."""
+    layers and 2 clients: compiles, carries each client's cut window
+    (the MLP stacks ``[C, L, D, win]``, never ``[C, L, D, d_ff]``) with
+    no Pallas call in it (the cut weights take XLA dots; the kernels are
+    compiled on their own above), and fits the chip's HBM, parameters in
+    and out included."""
     import dataclasses
 
     from repro import api
@@ -204,7 +207,10 @@ def test_fused_round_compiles_for_v5e(one_chip, monkeypatch):
     compiled = jax.jit(fed.round).lower(
         params, batch, _spec(one_chip, (), jnp.int32),
         _spec(one_chip, (2,), jnp.uint32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert f"f32[2,2,{K},{WIN}]" in text
+    assert f"f32[2,2,{K},{N}]" not in text
+    assert "tpu_custom_call" not in text
     mem = compiled.memory_analysis()
     need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
